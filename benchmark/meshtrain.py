@@ -19,7 +19,7 @@ set BEFORE jax loads:
 
 The child drives ``LazyDeviceVerifier("mesh")`` through the real
 ``AsyncVerifyService`` (fixed-shape buckets, dispatch-loop slots,
-depth-K pipelining — the same tunnel contract production nodes use) and
+depth-K pipelining — the same dispatch path production nodes use) and
 prints ONE JSON line.  The parent assembles the ``mesh_train`` block:
 
 - ``per_mesh[m].per_batch[b].train_sigs_per_s`` — sustained amortized

@@ -221,7 +221,7 @@ def run_profile(
 
     ``route="device"`` pins warmed-up waves to the device via
     HOTSTUFF_FORCE_DEVICE_ROUTE (the waterfall should measure the
-    dispatch pipeline, not the adaptive router's weather calls);
+    dispatch pipeline, not the adaptive router's calls);
     ``route="auto"`` leaves the cost-model routing in charge.
     ``verifier="cpu"`` profiles the inline host path instead;
     ``verifier="bls"`` profiles the BLS claims path (device G1

@@ -16,13 +16,3 @@ Reference layer map: SURVEY.md §1; component parity: SURVEY.md §2.
 """
 
 __version__ = "0.1.0"
-
-# One persistent XLA/Mosaic compilation cache for every process that
-# imports the framework (nodes, bench, tests).  The Pallas verify kernel
-# costs minutes of Mosaic compile per batch shape; with a shared cache it
-# compiles once per machine and loads in seconds ever after.  Must run
-# before jax is imported anywhere; an explicit env var wins.
-import os as _os
-
-JAX_CACHE_DIR = _os.path.expanduser("~/.cache/hotstuff_tpu/jax")
-_os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", JAX_CACHE_DIR)

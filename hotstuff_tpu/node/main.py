@@ -17,6 +17,7 @@ import sys
 
 from ..consensus import Committee, Parameters
 from .config import (
+    ConfigError,
     Secret,
     read_committee,
     write_committee,
@@ -799,14 +800,18 @@ def main(argv=None) -> int:
     if args.command == "keys":
         Secret.new(args.scheme).write(args.filename)
         return 0
-    if args.command == "run":
-        # sanity-check the committee file before booting
-        read_committee(args.committee)
-        asyncio.run(_run_node(args))
-        return 0
-    if args.command == "run-many":
-        read_committee(args.committee)
-        asyncio.run(_run_many(args))
+    if args.command in ("run", "run-many"):
+        try:
+            # sanity-check the committee file before booting
+            read_committee(args.committee)
+            asyncio.run(
+                _run_node(args) if args.command == "run" else _run_many(args)
+            )
+        except ConfigError as e:
+            # a configuration this host cannot serve: unreadable files,
+            # or a device verifier without its device
+            log.error("Cannot boot: %s", e)
+            return 1
         return 0
     if args.command == "reconfig":
         return asyncio.run(_submit_reconfig(args))

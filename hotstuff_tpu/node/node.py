@@ -189,8 +189,22 @@ class LazyDeviceVerifier:
     def warmup(self, batch: int | None = None) -> None:
         if self._kind in self._warm:
             return  # the shared device instance is already warm
-        self._materialize().warmup(batch)
+        import json
+        import time
+
+        t0 = time.perf_counter()
+        device = self._materialize()
+        device.warmup(batch)
         self._warm.add(self._kind)
+        # NOTE: this log entry is part of the benchmark log-scrape
+        # contract (chip_smoke.py reads the device, the kernel and the
+        # cache hits per pad shape from it).
+        log.info(
+            "Device verifier [%s] warm in %.1f s: %s",
+            self._kind,
+            time.perf_counter() - t0,
+            json.dumps(device.describe()),
+        )
 
     def verify_one(self, digest, pk, sig) -> bool:
         return self._cpu.verify_one(digest, pk, sig)
@@ -379,26 +393,47 @@ class Node:
         # plumbing with jax never imported — the service's ready gate
         # keeps everything on CPU.  Must skip the WHOLE warmup block,
         # not just the co-location boost.
-        if hasattr(verifier, "warmup") and not os.environ.get(
-            "HOTSTUFF_SKIP_WARMUP"
-        ) and (
-            committee_size >= getattr(verifier, "min_device_batch", 0)
-            or colocated > 1
+        if (
+            verifier_backend != "cpu"
+            and hasattr(verifier, "warmup")
+            and not os.environ.get("HOTSTUFF_SKIP_WARMUP")
         ):
-            # compile/cache-load the device kernel BEFORE binding the
-            # consensus port: a cold compile on the first QC verify
-            # would stall past the round timeout and trigger view
-            # changes (clients wait for the port, so boot-time cost is
-            # invisible to the measured window).  Skipped when every
-            # possible batch (<= committee size) routes to the CPU
-            # hybrid path anyway — then the kernel is never dispatched.
-            quorum = committee_size * 2 // 3 + 1
-            wave = (
-                committee_size
-                if colocated <= 1
-                else min(1024, colocated * (quorum + 2))
-            )
-            verifier.warmup(batch=wave)
+            min_batch = getattr(verifier, "min_device_batch", 0)
+            if committee_size >= min_batch or colocated > 1:
+                # A device verifier means the chip: refuse any other
+                # backend HERE, where the device is about to be engaged,
+                # rather than carry on with the XLA kernel on the CPU
+                # (committees that never get here never import jax).
+                from ..tpu import require_tpu
+
+                try:
+                    require_tpu(f"--verifier {verifier_backend}")
+                except RuntimeError as e:
+                    raise ConfigError(str(e)) from e
+                # compile/cache-load the device kernel BEFORE binding
+                # the consensus port: a cold compile on the first QC
+                # verify would stall past the round timeout and trigger
+                # view changes (clients wait for the port, so boot-time
+                # cost is invisible to the measured window).
+                quorum = committee_size * 2 // 3 + 1
+                wave = (
+                    committee_size
+                    if colocated <= 1
+                    else min(1024, colocated * (quorum + 2))
+                )
+                verifier.warmup(batch=wave)
+            else:
+                # every possible batch (<= committee size) routes to the
+                # CPU hybrid path: the kernel is never dispatched
+                log.info(
+                    "Device verifier [%s] selected but will never "
+                    "engage: a committee of %d is below "
+                    "min_device_batch=%d and this node is not "
+                    "co-located; every batch verifies on the CPU",
+                    verifier_backend,
+                    committee_size,
+                    min_batch,
+                )
 
         from .. import telemetry
 

@@ -313,7 +313,9 @@ def make_sharded_g1_aggregate(mesh):
     power-of-two per-device slice; the driver pads with identities."""
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.mesh import DP_AXIS as axis, shard_map
+    from jax import shard_map
+
+    from ..parallel.mesh import DP_AXIS as axis
 
     def local(xs, ys, zs):
         part = _tree_reduce((xs, ys, zs))  # [1, NLIMBS] per device

@@ -86,8 +86,9 @@ _verify_kernel_pallas_donated = jax.jit(
 
 
 # Pallas pad shapes: lane-aligned, capped at 1024 per dispatch (larger
-# batches chunk; each new shape costs a multi-minute Mosaic compile,
-# amortized by the persistent compilation cache).
+# batches chunk).  Each shape is traced, lowered and compiled on its
+# own; only the compile is a load from the persistent cache after the
+# first process (seconds per shape on a v5e: CHANGES.md, ISSUE 22).
 PALLAS_PAD_SIZES = (128, 256, 1024)
 
 
@@ -142,9 +143,8 @@ class BatchVerifier:
 
     Hybrid routing: batches smaller than ``min_device_batch`` are
     verified on the CPU backend instead — kernel dispatch has a fixed
-    cost (milliseconds under a remote tunnel, tens of microseconds
-    co-located) that swamps the work of a handful of signatures, so the
-    device only sees batches where it pays off.  Set
+    cost (the dispatch latency) that swamps the work of a handful of
+    signatures, so the device only sees batches where it pays off.  Set
     ``min_device_batch=0`` to force everything onto the device (tests
     do, so the kernel path is what's exercised)."""
 
@@ -209,6 +209,8 @@ class BatchVerifier:
             self.pad_sizes = None  # resolved with use_pallas
         self.min_device_batch = min_device_batch
         self._cpu = None  # lazy CpuVerifier for small batches
+        # pad shape -> FirstCallTimer.take() of its warmup call
+        self.warm_report: dict[int, dict] = {}
 
     @property
     def use_pallas(self) -> bool:
@@ -220,6 +222,21 @@ class BatchVerifier:
                 and not os.environ.get("HOTSTUFF_NO_PALLAS")
             )
         return self._use_pallas
+
+    @property
+    def kernel_name(self) -> str:
+        return "pallas" if self.use_pallas else "xla"
+
+    def describe(self) -> dict:
+        """Where and how this verifier runs — the node's boot line."""
+        from . import device_info
+
+        return {
+            **device_info(),
+            "kernel": self.kernel_name,
+            "pad_shapes": list(self._padded_sizes()),
+            "warm": {str(k): v for k, v in self.warm_report.items()},
+        }
 
     def _padded_sizes(self) -> tuple[int, ...]:
         if self.pad_sizes is None:
@@ -234,9 +251,11 @@ class BatchVerifier:
 
     def warmup(self, batch: int | None = None) -> None:
         """Compile (or cache-load) the device kernel BEFORE entering the
-        consensus hot path.  A cold Mosaic compile of the Pallas kernel
-        takes minutes — paid here, once, at node boot, instead of on the
-        first QC verify where it would blow through the round timeout.
+        consensus hot path.  The first call at each pad shape costs
+        seconds to tens of seconds even on a cache hit — paid here,
+        once, at node boot, instead of on the first QC verify where it
+        would blow through the round timeout.  ``warm_report`` keeps
+        where each shape's seconds went.
 
         ``batch`` is the largest batch the caller expects (the committee
         size: QC/TC verification batches never exceed it) — warming the
@@ -254,8 +273,8 @@ class BatchVerifier:
         # 2f+1 <= committee size, so any pad size at or below the
         # committee's own pad is reachable (e.g. committee 150 pads to
         # 256, but its 101-vote QCs pad to 128 — leaving 128 cold would
-        # put a multi-minute Mosaic compile inside the consensus hot
-        # path, exactly what this warmup exists to prevent).
+        # put a Mosaic compile inside the consensus hot path, exactly
+        # what this warmup exists to prevent).
         grid = self._padded_sizes()
         ceiling = next((p for p in grid if n <= p), grid[-1])
         floor = max(self.min_device_batch, 1)  # smaller pads never reach
@@ -273,10 +292,16 @@ class BatchVerifier:
             if buckets:
                 floor = min(floor, buckets[0])
         sizes = [p for p in grid if floor <= p <= ceiling] or [n]
-        for size in sizes:
-            out = self.verify([msg] * size, [pk] * size, [sig] * size)
-            if not out.all():
-                raise RuntimeError("verifier warmup produced invalid result")
+        from . import FirstCallTimer
+
+        with FirstCallTimer() as timer:
+            for size in sizes:
+                out = self.verify([msg] * size, [pk] * size, [sig] * size)
+                if not out.all():
+                    raise RuntimeError(
+                        "verifier warmup produced invalid result"
+                    )
+                self.warm_report[size] = timer.take()
 
     def _neg_point(self, pk: bytes):
         hit = self._point_cache.get(pk)

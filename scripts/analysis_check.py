@@ -14,7 +14,7 @@ Three stages, all must pass:
 
 Runs stdlib-only (no jax import), so the CI lint job needs no heavy
 deps.  Invoked as ``LINT=1 scripts/trace.sh`` to mirror the BYZ=/
-STATE=/TUNNEL= gate pattern.
+STATE= gate pattern.
 """
 
 from __future__ import annotations

@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Live health-plane check (docs/TELEMETRY.md, ISSUE 13).
 
-Three phases, exit non-zero when ANY contract breaks:
+Two phases, exit non-zero when ANY contract breaks:
 
 1. **Healthy committee, live watch** — a 4-node ``benchmark local
    --health --journal`` run with the fleet watcher attached mid-run:
@@ -14,16 +14,9 @@ Three phases, exit non-zero when ANY contract breaks:
    ``leader_stall`` incident must appear in the LIVE view (scraped
    from the victim's own monitor) and in the ``+ HEALTH`` SUMMARY
    block, and the campaign rings must persist beside the journals.
-3. **Perfgate ratchet with the plane on** — ``bench.probe_tunnel()``
-   re-measured in a child with ``HOTSTUFF_TELEMETRY=1
-   HOTSTUFF_HEALTH=1`` while a live HealthMonitor ticks at 4x the
-   production cadence and a client scrapes ``/delta`` throughout: the
-   recorder + export overhead must keep ``tunnel_dispatch_p50_ms``
-   within the existing series-best ratchet (scripts/perfgate.py).
-   Skip with ``--no-perfgate``.
 
 Usage:
-    python scripts/health_check.py [--rate R] [--no-perfgate]
+    python scripts/health_check.py [--rate R]
     HEALTH=1 scripts/trace.sh             # same, via the trace wrapper
 """
 
@@ -189,139 +182,14 @@ def phase_isolation(rate: int) -> bool:
     return failed
 
 
-def _probe_child() -> int:
-    """Phase-3 child: measure the dispatch tunnel with the health plane
-    LIVE in-process — a HealthMonitor ticking at 4x the production
-    cadence (campaign ring included) and a client scraping ``/delta``
-    for the whole measurement window — so the recorder + export
-    overhead lands inside ``tunnel_dispatch_p50_ms``."""
-    os.environ["HOTSTUFF_TELEMETRY"] = "1"
-    os.environ["HOTSTUFF_HEALTH"] = "1"
-    import asyncio
-    import json
-    import tempfile
-    import threading
-    import urllib.request
-
-    from hotstuff_tpu import telemetry
-    from hotstuff_tpu.telemetry.health import HealthMonitor
-
-    import bench
-
-    telemetry.enable()
-    tel = telemetry.for_node("probe")
-    ring = os.path.join(
-        tempfile.mkdtemp(prefix="health-probe-"), "probe-campaign.json"
-    )
-    mon = HealthMonitor(
-        tel, "probe", timeout_s=60.0, interval_s=0.25, campaign_path=ring
-    )
-
-    loop = asyncio.new_event_loop()
-    ready = threading.Event()
-    state: dict = {}
-
-    async def _serve():
-        state["server"] = await telemetry.maybe_start_server(
-            0, host="127.0.0.1"
-        )
-        state["monitor"] = asyncio.ensure_future(mon.run())
-        ready.set()
-
-    def _loop_main():
-        asyncio.set_event_loop(loop)
-        loop.run_until_complete(_serve())
-        loop.run_forever()
-
-    threading.Thread(target=_loop_main, daemon=True).start()
-    if not ready.wait(10.0) or state.get("server") is None:
-        print("probe child: metrics server never came up", file=sys.stderr)
-        return 1
-    port = state["server"].port
-
-    stop = threading.Event()
-    scrapes = [0]
-
-    def _scrape():
-        seq = -1
-        while not stop.is_set():
-            try:
-                with urllib.request.urlopen(
-                    f"http://127.0.0.1:{port}/delta?since={seq}",
-                    timeout=2.0,
-                ) as resp:
-                    seq = json.loads(resp.read()).get("seq", -1)
-                    scrapes[0] += 1
-            except (OSError, ValueError):
-                pass
-            stop.wait(0.25)
-
-    scraper = threading.Thread(target=_scrape, daemon=True)
-    scraper.start()
-    try:
-        out = bench.probe_tunnel()
-    finally:
-        stop.set()
-        scraper.join(5.0)
-    out["delta_scrapes"] = scrapes[0]
-    print(json.dumps(out))
-    return 0
-
-
-def phase_perfgate() -> bool:
-    print("=== phase 3: dispatch ratchet with the health plane on ===")
-    import perfgate
-
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    proc = subprocess.run(
-        [sys.executable, os.path.abspath(__file__), "--probe-child"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=600,
-    )
-    fresh = perfgate.last_json_line(proc.stdout)
-    if not check("tunnel probe ran with the plane live",
-                 proc.returncode == 0 and fresh is not None,
-                 f"exit {proc.returncode}: {proc.stderr.strip()[-200:]}"):
-        return True
-    if not check("delta export scraped during the window",
-                 fresh.get("delta_scrapes", 0) > 0):
-        return True
-    best = perfgate.load_best()
-    if best is None:
-        print("  [skip] no committed BENCH series carries the ratchet "
-              "metric")
-        return False
-    failures = perfgate.ratchet_check(fresh, best)
-    ok = check(
-        "tunnel_dispatch_p50_ms within the series-best ratchet",
-        not failures,
-        "; ".join(failures),
-    )
-    if ok:
-        print(f"  ({perfgate.RATCHET_METRIC} "
-              f"{fresh.get(perfgate.RATCHET_METRIC)} ms vs best "
-              f"{best[0]:g} ms x {perfgate.RATCHET_SLACK:g})")
-    return not ok
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--rate", type=int, default=400)
-    ap.add_argument("--no-perfgate", action="store_true",
-                    help="skip the dispatch-ratchet phase")
-    ap.add_argument("--probe-child", action="store_true",
-                    help=argparse.SUPPRESS)  # phase-3 internal re-exec
     args = ap.parse_args(argv)
 
     os.chdir(REPO)
-    if args.probe_child:
-        return _probe_child()
     failed = phase_healthy(args.rate)
     failed |= phase_isolation(args.rate)
-    if not args.no_perfgate:
-        failed |= phase_perfgate()
-    else:
-        print("=== phase 3 skipped (--no-perfgate) ===")
     print("health check:", "FAIL" if failed else "PASS")
     return 1 if failed else 0
 

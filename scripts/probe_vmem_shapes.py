@@ -15,8 +15,6 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import hotstuff_tpu  # noqa: F401,E402
-
 
 def main() -> int:
     import jax

@@ -11,12 +11,6 @@
 #
 #   PERFGATE=1 scripts/trace.sh   # also run the perf regression gate
 #                                 # (scripts/perfgate.py) afterwards
-#   TUNNEL=1 scripts/trace.sh     # ONLY the dispatch-tunnel anatomy
-#                                 # check (scripts/tunnel_check.py):
-#                                 # waterfall at QC 16/64/256, e2e
-#                                 # delta vs the committed reference,
-#                                 # non-zero exit if leaf-span coverage
-#                                 # drops below 95%
 #   BYZ=1 scripts/trace.sh        # ONLY the Byzantine adversary matrix
 #                                 # (scripts/byz_check.py): equivocation
 #                                 # caught-and-attributed, collusion
@@ -52,8 +46,7 @@
 #                                 # committee with quiet detectors,
 #                                 # leader-isolation trips leader_stall
 #                                 # in the live view AND the + HEALTH
-#                                 # SUMMARY, and the dispatch ratchet
-#                                 # holds with the plane enabled
+#                                 # SUMMARY
 #   RECONFIG=1 scripts/trace.sh   # ONLY the live-reconfiguration check
 #                                 # (scripts/reconfig_check.py): rotate
 #                                 # joins node 4 / retires node 0 with
@@ -119,11 +112,6 @@
 set -euo pipefail
 
 cd "$(dirname "$0")/.."
-
-if [ "${TUNNEL:-0}" = "1" ]; then
-    exec timeout -k 10 1800 env JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
-        python scripts/tunnel_check.py "$@"
-fi
 
 if [ "${MESH:-0}" = "1" ]; then
     exec timeout -k 10 1800 env JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
