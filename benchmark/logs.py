@@ -74,8 +74,7 @@ RE_VERIFY_STATS = re.compile(
     r"(?: zc=(\d+) fb=(\d+))?"
 )
 # periodic per-node telemetry snapshot (telemetry/exporter.py) — a
-# cumulative JSON document superseding 'Work stats:'; keep the LAST
-# line per node log
+# cumulative JSON document; keep the LAST line per node log
 RE_TELEMETRY = re.compile(r"Telemetry snapshot: (\{.*\})")
 # health-plane incident transitions (telemetry/health.py HealthMonitor):
 # one JSON document per detector open/close, timestamped so the SLO

@@ -119,14 +119,14 @@ def make_train_claims(n: int, waves: int, scheme: str = "ed25519"):
 
 def waterfall(span_rows: list[tuple], e2e_ms: list[float]) -> dict:
     """Aggregate drained recorder rows ``(name, t0_ns, dur_ns, depth,
-    thread)`` against the externally measured per-wave ``e2e_ms``.
+    thread, ids)`` against the externally measured per-wave ``e2e_ms``.
 
     Returns {"e2e_ms": {p50, p99}, "stages": {name: {p50_ms, p99_ms,
     count, pct_of_e2e}}, "coverage_pct": float} — stages ordered and
     summed per spans.LEAF_STAGES; parent spans (e2e, dispatch.wall, ...)
     are reported but never counted toward coverage."""
     by_stage: dict[str, list[float]] = {}
-    for name, _t0, dur_ns, _depth, _thread in span_rows:
+    for name, _t0, dur_ns, *_ in span_rows:
         by_stage.setdefault(name, []).append(dur_ns / 1e6)
     e2e_p50 = _percentile(e2e_ms, 50)
     stages: dict[str, dict] = {}

@@ -7,8 +7,9 @@ EC2.  This harness produces the best evidence this environment allows:
 
 - an in-process sweep (one asyncio loop hosting the whole committee —
   OS scheduling excluded) with per-node work accounting
-  (utils/workstats.py: signature verifies, crypto wall time, event-loop
-  lag — the direct starvation signal);
+  (crypto/service.py VerifyWork: signature verifies, crypto wall time;
+  telemetry/hoststats.py: event-loop lag — the direct starvation
+  signal);
 - a decomposition table: measured TPS, aggregate crypto work, loop lag,
   and the per-(node, payload) protocol cost c = core_seconds /
   (payloads * nodes) — every node processes every block, so ONE core
@@ -33,28 +34,32 @@ from .local import LocalBench
 from .logs import LogParser
 from .utils import PathMaker, Print
 
-RE_WORKSTATS = re.compile(r"\[(?:[^]]*)\] (workstats\.[^ ]+) Work stats: (\{.*\})")
+RE_HOST_STATS = re.compile(r"Host stats: (.*)")
 RE_TELEMETRY = re.compile(r"Telemetry snapshot: (\{.*\})")
 
 
-def scrape_workstats(logs_dir: str) -> list[dict]:
-    """Last 'Work stats' JSON per node logger across the node logs."""
+def scrape_host_stats(logs_dir: str) -> list[dict]:
+    """Last 'Host stats' line (telemetry/hoststats.py: one a process,
+    cumulative ``key=value`` pairs) of each node log, as floats."""
     latest: dict[str, dict] = {}
     for path in sorted(glob(os.path.join(logs_dir, "node-*.log"))):
         with open(path) as f:
             for line in f:
-                m = RE_WORKSTATS.search(line)
+                m = RE_HOST_STATS.search(line)
                 if m:
-                    latest[m.group(1)] = json.loads(m.group(2))
+                    latest[path] = {
+                        k: float(v)
+                        for k, v in (
+                            item.split("=") for item in m.group(1).split()
+                        )
+                    }
     return list(latest.values())
 
 
 def scrape_telemetry(logs_dir: str) -> list[dict]:
     """Last 'Telemetry snapshot' document per node across the node logs.
-    The snapshot is a strict SUPERSET of the Work stats document (the
-    pinned telemetry contract), so callers read the same keys from
-    either — this scraper is preferred, scrape_workstats is the
-    fallback for old logs (ROADMAP follow-up)."""
+    It carries the node's verification work and the process's loop lag
+    at its top level (telemetry.SNAPSHOT_WORK_KEYS)."""
     latest: dict[tuple, dict] = {}
     for path in sorted(glob(os.path.join(logs_dir, "node-*.log"))):
         with open(path) as f:
@@ -77,11 +82,10 @@ def run_scaling(
     timeout_delay: int = 5_000,
     verifier: str = "cpu",
 ) -> str:
-    # Telemetry snapshots are the preferred work-accounting source (the
-    # superset document); HOTSTUFF_WORK_STATS stays on so the loop-lag
-    # probe runs AND old-style lines exist as the scrape fallback.
+    # Telemetry snapshots are the work-accounting source; the loop lag
+    # comes from every process's own 'Host stats' line, which is
+    # printed whether telemetry is on or off
     os.environ["HOTSTUFF_TELEMETRY"] = "1"
-    os.environ["HOTSTUFF_WORK_STATS"] = "1"
     rows = []
     try:
         for n in sizes:
@@ -94,11 +98,8 @@ def run_scaling(
                 verifier=verifier,
             )
             parser: LogParser = bench.run()
-            # prefer the telemetry snapshot document (same keys at top
-            # level); fall back cleanly when only Work stats lines exist
             stats = scrape_telemetry(PathMaker.logs_path())
-            if not stats:
-                stats = scrape_workstats(PathMaker.logs_path())
+            hosts = scrape_host_stats(PathMaker.logs_path())
             tps, window = parser.consensus_throughput()
             lat_s = parser.consensus_latency()
             payloads = parser.committed_payloads()
@@ -106,7 +107,7 @@ def run_scaling(
             verify_wall_s = (
                 sum(s.get("verify_wall_ms", 0.0) for s in stats) / 1e3
             )
-            lag_means = [s.get("loop_lag_mean_ms", 0.0) for s in stats]
+            lag_means = [h.get("lag_mean_ms", 0.0) for h in hosts]
             rows.append(
                 {
                     "nodes": n,
@@ -167,7 +168,6 @@ def run_scaling(
             )
     finally:
         os.environ.pop("HOTSTUFF_TELEMETRY", None)
-        os.environ.pop("HOTSTUFF_WORK_STATS", None)
     return format_report(rows, rate, duration, verifier=verifier)
 
 

@@ -23,6 +23,11 @@ conventions instead of generic style:
 - **guarded-by** — fields touched from both a dispatch-loop thread and
   the asyncio loop must carry a ``# guarded-by: <lock>`` annotation; a
   lockset walker checks annotated locks are actually held at writes.
+- **no-await-in-span** — no ``await`` / ``async for`` / ``async with`` /
+  ``yield`` lexically inside a ``with ...span(...)`` block
+  (``consensus/``, ``network/``, ``node/``, ``crypto/``, ``store/``): a
+  span lands in the profiler's trace, whose events on one thread must
+  nest, and 64 cores interleave on the loop thread at every ``await``.
 
 Escape hatches, in preference order: fix the finding; suppress one site
 with ``# lint: allow(<rule>)  -- <why>`` on (or directly above) the

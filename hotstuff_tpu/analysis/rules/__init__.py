@@ -4,6 +4,7 @@ from .blocking import NoBlockingInAsync
 from .clock_discipline import ClockDiscipline
 from .env_knobs import EnvKnobRegistry
 from .guarded_by import GuardedBy
+from .span_await import NoAwaitInSpan
 from .taxonomy_rule import TaxonomyRegistry
 from .wire_bounds import WireDecoderBounds
 
@@ -14,6 +15,7 @@ ALL_RULES = (
     EnvKnobRegistry(),
     GuardedBy(),
     ClockDiscipline(),
+    NoAwaitInSpan(),
 )
 
 __all__ = [
@@ -24,4 +26,5 @@ __all__ = [
     "EnvKnobRegistry",
     "GuardedBy",
     "ClockDiscipline",
+    "NoAwaitInSpan",
 ]

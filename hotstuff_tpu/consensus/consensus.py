@@ -23,6 +23,7 @@ from ..crypto.service import CpuVerifier, VerifierBackend
 from ..network import Receiver as NetworkReceiver
 from ..network import Writer
 from ..store import Store
+from ..telemetry import spans as _spans
 from .config import Committee, Parameters
 from .core import CONSENSUS_STATE_KEY, Core, make_event_channels
 from .errors import SerializationError
@@ -150,7 +151,11 @@ class ConsensusReceiverHandler:
         tx_state_sync: asyncio.Queue | None = None,
         state=None,
         committee=None,
+        node: str = "",
     ):
+        #: the ``node`` id of the receive path's spans; the listener
+        #: (network/receiver.py) labels its reply writes with it too
+        self.node = node
         self.tx_consensus = tx_consensus
         self.tx_helper = tx_helper
         self.tx_producer = tx_producer
@@ -213,7 +218,8 @@ class ConsensusReceiverHandler:
                 self._scheme_gen = gen
                 self.scheme = com.wire_scheme()
         try:
-            tag, payload = decode_message(message, scheme=self.scheme)
+            with _spans.span("net.decode", node=self.node):
+                tag, payload = decode_message(message, scheme=self.scheme)
         except SerializationError as e:
             log.warning("Dropping malformed message: %s", e)
             if self._dropped is not None:
@@ -279,19 +285,26 @@ class ConsensusReceiverHandler:
             await self.tx_consensus.put((tag, payload))
         elif tag == TAG_PRODUCER:
             digest, body = payload
-            if body:
-                # content addressing: a body that doesn't hash to its
-                # digest is a poisoned submission — drop it (no ACK)
-                from ..crypto import Digest
+            # one span a producer FRAME (a v2 frame carries a batch)
+            with _spans.span("ingest.admit", node=self.node):
+                if body:
+                    # content addressing: a body that doesn't hash to
+                    # its digest is a poisoned submission — drop it (no
+                    # ACK)
+                    from ..crypto import Digest
 
-                if Digest.of(body) != digest:
-                    log.warning(
-                        "Dropping producer payload whose body does not "
-                        "match its digest"
-                    )
-                    return
-            if self.admission is not None:
-                decision = self.admission.admit(1)
+                    if Digest.of(body) != digest:
+                        log.warning(
+                            "Dropping producer payload whose body does "
+                            "not match its digest"
+                        )
+                        return
+                decision = (
+                    self.admission.admit(1)
+                    if self.admission is not None
+                    else None
+                )
+            if decision is not None:
                 if decision.shed:
                     # typed BUSY instead of a silent drop: the legacy
                     # b"Ack" stays byte-compatible on the accept path,
@@ -322,23 +335,24 @@ class ConsensusReceiverHandler:
             # committee's window with garbage bodies)
             from ..crypto import Digest
 
-            valid = []
-            for digest, body in payload:
-                if body and Digest.of(body) != digest:
-                    log.warning(
-                        "Dropping batched producer payload whose body "
-                        "does not match its digest"
-                    )
-                    if self._dropped is not None:
-                        self._dropped.inc()
-                    continue
-                valid.append((digest, body))
-            if self.admission is not None:
-                decision = self.admission.admit(len(valid))
-            else:
-                from ..ingest import Decision
+            with _spans.span("ingest.admit", node=self.node):
+                valid = []
+                for digest, body in payload:
+                    if body and Digest.of(body) != digest:
+                        log.warning(
+                            "Dropping batched producer payload whose body "
+                            "does not match its digest"
+                        )
+                        if self._dropped is not None:
+                            self._dropped.inc()
+                        continue
+                    valid.append((digest, body))
+                if self.admission is not None:
+                    decision = self.admission.admit(len(valid))
+                else:
+                    from ..ingest import Decision
 
-                decision = Decision(len(valid), 0, 0, 0)
+                    decision = Decision(len(valid), 0, 0, 0)
             # the accepted prefix enters; the shed suffix is the
             # client's to resubmit after retry_after_ms (order is
             # preserved on the wire, so "first N" is well-defined)
@@ -398,25 +412,26 @@ class ConsensusReceiverHandler:
         if j is not None and spans:
             # sampled: the batch's first digest stands for the frame
             j.record("recv.producer", 0, Digest(bytes(digests[:32])), "client")
-        valid = []
-        for i, (off, ln) in enumerate(spans):
-            digest = Digest(bytes(digests[i * 32 : (i + 1) * 32]))
-            body = mv[off : off + ln]
-            if ln and Digest.of(body) != digest:
-                log.warning(
-                    "Dropping batched producer payload whose body "
-                    "does not match its digest"
-                )
-                if self._dropped is not None:
-                    self._dropped.inc()
-                continue
-            valid.append((digest, body))
-        if self.admission is not None:
-            decision = self.admission.admit(len(valid))
-        else:
-            from ..ingest import Decision
+        with _spans.span("ingest.admit", node=self.node):
+            valid = []
+            for i, (off, ln) in enumerate(spans):
+                digest = Digest(bytes(digests[i * 32 : (i + 1) * 32]))
+                body = mv[off : off + ln]
+                if ln and Digest.of(body) != digest:
+                    log.warning(
+                        "Dropping batched producer payload whose body "
+                        "does not match its digest"
+                    )
+                    if self._dropped is not None:
+                        self._dropped.inc()
+                    continue
+                valid.append((digest, body))
+            if self.admission is not None:
+                decision = self.admission.admit(len(valid))
+            else:
+                from ..ingest import Decision
 
-            decision = Decision(len(valid), 0, 0, 0)
+                decision = Decision(len(valid), 0, 0, 0)
         for digest, body in valid[: decision.accepted]:
             if len(body) and self.bodies is not None:
                 await self.bodies.admit(digest, bytes(body))
@@ -501,6 +516,7 @@ class Consensus:
         committee.verify_pops()
         if verifier is None:
             verifier = CpuVerifier()
+        node_id = str(name)[:8]  # the ``node`` id of this stack's spans
 
         payload_bodies = PayloadBodies(store, parameters.payload_body_budget)
         # Replicated execution layer (store/state.py): the commit path
@@ -719,6 +735,7 @@ class Consensus:
                     link_delay=link_delay,
                     fault_plane=fault_plane,
                     flows=flows,
+                    node=node_id,
                 )
 
             def make_reliable():
@@ -726,6 +743,7 @@ class Consensus:
                     link_delay=link_delay,
                     fault_plane=fault_plane,
                     flows=flows,
+                    node=node_id,
                 )
         else:
             from ..network import ReliableSender, SimpleSender
@@ -746,6 +764,7 @@ class Consensus:
                     max_conns=max_conns,
                     fault_plane=fault_plane,
                     flows=flows,
+                    node=node_id,
                 )
 
             def make_reliable():
@@ -754,6 +773,7 @@ class Consensus:
                     max_conns=max_conns,
                     fault_plane=fault_plane,
                     flows=flows,
+                    node=node_id,
                 )
         self.receiver = receiver_cls(
             bind_host,
@@ -769,6 +789,7 @@ class Consensus:
                 tx_state_sync=tx_state_sync,
                 state=state_machine,
                 committee=committee,
+                node=node_id,
             ),
             fault_plane=fault_plane,
             flows=flows,

@@ -32,6 +32,7 @@ from ..crypto.async_service import AsyncVerifyService
 from ..crypto.service import VerifierBackend
 from ..network import SimpleSender
 from ..store import Store
+from ..telemetry import spans as _spans
 from ..utils.clock import default_clock
 from ..utils.codec import Decoder, Encoder
 from .aggregator import ROUND_LOOKAHEAD, Aggregator
@@ -401,6 +402,8 @@ class Core:
         # flight recorder (telemetry/journal.py): same guard discipline —
         # journaling off means one attribute test per site and no writes
         self._journal = telemetry.journal if telemetry is not None else None
+        #: the ``node`` id of this core's spans (telemetry/spans.py)
+        self._node = str(name)[:8]
         if telemetry is not None:
             telemetry.gauge(
                 "core_round", "Current consensus round", fn=lambda: self.round
@@ -451,16 +454,19 @@ class Core:
         self.log.info("Recovered consensus state at round %d", self.round)
 
     async def persist_state(self) -> None:
-        state = ConsensusState(
-            self.round,
-            self.last_voted_round,
-            self.last_committed_round,
-            self.high_qc,
-        )
-        await self.store.write(CONSENSUS_STATE_KEY, state.serialize())
+        with _spans.span("core.persist", node=self._node, round=self.round):
+            data = ConsensusState(
+                self.round,
+                self.last_voted_round,
+                self.last_committed_round,
+                self.high_qc,
+            ).serialize()
+        await self.store.write(CONSENSUS_STATE_KEY, data)
 
     async def store_block(self, block: Block) -> None:
-        await self.store.write(block.digest().to_bytes(), block.serialize())
+        with _spans.span("core.persist", node=self._node, round=block.round):
+            key, data = block.digest().to_bytes(), block.serialize()
+        await self.store.write(key, data)
 
         # Maintain the per-round payload index + latest-round key the
         # proposer's payload buffering feeds on (core.rs:117-148).
@@ -468,19 +474,24 @@ class Core:
         latest = int.from_bytes(latest_raw, "big") if latest_raw else 0
         if latest == block.round:
             raw = await self.store.read(round_key(block.round))
-            payloads = decode_payload_index(raw) if raw else []
-            known = set(payloads)
-            for p in block.payloads:
-                if p not in known:
-                    known.add(p)
-                    payloads.append(p)
+            with _spans.span(
+                "core.persist", node=self._node, round=block.round
+            ):
+                payloads = decode_payload_index(raw) if raw else []
+                known = set(payloads)
+                for p in block.payloads:
+                    if p not in known:
+                        known.add(p)
+                        payloads.append(p)
         elif latest < block.round:
             payloads = list(block.payloads)
         else:
             self.log.warning("The block round is less than the last round")
             return
-        await self.store.write(round_key(block.round), encode_payload_index(payloads))
-        await self.store.write(LATEST_ROUND_KEY, round_key(block.round))
+        with _spans.span("core.persist", node=self._node, round=block.round):
+            key, index = round_key(block.round), encode_payload_index(payloads)
+        await self.store.write(key, index)
+        await self.store.write(LATEST_ROUND_KEY, key)
 
     # ---- voting and committing ---------------------------------------------
 
@@ -507,10 +518,10 @@ class Core:
         self._increase_last_voted_round(block.round)
         await self.persist_state()
         self.state_changed = False
-        vote = Vote.for_block(block, self.name)
-        vote.signature = await self.signature_service.request_signature(
-            vote.digest()
-        )
+        with _spans.span("core.vote.make", node=self._node, round=block.round):
+            vote = Vote.for_block(block, self.name)
+            digest = vote.digest()
+        vote.signature = await self.signature_service.request_signature(digest)
         return vote
 
     async def _commit(self, block: Block, cert_qc: QC) -> None:
@@ -552,54 +563,60 @@ class Core:
         committed_payloads: set = set()
         for b, cqc in zip(reversed(to_commit), reversed(cert_qcs)):
             await self.tx_commit.put(b)
-            committed_payloads.update(b.payloads)
-            if self._trace is not None:
-                self._trace.mark_committed(b.digest().to_bytes(), b.round)
-            if self._journal is not None:
-                self._journal.record("commit", b.round, b.digest())
-            # NOTE: this log entry is used to compute performance.
-            # One info line per block in the chain walk — a DELIBERATE
-            # divergence from the reference, which info-logs only the
-            # head and debug-logs the rest (core.rs:204-209): head-only
-            # logging hides the other blocks' payloads from the harness
-            # and undercounts TPS after every view change.
-            reported = b.digest()
-            shadow = None
-            adversary = self.adversary
-            if (
-                adversary is not None
-                and adversary.is_shadow_committer
-                and adversary.active("collude")
-                and b.author in adversary.colluder_names
-            ):
-                # collude policy: the designated shadow committer
-                # reports the shadow branch for colluder-led rounds —
-                # a REAL divergent history the safety checker must
-                # catch and attribute to the colluding authorities
-                shadow = adversary.shadow_block(b).digest()
-                reported = shadow
-                adversary.count("byz_shadow_commits")
-                adversary.record("shadow-commit", b.round, reported)
-                self.log.info(
-                    "byz shadow-commit round %d -> %s", b.round, reported
-                )
-            self.log.info("Committed block %d -> %s", b.round, reported)
-            if self.state is not None:
-                # execution layer: apply in commit order; the REPORTED
-                # root chains over the reported (possibly shadow)
-                # digests, so a colluder's claimed state diverges
-                # exactly where its claimed digest log does
-                root = self.state.apply_block(b, reported_digest=shadow)
-                if root is not None:
-                    if self._journal is not None:
-                        self._journal.record("state.apply", b.round, b.digest())
-                    # NOTE: this log entry is used to compute performance.
+            with _spans.span("core.commit", node=self._node, round=b.round):
+                committed_payloads.update(b.payloads)
+                if self._trace is not None:
+                    self._trace.mark_committed(b.digest().to_bytes(), b.round)
+                if self._journal is not None:
+                    self._journal.record("commit", b.round, b.digest())
+                # NOTE: this log entry is used to compute performance.
+                # One info line per block in the chain walk — a DELIBERATE
+                # divergence from the reference, which info-logs only the
+                # head and debug-logs the rest (core.rs:204-209): head-only
+                # logging hides the other blocks' payloads from the harness
+                # and undercounts TPS after every view change.
+                reported = b.digest()
+                shadow = None
+                adversary = self.adversary
+                if (
+                    adversary is not None
+                    and adversary.is_shadow_committer
+                    and adversary.active("collude")
+                    and b.author in adversary.colluder_names
+                ):
+                    # collude policy: the designated shadow committer
+                    # reports the shadow branch for colluder-led rounds —
+                    # a REAL divergent history the safety checker must
+                    # catch and attribute to the colluding authorities
+                    shadow = adversary.shadow_block(b).digest()
+                    reported = shadow
+                    adversary.count("byz_shadow_commits")
+                    adversary.record("shadow-commit", b.round, reported)
                     self.log.info(
-                        "State root %d -> %s (round %d)",
-                        self.state.version,
-                        Digest(root),
-                        b.round,
+                        "byz shadow-commit round %d -> %s", b.round, reported
                     )
+                self.log.info("Committed block %d -> %s", b.round, reported)
+                if self.state is not None:
+                    # execution layer: apply in commit order; the REPORTED
+                    # root chains over the reported (possibly shadow)
+                    # digests, so a colluder's claimed state diverges
+                    # exactly where its claimed digest log does
+                    with _spans.span(
+                        "store.apply", node=self._node, round=b.round
+                    ):
+                        root = self.state.apply_block(
+                            b, reported_digest=shadow
+                        )
+                    if root is not None:
+                        if self._journal is not None:
+                            self._journal.record("state.apply", b.round, b.digest())
+                        # NOTE: this log entry is used to compute performance.
+                        self.log.info(
+                            "State root %d -> %s (round %d)",
+                            self.state.version,
+                            Digest(root),
+                            b.round,
+                        )
             if b.reconfig is not None:
                 await self._apply_reconfig(b, cqc)
         # Tell the proposer what committed: (a) it prunes those digests
@@ -611,15 +628,15 @@ class Core:
         # payloads of orphaned blocks return to the buffer (orphan
         # recovery; the reference instead drops whole per-round buckets
         # on cleanup, proposer.rs:164-173, losing them entirely).
-        if self.payload_bodies is not None:
-            self.payload_bodies.mark_committed(committed_payloads)
-        await self.tx_proposer.put(
-            ProposerMessage.cleanup(
+        with _spans.span("core.commit", node=self._node, round=block.round):
+            if self.payload_bodies is not None:
+                self.payload_bodies.mark_committed(committed_payloads)
+            cleanup = ProposerMessage.cleanup(
                 [],
                 payloads=committed_payloads,
                 committed_round=self.last_committed_round,
             )
-        )
+        await self.tx_proposer.put(cleanup)
 
     def _update_high_qc(self, qc: QC) -> None:
         if qc.round > self.high_qc.round:
@@ -854,18 +871,24 @@ class Core:
         # Accumulate-then-dispatch: authority/stake checks happen on entry;
         # signatures were either pre-verified by the burst preverifier
         # (sig_verified) or batch-verified at quorum inside the aggregator.
-        qc = self.aggregator.add_vote(vote, self.round, sig_verified=sig_verified)
-        if qc is not None:
-            self.log.debug("Assembled %r", qc)
-            # qc.form marks the FORMATION moment at the assembling node
-            # (quorum-th vote folded in), distinct from the ``qc`` edge
-            # which marks high-QC adoption — the critical-path engine
-            # (telemetry/critpath.py) attributes agg.form from it
-            if self._journal is not None and not qc.is_genesis():
-                self._journal.record("qc.form", qc.round, qc.hash)
-            self._process_qc(qc)
-            if self.name == self.leader_elector.get_leader(self.round):
-                await self._generate_proposal(None)
+        with _spans.span("core.vote", node=self._node, round=vote.round):
+            qc = self.aggregator.add_vote(
+                vote, self.round, sig_verified=sig_verified
+            )
+            if qc is not None:
+                self.log.debug("Assembled %r", qc)
+                # qc.form marks the FORMATION moment at the assembling
+                # node (quorum-th vote folded in), distinct from the
+                # ``qc`` edge which marks high-QC adoption — the
+                # critical-path engine (telemetry/critpath.py)
+                # attributes agg.form from it
+                if self._journal is not None and not qc.is_genesis():
+                    self._journal.record("qc.form", qc.round, qc.hash)
+                self._process_qc(qc)
+        if qc is not None and self.name == self.leader_elector.get_leader(
+            self.round
+        ):
+            await self._generate_proposal(None)
 
     def _qc_cache(self) -> set:
         if len(self._verified_qcs) > 4_096:
@@ -1114,8 +1137,12 @@ class Core:
                         self.timer.duration * 1e3,
                     )
                     await default_clock().sleep(delay)
-                address = self.committee.address(next_leader)
-                await self.network.send(address, encode_vote(vote))
+                with _spans.span(
+                    "core.vote.make", node=self._node, round=block.round
+                ):
+                    address = self.committee.address(next_leader)
+                    frame = encode_vote(vote)
+                await self.network.send(address, frame)
             if adversary is not None and adversary.active("double-vote"):
                 await self._byz_double_vote(block, next_leader)
         if adversary is not None and adversary.active("forge-qc"):
@@ -1178,19 +1205,20 @@ class Core:
     async def _handle_proposal(
         self, block: Block, sigs_verified: bool = False
     ) -> None:
-        digest = block.digest()
-        expected = self.leader_elector.get_leader(block.round)
-        if block.author != expected:
-            raise WrongLeader(digest, block.author, block.round)
-        block.verify(
-            self.committee,
-            self.verifier,
-            qc_cache=self._qc_cache(),
-            sigs_verified=sigs_verified,
-        )
-        self._process_qc(block.qc)
-        if block.tc is not None:
-            self._advance_round(block.tc.round, via_tc=True)
+        with _spans.span("core.proposal", node=self._node, round=block.round):
+            digest = block.digest()
+            expected = self.leader_elector.get_leader(block.round)
+            if block.author != expected:
+                raise WrongLeader(digest, block.author, block.round)
+            block.verify(
+                self.committee,
+                self.verifier,
+                qc_cache=self._qc_cache(),
+                sigs_verified=sigs_verified,
+            )
+            self._process_qc(block.qc)
+            if block.tc is not None:
+                self._advance_round(block.tc.round, via_tc=True)
         await self._process_block(block)
 
     async def _handle_tc(self, tc: TC, sigs_verified: bool = False) -> None:
@@ -1343,58 +1371,59 @@ class Core:
             # per-burst checks — typically 1-2 signatures each — would
             # run ~3 small batch equations where quorum time runs one.
             collectors[TAG_VOTE] = collect_vote
-        for idx, (tag, payload) in enumerate(burst):
-            if tag == TAG_TIMEOUT:
-                if (
-                    # same lookahead bound as add_timeout: far-future
-                    # timeouts are a free rejection, not crypto work
-                    self.round
-                    <= payload.round
-                    <= self.round + ROUND_LOOKAHEAD
-                    # committee membership BEFORE aggregation — the
-                    # soundness precondition above
-                    and self.committee.for_round(payload.round).stake(
-                        payload.author
-                    )
-                    > 0
-                ):
-                    timeout_groups.setdefault(payload.digest(), []).append(
-                        (idx, payload)
-                    )
-            elif tag in collectors:
-                try:
-                    collectors[tag](idx, payload)
-                except ConsensusError:
-                    # a structural rule failed (e.g. a sub-quorum
-                    # embedded QC): collect nothing — the handler's
-                    # full sync verify rejects it with the proper error
-                    continue
+        with _spans.span("core.claims", node=self._node, round=self.round):
+            for idx, (tag, payload) in enumerate(burst):
+                if tag == TAG_TIMEOUT:
+                    if (
+                        # same lookahead bound as add_timeout: far-future
+                        # timeouts are a free rejection, not crypto work
+                        self.round
+                        <= payload.round
+                        <= self.round + ROUND_LOOKAHEAD
+                        # committee membership BEFORE aggregation — the
+                        # soundness precondition above
+                        and self.committee.for_round(payload.round).stake(
+                            payload.author
+                        )
+                        > 0
+                    ):
+                        timeout_groups.setdefault(payload.digest(), []).append(
+                            (idx, payload)
+                        )
+                elif tag in collectors:
+                    try:
+                        collectors[tag](idx, payload)
+                    except ConsensusError:
+                        # a structural rule failed (e.g. a sub-quorum
+                        # embedded QC): collect nothing — the handler's
+                        # full sync verify rejects it with the proper error
+                        continue
 
-        for digest, members in timeout_groups.items():
-            if len(members) == 1:
-                idx0, t = members[0]
-                author_claim = (
-                    "one",
-                    digest.to_bytes(),
-                    t.author.to_bytes(),
-                    t.signature.to_bytes(),
-                )
-            else:
-                author_claim = (
-                    "shared",
-                    digest.to_bytes(),
-                    tuple(
-                        (t.author.to_bytes(), t.signature.to_bytes())
-                        for _, t in members
-                    ),
-                )
-            claims.setdefault(author_claim, None)
-            for idx, t in members:
-                try:
-                    keys = [author_claim] + add_qc_claims(t.high_qc)
-                except ConsensusError:
-                    continue  # sub-quorum high_qc: leave to the handler
-                per_msg.append((idx, keys))
+            for digest, members in timeout_groups.items():
+                if len(members) == 1:
+                    idx0, t = members[0]
+                    author_claim = (
+                        "one",
+                        digest.to_bytes(),
+                        t.author.to_bytes(),
+                        t.signature.to_bytes(),
+                    )
+                else:
+                    author_claim = (
+                        "shared",
+                        digest.to_bytes(),
+                        tuple(
+                            (t.author.to_bytes(), t.signature.to_bytes())
+                            for _, t in members
+                        ),
+                    )
+                claims.setdefault(author_claim, None)
+                for idx, t in members:
+                    try:
+                        keys = [author_claim] + add_qc_claims(t.high_qc)
+                    except ConsensusError:
+                        continue  # sub-quorum high_qc: leave to the handler
+                    per_msg.append((idx, keys))
 
         if not claims:
             return set()
@@ -1411,13 +1440,14 @@ class Core:
                 e,
             )
             return set()
-        verdict = dict(zip(ordered, results))
-        for claim, key in qc_memo.items():
-            if verdict.get(claim):
-                cache.add(key)
-        return {
-            idx for idx, keys in per_msg if all(verdict[k] for k in keys)
-        }
+        with _spans.span("core.claims", node=self._node, round=self.round):
+            verdict = dict(zip(ordered, results))
+            for claim, key in qc_memo.items():
+                if verdict.get(claim):
+                    cache.add(key)
+            return {
+                idx for idx, keys in per_msg if all(verdict[k] for k in keys)
+            }
 
     async def _dispatch(self, tagged, sig_verified: bool = False) -> None:
         """``sig_verified=True``: every signature claim this message

@@ -38,6 +38,7 @@ from collections import OrderedDict
 
 from ..crypto import Digest, PublicKey, SignatureService
 from ..network import ReliableSender
+from ..telemetry import spans as _spans
 from ..utils.clock import default_clock
 from .config import Committee
 from .core import ProposerMessage
@@ -138,6 +139,7 @@ class Proposer:
         self.network = network if network is not None else ReliableSender()
         self._task: asyncio.Task | None = None
         self.log = logging.getLogger(f"{__name__}.{str(name)[:8]}")
+        self._node = str(name)[:8]  # the ``node`` id of its spans
         # Telemetry (optional): payload buffer dwell time + buffer
         # occupancy.  With telemetry on, `pending` values hold the
         # arrival timestamp (read at make time); off, they stay None —
@@ -186,105 +188,106 @@ class Proposer:
     async def _make_block(
         self, round_: Round, qc: QC, tc: TC | None, allow_empty: bool = False
     ) -> None:
-        if round_ <= self.last_made_round:
-            return  # already proposed for this round (equivocation guard)
-        op = self.pending_reconfig
-        if op is not None and newest_epoch(self.committee) >= op.new_committee.epoch:
-            # the epoch change is already scheduled (committed via
-            # another leader's block, or a competing op won): drop ours
-            self.pending_reconfig = None
-            op = None
-        snipes = (
-            self.adversary.wants("reconfig", round_)
-            if op is None and self.adversary is not None else False
-        )
-        if snipes:
-            # reconfig policy (forge half): attach a forged epoch change
-            # — well-formed wire, hostile committee / bad sponsor — that
-            # MUST die in every honest voter's Block.verify.  The
-            # reconfig-sniper mounts the same forgery, but only inside
-            # the epoch-activation margin (wants returns its token).
-            op = self.adversary.forged_reconfig(self.committee, round_)
-            if op is not None:
-                self.adversary.mark_adaptive(snipes, round_, self.log)
-                self.adversary.count("byz_forged_reconfigs")
-                self.adversary.record("reconfig-forge", round_)
-                self.log.info("byz reconfig-forge round %d", round_)
-        if not self.pending and not allow_empty and op is None:
-            # Defer: fire the moment the next payload arrives instead of
-            # wedging the round until the view-change timer (see module
-            # docstring).  A newer Make supersedes this one.
-            self.deferred = ProposerMessage.make(round_, qc, tc)
-            if self._deferred_makes is not None:
-                self._deferred_makes.inc()
-            self.log.info("Round: %d, no payloads yet - proposal deferred", round_)
-            return
-        # allow_empty: the core signalled that uncommitted payload blocks
-        # are in flight — an empty block advances the 2-chain so they
-        # commit now rather than on the producer's next burst.
-        self.last_made_round = round_
-        take = min(len(self.pending), MAX_BLOCK_PAYLOADS)
-        if self._payload_wait is not None and take:
-            now = default_clock().monotonic()
-            popped = [self.pending.popitem(last=False) for _ in range(take)]
-            for _, arrived in popped:
-                if arrived:  # re-buffered orphans may carry None
-                    self._payload_wait.observe(now - arrived)
-            payloads = tuple(d for d, _ in popped)
-        else:
-            payloads = tuple(
-                self.pending.popitem(last=False)[0] for _ in range(take)
+        with _spans.span("proposer.make", node=self._node, round=round_):
+            if round_ <= self.last_made_round:
+                return  # already proposed for this round (equivocation guard)
+            op = self.pending_reconfig
+            if op is not None and newest_epoch(self.committee) >= op.new_committee.epoch:
+                # the epoch change is already scheduled (committed via
+                # another leader's block, or a competing op won): drop ours
+                self.pending_reconfig = None
+                op = None
+            snipes = (
+                self.adversary.wants("reconfig", round_)
+                if op is None and self.adversary is not None else False
             )
-        if payloads:
-            self.inflight[round_] = payloads
-            while len(self.inflight) > MAX_INFLIGHT:
-                self._requeue_oldest_inflight()
-
-        if op is not None and op is self.pending_reconfig:
-            self.pending_reconfig = None  # it rides in this block
-        block = Block(
-            qc=qc, tc=tc, author=self.name, round=round_, payloads=payloads,
-            reconfig=op,
-        )
-        block.signature = await self.signature_service.request_signature(
-            block.digest()
-        )
-        if op is not None:
-            self.log.info(
-                "Proposing reconfig in block %d: epoch %d (margin %d)",
-                round_, op.new_committee.epoch, op.margin,
-            )
-        # NOTE: this log entry is used to compute performance — the harness
-        # maps each payload -> block digest from it (benchmark/logs.py
-        # contract).
-        self.log.info(
-            "Created block %d (payloads %s) -> %s",
-            block.round,
-            ",".join(str(p) for p in block.payloads),
-            block.digest(),
-        )
-        if self._journal is not None:
-            # the propose record is the timeline anchor traces.py hangs
-            # every recv.propose edge off — journaled just before the
-            # broadcast leaves this node
-            self._journal.record("propose", block.round, block.digest())
-            if block.payloads:
-                # producer-channel edge (ROADMAP PR 2 follow-up): pairs
-                # with the receiver's recv.producer record so traces
-                # can measure payload-wait (client frame -> proposed)
-                # and chaos runs can tell payload starvation from
-                # consensus stall
-                self._journal.record(
-                    "payload.first", block.round, block.payloads[0]
+            if snipes:
+                # reconfig policy (forge half): attach a forged epoch change
+                # — well-formed wire, hostile committee / bad sponsor — that
+                # MUST die in every honest voter's Block.verify.  The
+                # reconfig-sniper mounts the same forgery, but only inside
+                # the epoch-activation margin (wants returns its token).
+                op = self.adversary.forged_reconfig(self.committee, round_)
+                if op is not None:
+                    self.adversary.mark_adaptive(snipes, round_, self.log)
+                    self.adversary.count("byz_forged_reconfigs")
+                    self.adversary.record("reconfig-forge", round_)
+                    self.log.info("byz reconfig-forge round %d", round_)
+            if not self.pending and not allow_empty and op is None:
+                # Defer: fire the moment the next payload arrives instead of
+                # wedging the round until the view-change timer (see module
+                # docstring).  A newer Make supersedes this one.
+                self.deferred = ProposerMessage.make(round_, qc, tc)
+                if self._deferred_makes is not None:
+                    self._deferred_makes.inc()
+                self.log.info("Round: %d, no payloads yet - proposal deferred", round_)
+                return
+            # allow_empty: the core signalled that uncommitted payload blocks
+            # are in flight — an empty block advances the 2-chain so they
+            # commit now rather than on the producer's next burst.
+            self.last_made_round = round_
+            take = min(len(self.pending), MAX_BLOCK_PAYLOADS)
+            if self._payload_wait is not None and take:
+                now = default_clock().monotonic()
+                popped = [self.pending.popitem(last=False) for _ in range(take)]
+                for _, arrived in popped:
+                    if arrived:  # re-buffered orphans may carry None
+                        self._payload_wait.observe(now - arrived)
+                payloads = tuple(d for d, _ in popped)
+            else:
+                payloads = tuple(
+                    self.pending.popitem(last=False)[0] for _ in range(take)
                 )
+            if payloads:
+                self.inflight[round_] = payloads
+                while len(self.inflight) > MAX_INFLIGHT:
+                    self._requeue_oldest_inflight()
 
-        # Broadcast to the union of epochs (committee.broadcast_addresses
-        # is the union on a CommitteeSchedule — members of the adjacent
-        # epoch need boundary blocks too); ACK stake counts only under
-        # the BLOCK round's committee.
-        com = self.committee.for_round(round_)
-        names_addresses = self.committee.broadcast_addresses(self.name)
-        message = encode_propose(block)
+            if op is not None and op is self.pending_reconfig:
+                self.pending_reconfig = None  # it rides in this block
+            block = Block(
+                qc=qc, tc=tc, author=self.name, round=round_, payloads=payloads,
+                reconfig=op,
+            )
+            digest = block.digest()
+        block.signature = await self.signature_service.request_signature(digest)
+        with _spans.span("proposer.make", node=self._node, round=round_):
+            if op is not None:
+                self.log.info(
+                    "Proposing reconfig in block %d: epoch %d (margin %d)",
+                    round_, op.new_committee.epoch, op.margin,
+                )
+            # NOTE: this log entry is used to compute performance — the harness
+            # maps each payload -> block digest from it (benchmark/logs.py
+            # contract).
+            self.log.info(
+                "Created block %d (payloads %s) -> %s",
+                block.round,
+                ",".join(str(p) for p in block.payloads),
+                block.digest(),
+            )
+            if self._journal is not None:
+                # the propose record is the timeline anchor traces.py hangs
+                # every recv.propose edge off — journaled just before the
+                # broadcast leaves this node
+                self._journal.record("propose", block.round, block.digest())
+                if block.payloads:
+                    # producer-channel edge (ROADMAP PR 2 follow-up): pairs
+                    # with the receiver's recv.producer record so traces
+                    # can measure payload-wait (client frame -> proposed)
+                    # and chaos runs can tell payload starvation from
+                    # consensus stall
+                    self._journal.record(
+                        "payload.first", block.round, block.payloads[0]
+                    )
+
+            # Broadcast to the union of epochs (committee.broadcast_addresses
+            # is the union on a CommitteeSchedule — members of the adjacent
+            # epoch need boundary blocks too); ACK stake counts only under
+            # the BLOCK round's committee.
+            com = self.committee.for_round(round_)
+            names_addresses = self.committee.broadcast_addresses(self.name)
+            message = encode_propose(block)
         # broadcast() (not a per-peer send loop) so flow accounting
         # charges ONE logical propose per proposal: the wire/logical
         # ratio is the leader amplification factor (== n-1 here).
@@ -438,10 +441,13 @@ class Proposer:
                     # lint: allow(no-blocking-in-async) -- guarded by
                     # membership in asyncio.wait's done set
                     digest = prod_task.result()
-                    self._buffer_payload(digest)
-                    # drain any burst backlog without extra loop passes
-                    while not self.rx_producer.empty():
-                        self._buffer_payload(self.rx_producer.get_nowait())
+                    with _spans.span("ingest.buffer", node=self._node):
+                        self._buffer_payload(digest)
+                        # drain any burst backlog without extra loop passes
+                        while not self.rx_producer.empty():
+                            self._buffer_payload(
+                                self.rx_producer.get_nowait()
+                            )
                     prod_task = asyncio.ensure_future(self.rx_producer.get())
                     if self.deferred is not None and self.pending:
                         make = self.deferred
@@ -474,30 +480,31 @@ class Proposer:
                             self.deferred = None
                             await self._make_block(make.round, make.qc, make.tc)
                     else:
-                        # Cleanup(rounds): the chain advanced through these
-                        # rounds — a deferred make for an older round is
-                        # stale (the core will issue a fresh Make when this
-                        # node next leads).
-                        if (
-                            self.deferred is not None
-                            and message.rounds
-                            and self.deferred.round <= max(message.rounds)
-                        ):
-                            self.deferred = None
-                        # Cleanup(payloads): these digests committed (in
-                        # anyone's block) — proposing them again would
-                        # waste block capacity on duplicates.  They stay
-                        # in `seen` so a re-delivered copy is not
-                        # re-buffered either.
-                        if self.admission is not None and message.payloads:
-                            # drain signal for the ingest credit window
-                            self.admission.on_committed(len(message.payloads))
-                        for digest in message.payloads:
-                            self.pending.pop(digest, None)
-                            self.committed_seen[digest] = None
-                        while len(self.committed_seen) > SEEN_CAP:
-                            self.committed_seen.popitem(last=False)
-                        self._resolve_inflight(message)
+                        with _spans.span("proposer.cleanup", node=self._node):
+                            # Cleanup(rounds): the chain advanced through these
+                            # rounds — a deferred make for an older round is
+                            # stale (the core will issue a fresh Make when this
+                            # node next leads).
+                            if (
+                                self.deferred is not None
+                                and message.rounds
+                                and self.deferred.round <= max(message.rounds)
+                            ):
+                                self.deferred = None
+                            # Cleanup(payloads): these digests committed (in
+                            # anyone's block) — proposing them again would
+                            # waste block capacity on duplicates.  They stay
+                            # in `seen` so a re-delivered copy is not
+                            # re-buffered either.
+                            if self.admission is not None and message.payloads:
+                                # drain signal for the ingest credit window
+                                self.admission.on_committed(len(message.payloads))
+                            for digest in message.payloads:
+                                self.pending.pop(digest, None)
+                                self.committed_seen[digest] = None
+                            while len(self.committed_seen) > SEEN_CAP:
+                                self.committed_seen.popitem(last=False)
+                            self._resolve_inflight(message)
                     msg_task = asyncio.ensure_future(self.rx_message.get())
         finally:
             prod_task.cancel()

@@ -27,6 +27,7 @@ import logging
 from ..crypto import Digest, PublicKey
 from ..network import SimpleSender
 from ..store import Store
+from ..telemetry import spans as _spans
 from ..utils.clock import default_clock
 from .config import Committee
 from .errors import SerializationError
@@ -58,6 +59,7 @@ class Synchronizer:
         self._journal = telemetry.journal if telemetry is not None else None
 
         self.log = logging.getLogger(f"{__name__}.{str(name)[:8]}")
+        self._node = str(name)[:8]  # the ``node`` id of its spans
         self._pending: set[Digest] = set()  # child digests being synced
         # parent digest -> (first-ask time, child round, parent round):
         # the rounds make the retry broadcast epoch-targeted
@@ -204,10 +206,13 @@ class Synchronizer:
             return Block.genesis()
         data = await self.store.read(block.parent.to_bytes())
         if data is not None:
-            try:
-                return Block.deserialize(data)
-            except Exception as e:
-                raise SerializationError(f"corrupt block in store: {e}") from e
+            with _spans.span("core.ancestors", node=self._node):
+                try:
+                    return Block.deserialize(data)
+                except Exception as e:
+                    raise SerializationError(
+                        f"corrupt block in store: {e}"
+                    ) from e
         if block.qc.round <= max(floor, self.join_floor):
             return Block.genesis()
         await self._request_parent(block)

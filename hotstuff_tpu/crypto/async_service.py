@@ -1028,17 +1028,24 @@ class AsyncVerifyService:
             )
             return out
 
-        loop = asyncio.get_running_loop()
-        fut: asyncio.Future = loop.create_future()
-        self._pending.append((claims, fut))
-        if _spans.recorder() is not None:
-            self._arrivals.append(time.perf_counter_ns())
-        if self._task is None or self._task.done():
-            # the dispatcher task drains all pending batches then exits —
-            # no long-lived task to leak across loops or shutdowns
-            self._task = loop.create_task(
-                self._run(), name="verify-dispatcher"
-            )
+        if not self._pending:
+            # a wave is named when its batch gets its first claim, so
+            # every span from this submit to the verdicts' delivery
+            # carries the one serial
+            self._wave_serial += 1
+        with _spans.span("verify.submit", wave=self._wave_serial):
+            loop = asyncio.get_running_loop()
+            fut: asyncio.Future = loop.create_future()
+            self._pending.append((claims, fut))
+            if _spans.recorder() is not None:
+                self._arrivals.append(time.perf_counter_ns())
+            if self._task is None or self._task.done():
+                # the dispatcher task drains all pending batches then
+                # exits — no long-lived task to leak across loops or
+                # shutdowns
+                self._task = loop.create_task(
+                    self._run(), name="verify-dispatcher"
+                )
         return await fut
 
     # ---- the dispatcher ----------------------------------------------------
@@ -1198,10 +1205,12 @@ class AsyncVerifyService:
         measure_only: bool = False,
         deadline: float | None = None,
         wave: "AdoptedWave | None" = None,
+        serial: int = 0,
     ):
         """Start a device dispatch on the dedicated dispatch loop and
         register it in the in-flight table (occupancy + deadline stamp
-        drive routing).  The slot thread delivers completion back to the
+        drive routing) under ``serial``, the wave's serial (the id of
+        its spans).  The slot thread delivers completion back to the
         event loop with ``call_soon_threadsafe``; delivery frees the
         slot, wakes any dispatcher queued in _wait_for_slot, and marks
         exceptions retrieved so abandoned waves (deadline-miss /
@@ -1218,8 +1227,6 @@ class AsyncVerifyService:
             # staging scratch per thread, so each slot reuses its own
             # preallocated buffers wave after wave.
             self._dispatch = _DispatchLoop(self.pipeline_depth)
-        self._wave_serial += 1
-        serial = self._wave_serial
         # guarded-by: gil -- written here on the event loop, popped by
         # _deliver (loop) and by _on_done's loop-closed fallback (slot
         # thread); every access is a single dict bytecode, atomic under
@@ -1267,7 +1274,9 @@ class AsyncVerifyService:
                 self._inflight.pop(serial, None)
 
         self._dispatch.submit(
-            lambda: self._dispatch_sync(claims, t_spawn, end_holder, wave),
+            lambda: self._dispatch_sync(
+                claims, t_spawn, end_holder, wave, serial
+            ),
             _on_done,
         )
         return fut, end_holder
@@ -1278,30 +1287,34 @@ class AsyncVerifyService:
         t_spawn: int | None = None,
         end_holder: list | None = None,
         wave: "AdoptedWave | None" = None,
+        serial: int = 0,
     ) -> list[bool]:
         """Slot-thread body: evaluate on the forced-device dispatch
         view, timing the dispatch for the routing EWMA.  An adopted
         zero-copy wave stages from its arena columns instead of
-        flattening claim tuples (released inside eval_claims_arena)."""
+        flattening claim tuples (released inside eval_claims_arena).
+        The ``dispatch.wall`` frame hands ``wave=serial`` down to the
+        stage spans inside it (``flatten`` ... ``readback``)."""
         rec = _spans.recorder()
-        if rec is not None:
-            t_enter = time.perf_counter_ns()
-            if t_spawn is not None:
-                # dispatch-loop handoff -> slot thread entry (thread
-                # wakeup + any queueing behind a previous dispatch)
-                rec.add("stage.slot_wait", t_spawn, t_enter - t_spawn)
+        if rec is not None and t_spawn is not None:
+            # dispatch-loop handoff -> slot thread entry (thread
+            # wakeup + any queueing behind a previous dispatch)
+            rec.add(
+                "stage.slot_wait",
+                t_spawn,
+                time.perf_counter_ns() - t_spawn,
+                wave=serial,
+            )
         target = getattr(self.backend, "async_backend", self.backend)
         t0 = time.perf_counter()
-        if wave is not None:
-            out = eval_claims_arena(target, wave, claims)
-        else:
-            out = eval_claims_sync(target, claims)
+        with _spans.span("dispatch.wall", wave=serial):
+            if wave is not None:
+                out = eval_claims_arena(target, wave, claims)
+            else:
+                out = eval_claims_sync(target, claims)
         wall = time.perf_counter() - t0
-        if rec is not None:
-            end_ns = time.perf_counter_ns()
-            rec.add("dispatch.wall", t_enter, end_ns - t_enter)
-            if end_holder is not None:
-                end_holder.append(end_ns)
+        if rec is not None and end_holder is not None:
+            end_holder.append(time.perf_counter_ns())
         if self._tel_device_wall is not None:
             self._tel_device_wall.add(wall)
         ewma = self._device_ewma_s
@@ -1352,6 +1365,8 @@ class AsyncVerifyService:
             arrivals, self._arrivals = self._arrivals, []
             if not batch:
                 return  # drained — the next submit respawns the task
+            # the serial the batch's first submit took (verify_claims)
+            serial = self._wave_serial
             rec = _spans.recorder()
             wave_t0 = min(arrivals) if (rec is not None and arrivals) else None
             if wave_t0 is not None:
@@ -1359,6 +1374,7 @@ class AsyncVerifyService:
                     "coalesce.wait",
                     wave_t0,
                     time.perf_counter_ns() - wave_t0,
+                    wave=serial,
                 )
             # Deduplicate identical claims across submissions: a claim's
             # verdict is a PURE function of (digest, pk, sig) bytes, so
@@ -1369,21 +1385,22 @@ class AsyncVerifyService:
             # layer exists to avoid).  Each core still applies its OWN
             # stake/quorum/safety rules to the verdicts; no per-node
             # acceptance state crosses node boundaries.
-            unique: dict = {}
-            for cs, _ in batch:
-                for c in cs:
-                    unique.setdefault(c, None)
-            claims = list(unique.keys())
-            n_sigs = sum(claim_sig_count(c) for c in claims)
-            agg_in_wave = [c for c in claims if c[0] == "agg"]
-            if agg_in_wave:
-                self.agg_claims += len(agg_in_wave)
-                self.agg_sigs += sum(len(c[3]) for c in agg_in_wave)
-            self.dispatches += 1
-            if self._tel_wave is not None:
-                self._tel_claims_submitted.inc(sum(len(cs) for cs, _ in batch))
-                self._tel_claims_unique.inc(len(claims))
-                self._tel_wave.observe(n_sigs)
+            with _spans.span("verify.collect", wave=serial):
+                unique: dict = {}
+                for cs, _ in batch:
+                    for c in cs:
+                        unique.setdefault(c, None)
+                claims = list(unique.keys())
+                n_sigs = sum(claim_sig_count(c) for c in claims)
+                agg_in_wave = [c for c in claims if c[0] == "agg"]
+                if agg_in_wave:
+                    self.agg_claims += len(agg_in_wave)
+                    self.agg_sigs += sum(len(c[3]) for c in agg_in_wave)
+                self.dispatches += 1
+                if self._tel_wave is not None:
+                    self._tel_claims_submitted.inc(sum(len(cs) for cs, _ in batch))
+                    self._tel_claims_unique.inc(len(claims))
+                    self._tel_wave.observe(n_sigs)
 
             # zero-copy adoption (ISSUE 20): if the native transport
             # packed this wave's votes into a staging arena and the
@@ -1394,7 +1411,7 @@ class AsyncVerifyService:
             adopted = None
             ing = zero_copy_ingest_if_active()
             if ing is not None and ing.active:
-                with _spans.span("native.pack"):
+                with _spans.span("native.pack", wave=serial):
                     fb_before = ing.fallback_waves
                     adopted = ing.try_adopt(claims, self.wave_buckets)
                 if adopted is not None:
@@ -1407,7 +1424,7 @@ class AsyncVerifyService:
                     if self._tel_fallback is not None:
                         self._tel_fallback.inc()
             try:
-                with _spans.span("route.decide"):
+                with _spans.span("route.decide", wave=serial, sigs=n_sigs):
                     route = self._route_device(n_sigs)
                 waited = False
                 while route == "wait":
@@ -1429,6 +1446,7 @@ class AsyncVerifyService:
                             "pipeline.wait",
                             t_w,
                             time.perf_counter_ns() - t_w,
+                            wave=serial,
                         )
                     route = self._route_device(n_sigs)
                 if self._tel_route is not None:
@@ -1450,7 +1468,7 @@ class AsyncVerifyService:
                     # pack too — they measure the shape real waves use.
                     # Adopted waves skip this: the arena is already
                     # bucket-shaped with native-padded rows.
-                    with _spans.span("stage.pack"):
+                    with _spans.span("stage.pack", wave=serial, sigs=n_sigs):
                         dispatch_claims = self._pack_wave(claims, n_sigs)
                 if route == "probe":
                     # measurement-only device dispatch: results are
@@ -1458,10 +1476,11 @@ class AsyncVerifyService:
                     # itself is served from the CPU so a degraded dispatch path
                     # never adds wave latency
                     self.probe_dispatches += 1
-                    self._spawn_device(
-                        loop, dispatch_claims, measure_only=True,
-                        wave=adopted,
-                    )
+                    with _spans.span("verify.spawn", wave=serial):
+                        self._spawn_device(
+                            loop, dispatch_claims, measure_only=True,
+                            wave=adopted, serial=serial,
+                        )
                     adopted = None  # released by the probe dispatch
                 if route == "device":
                     self.device_dispatches += 1
@@ -1469,10 +1488,21 @@ class AsyncVerifyService:
                         self.mesh_dispatches += 1
                     self.device_sigs += n_sigs
                     deadline = self._deadline_s()
-                    exec_fut, end_holder = self._spawn_device(
-                        loop, dispatch_claims, deadline=deadline,
-                        wave=adopted,
-                    )
+                    with _spans.span(
+                        "verify.spawn",
+                        wave=serial,
+                        sigs=n_sigs,
+                        # the rows the device is handed: an adopted
+                        # arena's, else the claims' signatures and one
+                        # for each pad claim
+                        bucket=adopted.rows
+                        if adopted is not None
+                        else n_sigs + len(dispatch_claims) - len(claims),
+                    ):
+                        exec_fut, end_holder = self._spawn_device(
+                            loop, dispatch_claims, deadline=deadline,
+                            wave=adopted, serial=serial,
+                        )
                     adopted = None  # released by the slot thread
                     # async readback (ISSUE 5): the dispatcher does NOT
                     # await the device — a per-wave lander task lands
@@ -1484,7 +1514,7 @@ class AsyncVerifyService:
                     lander = loop.create_task(
                         self._land_device(
                             batch, dispatch_claims, exec_fut, end_holder,
-                            wave_t0, deadline,
+                            wave_t0, deadline, serial,
                         ),
                         name="verify-lander",
                     )
@@ -1500,7 +1530,10 @@ class AsyncVerifyService:
                     await self._serve_cpu(batch)
                 if wave_t0 is not None:
                     rec.add(
-                        "e2e", wave_t0, time.perf_counter_ns() - wave_t0
+                        "e2e",
+                        wave_t0,
+                        time.perf_counter_ns() - wave_t0,
+                        wave=serial,
                     )
                 self._log_stats()
             except asyncio.CancelledError:
@@ -1570,6 +1603,7 @@ class AsyncVerifyService:
         end_holder: list,
         wave_t0: int | None,
         deadline: float,
+        serial: int = 0,
     ) -> None:
         """Land one in-flight device wave: await its completion future
         (bounded by the dispatch deadline), fan its verdicts out to this
@@ -1591,7 +1625,10 @@ class AsyncVerifyService:
                 await self._serve_cpu(batch)
                 if rec is not None and wave_t0 is not None:
                     rec.add(
-                        "e2e", wave_t0, time.perf_counter_ns() - wave_t0
+                        "e2e",
+                        wave_t0,
+                        time.perf_counter_ns() - wave_t0,
+                        wave=serial,
                     )
                 self._log_stats()
                 return
@@ -1610,19 +1647,22 @@ class AsyncVerifyService:
                         RuntimeError(f"verify dispatch failed: {e}")
                     )
             return
-        verdict = dict(zip(claims, results))
         fan_t0 = end_holder[0] if (rec is not None and end_holder) else None
-        for cs, fut in batch:
-            if not fut.done():
-                fut.set_result([verdict[c] for c in cs])
+        with _spans.span("verify.deliver", wave=serial):
+            verdict = dict(zip(claims, results))
+            for cs, fut in batch:
+                if not fut.done():
+                    fut.set_result([verdict[c] for c in cs])
         if rec is not None:
             end_ns = time.perf_counter_ns()
             if fan_t0 is not None:
                 # worker completion -> every waiter's future resolved
                 # (captures the executor -> loop wakeup gap)
-                rec.add("verdict.fanout", fan_t0, end_ns - fan_t0)
+                rec.add(
+                    "verdict.fanout", fan_t0, end_ns - fan_t0, wave=serial
+                )
             if wave_t0 is not None:
-                rec.add("e2e", wave_t0, end_ns - wave_t0)
+                rec.add("e2e", wave_t0, end_ns - wave_t0, wave=serial)
         self._log_stats()
 
     def _log_stats(self) -> None:

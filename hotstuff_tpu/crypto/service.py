@@ -10,6 +10,7 @@ vote/block is never the bottleneck — QC verify is).
 
 from __future__ import annotations
 
+import time
 from typing import Iterable, Protocol
 
 from ..telemetry import spans as _spans
@@ -175,6 +176,65 @@ class CpuVerifier:
             return batch_verify_arrays(digests, pks, sigs)
 
 
+class VerifyWork:
+    """One node's verification work as its verifier saw it: calls,
+    signatures and wall time — the protocol's dominant CPU cost, which
+    the committee-scaling decomposition (benchmark/scaling.py) reads
+    from the ``Telemetry snapshot:`` document."""
+
+    __slots__ = ("verify_calls", "verify_sigs", "verify_wall_s", "started")
+
+    def __init__(self):
+        self.verify_calls = 0
+        self.verify_sigs = 0
+        self.verify_wall_s = 0.0
+        self.started = time.monotonic()
+
+    def to_json(self) -> dict:
+        return {
+            "elapsed_s": round(time.monotonic() - self.started, 3),
+            "verify_calls": self.verify_calls,
+            "verify_sigs": self.verify_sigs,
+            "verify_wall_ms": round(self.verify_wall_s * 1e3, 3),
+        }
+
+
+class CountingVerifier:
+    """Delegating VerifierBackend that accounts calls/signatures/wall
+    time into a VerifyWork."""
+
+    def __init__(self, inner, work: VerifyWork):
+        self.inner = inner
+        self.work = work
+        self.name = getattr(inner, "name", "counted")
+
+    def _timed(self, n_sigs: int, fn, *args):
+        t0 = time.perf_counter()
+        out = fn(*args)
+        self.work.verify_wall_s += time.perf_counter() - t0
+        self.work.verify_calls += 1
+        self.work.verify_sigs += n_sigs
+        return out
+
+    def verify_one(self, digest, pk, sig) -> bool:
+        return self._timed(1, self.inner.verify_one, digest, pk, sig)
+
+    def verify_shared_msg(self, digest, votes) -> bool:
+        return self._timed(
+            len(votes), self.inner.verify_shared_msg, digest, votes
+        )
+
+    def verify_many(self, digests, pks, sigs, aggregate_ok: bool = False):
+        def call(d, p, s):
+            return self.inner.verify_many(d, p, s, aggregate_ok=aggregate_ok)
+
+        return self._timed(len(digests), call, digests, pks, sigs)
+
+    def __getattr__(self, item):
+        # precompute/warmup/etc. pass through untimed
+        return getattr(self.inner, item)
+
+
 class SignatureService:
     """The service owning the node's secret key.
 
@@ -221,7 +281,8 @@ class SignatureService:
         test constructors, consensus/src/tests/common.rs:48-114)."""
         if self._closed or self._key is None:
             raise RuntimeError("SignatureService is shut down")
-        return Signature(self._key.sign(digest.to_bytes()))  # type: ignore[attr-defined]
+        with _spans.span("core.sign"):
+            return Signature(self._key.sign(digest.to_bytes()))  # type: ignore[attr-defined]
 
     def shutdown(self) -> None:
         self._closed = True

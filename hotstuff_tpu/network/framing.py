@@ -12,6 +12,8 @@ import asyncio
 import socket
 import struct
 
+from ..telemetry import spans as _spans
+
 _LEN = struct.Struct(">I")
 MAX_FRAME = 64 * 1024 * 1024
 
@@ -44,6 +46,11 @@ def write_frame(writer: asyncio.StreamWriter, payload: bytes) -> None:
     writer.write(_LEN.pack(len(payload)) + payload)
 
 
-async def send_frame(writer: asyncio.StreamWriter, payload: bytes) -> None:
-    write_frame(writer, payload)
+async def send_frame(
+    writer: asyncio.StreamWriter, payload: bytes, node: str = ""
+) -> None:
+    """Write one frame and wait for the transport's buffer to drain
+    below its high-water mark; ``node`` labels the ``net.write`` span."""
+    with _spans.span("net.write", node=node):
+        write_frame(writer, payload)
     await writer.drain()

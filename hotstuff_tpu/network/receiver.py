@@ -61,16 +61,19 @@ async def dispatch_ingest(handler, writer, frame: bytes) -> None:
 class Writer:
     """Reply-channel handed to MessageHandler.dispatch."""
 
-    def __init__(self, stream_writer: asyncio.StreamWriter, flows=None):
+    def __init__(
+        self, stream_writer: asyncio.StreamWriter, flows=None, node: str = ""
+    ):
         self._writer = stream_writer
         self._flows = flows
+        self._node = node
 
     async def send(self, payload: bytes) -> None:
         # replies (ACKs, state-read values) leave on the accepted
         # socket, not through a sender — charge their egress here
         if self._flows is not None:
             self._flows.tx(self.peer, payload)
-        await send_frame(self._writer, payload)
+        await send_frame(self._writer, payload, self._node)
 
     @property
     def peer(self):
@@ -105,6 +108,8 @@ class Receiver:
         self.handler = handler
         self._faults = fault_plane
         self._flows = flows
+        #: the ``node`` id of this listener's spans: the handler's
+        self._node = getattr(handler, "node", "")
         self._server: asyncio.AbstractServer | None = None
         # insertion-ordered (dict-as-set): shutdown closes connections
         # in accept order, so teardown is reproducible — a plain set
@@ -129,7 +134,7 @@ class Receiver:
         set_nodelay(stream_writer)
         log.debug("Incoming connection from %s", peer)
         self._writers[stream_writer] = None
-        writer = Writer(stream_writer, flows=self._flows)
+        writer = Writer(stream_writer, flows=self._flows, node=self._node)
         try:
             while True:
                 frame = await read_frame(reader)

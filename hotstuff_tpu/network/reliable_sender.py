@@ -40,6 +40,7 @@ import logging
 from collections import deque
 
 from ..faults.plane import BARRIER_POLL_S, corrupt_frame
+from ..telemetry import spans as _spans
 from ..utils.clock import default_clock, default_connector, default_rng
 from .errors import UnexpectedAckError, classify
 from .framing import FramingError, read_frame, send_frame, set_nodelay
@@ -62,10 +63,14 @@ class FaultDisconnect(ConnectionError):
 
 
 class _Connection:
-    def __init__(self, address: Address, delay_fn=None, faults=None, flows=None):
+    def __init__(
+        self, address: Address, delay_fn=None, faults=None, flows=None,
+        node: str = "",
+    ):
         self.address = address
         self._faults = faults
         self._flows = flows
+        self._node = node
         #: retries whose backoff sleep was jittered (telemetry reads
         #: this: stampede-avoided reconnect attempts)
         self.jittered_retries = 0
@@ -161,7 +166,7 @@ class _Connection:
             # that really crossed the healed link a second time
             if self._flows is not None:
                 self._flows.tx(self.address, data, retx=True)
-            await send_frame(writer, data)
+            await send_frame(writer, data, self._node)
 
         async def writer_loop():
             while True:
@@ -191,24 +196,29 @@ class _Connection:
         async def reader_loop():
             while True:
                 ack = await read_frame(reader)
-                # each ACK pairs FIFO with exactly one sent frame; a frame
-                # whose caller cancelled still consumed this ACK slot
-                if self.pending:
-                    _, fut = self.pending.popleft()
-                    if self._delay_fn is not None:
-                        # the ACK's return leg crosses the same link
-                        asyncio.get_running_loop().call_later(
-                            self._delay_fn(), _resolve, fut, ack
+                with _spans.span("net.ack", node=self._node):
+                    # each ACK pairs FIFO with exactly one sent frame; a
+                    # frame whose caller cancelled still consumed this
+                    # ACK slot
+                    if self.pending:
+                        _, fut = self.pending.popleft()
+                        if self._delay_fn is not None:
+                            # the ACK's return leg crosses the same link
+                            asyncio.get_running_loop().call_later(
+                                self._delay_fn(), _resolve, fut, ack
+                            )
+                        elif not fut.cancelled():
+                            fut.set_result(ack)
+                    else:
+                        # protocol desync the reference surfaces as
+                        # UnexpectedAck (error.rs): keep the connection
+                        # (the peer may just have double-ACKed) but say so
+                        log.warning(
+                            "%s",
+                            UnexpectedAckError(
+                                self.address, "no frame in flight"
+                            ),
                         )
-                    elif not fut.cancelled():
-                        fut.set_result(ack)
-                else:
-                    # protocol desync the reference surfaces as
-                    # UnexpectedAck (error.rs): keep the connection (the
-                    # peer may just have double-ACKed) but say so
-                    log.warning(
-                        "%s", UnexpectedAckError(self.address, "no frame in flight")
-                    )
 
         wtask = asyncio.ensure_future(writer_loop())
         rtask = asyncio.ensure_future(reader_loop())
@@ -221,7 +231,7 @@ class _Connection:
         if faults is None:
             if self._flows is not None:
                 self._flows.tx(self.address, data)
-            await send_frame(writer, data)
+            await send_frame(writer, data, self._node)
             return
         while faults.barrier():
             await default_clock().sleep(BARRIER_POLL_S)
@@ -235,11 +245,11 @@ class _Connection:
             mangled = corrupt_frame(data)
             if self._flows is not None:
                 self._flows.tx(self.address, mangled)
-            await send_frame(writer, mangled)
+            await send_frame(writer, mangled, self._node)
             raise FaultDisconnect(f"fault plane corrupted frame to {self.address}")
         if self._flows is not None:
             self._flows.tx(self.address, data)
-        await send_frame(writer, data)
+        await send_frame(writer, data, self._node)
 
     @staticmethod
     async def _supervise(wtask: asyncio.Task, rtask: asyncio.Task) -> None:
@@ -286,12 +296,14 @@ class ReliableSender(BoundedPoolMixin):
         max_conns: int | None = None,
         fault_plane=None,
         flows=None,
+        node: str = "",
     ):
         self._connections: dict[Address, _Connection] = {}
         self._link_delay = link_delay
         self._max_conns = max_conns
         self._fault_plane = fault_plane
         self._flows = flows
+        self._node = node  # the ``node`` id of this sender's spans
         self._sweeper: asyncio.Task | None = None
 
     def _connection(self, address: Address) -> _Connection:
@@ -303,15 +315,18 @@ class ReliableSender(BoundedPoolMixin):
             self._fault_plane.link(address) if self._fault_plane else None
         )
         conn = _Connection(
-            address, delay_fn=delay_fn, faults=faults, flows=self._flows
+            address, delay_fn=delay_fn, faults=faults, flows=self._flows,
+            node=self._node,
         )
         self._admit(address, conn)
         return conn
 
     async def _enqueue(self, address: Address, data: bytes) -> CancelHandler:
-        fut: CancelHandler = asyncio.get_running_loop().create_future()
-        conn = self._connection(address)
-        await conn.queue.put((conn.deliver_at(), data, fut))
+        with _spans.span("net.send", node=self._node):
+            fut: CancelHandler = asyncio.get_running_loop().create_future()
+            conn = self._connection(address)
+            item = (conn.deliver_at(), data, fut)
+        await conn.queue.put(item)
         return fut
 
     async def send(self, address: Address, data: bytes) -> CancelHandler:

@@ -13,6 +13,7 @@ import asyncio
 import logging
 
 from ..faults.plane import corrupt_frame
+from ..telemetry import spans as _spans
 from ..utils.clock import default_clock, default_connector, default_rng
 from .errors import classify
 from .framing import read_frame, send_frame, set_nodelay
@@ -40,10 +41,14 @@ class _Connection:
     exactly message loss), delays sleep inline, corruption flips a byte,
     duplication writes the frame twice."""
 
-    def __init__(self, address: Address, delay_fn=None, faults=None, flows=None):
+    def __init__(
+        self, address: Address, delay_fn=None, faults=None, flows=None,
+        node: str = "",
+    ):
         self.address = address
         self._faults = faults
         self._flows = flows
+        self._node = node
         self.queue: asyncio.Queue = asyncio.Queue(maxsize=CHANNEL_CAPACITY)
         self._scheduler = (
             None if delay_fn is None else LinkScheduler(delay_fn)
@@ -119,7 +124,7 @@ class _Connection:
         if self._faults is None:
             if self._flows is not None:
                 self._flows.tx(self.address, data)
-            await send_frame(writer, data)
+            await send_frame(writer, data, self._node)
             return
         decision = self._faults.decide()
         if decision.drop:
@@ -129,11 +134,11 @@ class _Connection:
         payload = corrupt_frame(data) if decision.corrupt else data
         if self._flows is not None:
             self._flows.tx(self.address, payload)
-        await send_frame(writer, payload)
+        await send_frame(writer, payload, self._node)
         if decision.duplicate:
             if self._flows is not None:
                 self._flows.tx(self.address, payload)
-            await send_frame(writer, payload)
+            await send_frame(writer, payload, self._node)
 
     @staticmethod
     async def _sink_acks(reader: asyncio.StreamReader) -> None:
@@ -182,12 +187,14 @@ class SimpleSender(BoundedPoolMixin):
         max_conns: int | None = None,
         fault_plane=None,
         flows=None,
+        node: str = "",
     ):
         self._connections: dict[Address, _Connection] = {}
         self._link_delay = link_delay
         self._max_conns = max_conns
         self._fault_plane = fault_plane
         self._flows = flows
+        self._node = node  # the ``node`` id of this sender's spans
         self._sweeper: asyncio.Task | None = None
 
     def _connection(self, address: Address) -> _Connection:
@@ -199,7 +206,8 @@ class SimpleSender(BoundedPoolMixin):
             self._fault_plane.link(address) if self._fault_plane else None
         )
         conn = _Connection(
-            address, delay_fn=delay_fn, faults=faults, flows=self._flows
+            address, delay_fn=delay_fn, faults=faults, flows=self._flows,
+            node=self._node,
         )
         self._admit(address, conn)
         return conn
@@ -212,9 +220,10 @@ class SimpleSender(BoundedPoolMixin):
             log.warning("Dropping message to %s: channel full", address)
 
     async def send(self, address: Address, data: bytes) -> None:
-        if self._flows is not None:
-            self._flows.logical(data)
-        self._enqueue(address, data)
+        with _spans.span("net.send", node=self._node):
+            if self._flows is not None:
+                self._flows.logical(data)
+            self._enqueue(address, data)
 
     async def broadcast(self, addresses: list[Address], data: bytes) -> None:
         # ONE logical charge per broadcast call regardless of fan-out —
@@ -222,8 +231,9 @@ class SimpleSender(BoundedPoolMixin):
         if self._flows is not None and addresses:
             self._flows.logical(data)
         if self._max_conns is None or len(addresses) <= self._max_conns:
-            for addr in addresses:
-                self._enqueue(addr, data)
+            with _spans.span("net.send", node=self._node):
+                for addr in addresses:
+                    self._enqueue(addr, data)
             return
         # Bounded pool: pace the fan-out so the working set stays near
         # the cap — without this, a committee-wide broadcast creates

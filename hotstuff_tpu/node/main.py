@@ -13,6 +13,7 @@ from __future__ import annotations
 import argparse
 import asyncio
 import logging
+import selectors
 import sys
 
 from ..consensus import Committee, Parameters
@@ -23,11 +24,41 @@ from .config import (
     write_committee,
     write_parameters,
 )
+from ..telemetry import spans as _spans
 from .node import Node
 
 log = logging.getLogger("node")
 
 LEVELS = [logging.ERROR, logging.WARNING, logging.INFO, logging.DEBUG]
+
+
+class _IdleSpanSelector(selectors.DefaultSelector):
+    """The event loop's selector with its waiting named: a ``select``
+    that may block is the ``loop.idle`` span, so in a profiler trace
+    the loop thread's busy time is the window less ``loop.idle``, and
+    what no other span covers of it is known (telemetry/spans.py)."""
+
+    def select(self, timeout=None):
+        if timeout is not None and timeout <= 0:
+            return super().select(timeout)
+        with _spans.span("loop.idle"):
+            return super().select(timeout)
+
+
+def _new_event_loop() -> asyncio.AbstractEventLoop:
+    return asyncio.SelectorEventLoop(_IdleSpanSelector())
+
+
+async def _with_host_stats(serving) -> None:
+    """Await ``serving`` with the process's ``Host stats:`` probe
+    (telemetry/hoststats.py) running beside it."""
+    from ..telemetry import hoststats
+
+    probe = hoststats.start()
+    try:
+        await serving
+    finally:
+        probe.cancel()
 
 
 class _FastFormatter(logging.Formatter):
@@ -289,7 +320,7 @@ async def _run_node(args) -> None:
     _freeze_boot_objects()
     # serve() instead of analyze_block(): a node voted out by a
     # committed reconfiguration exits cleanly after its grace window
-    await node.serve()
+    await _with_host_stats(node.serve())
 
 
 async def _submit_reconfig(args) -> int:
@@ -463,7 +494,7 @@ async def _run_many(args) -> None:
     if len(nodes) >= 64:
         probe = asyncio.ensure_future(_fd_probe())
     try:
-        await asyncio.gather(*(n.serve() for n in nodes))
+        await _with_host_stats(asyncio.gather(*(n.serve() for n in nodes)))
     finally:
         if probe is not None:
             probe.cancel()
@@ -517,7 +548,7 @@ async def _deploy_testbed(
         booted.append(node)
     log.info("Deployed %d-node local testbed on base port %d", nodes, base_port)
     _freeze_boot_objects()
-    await asyncio.gather(*(n.serve() for n in booted))
+    await _with_host_stats(asyncio.gather(*(n.serve() for n in booted)))
 
 
 def main(argv=None) -> int:
@@ -805,7 +836,8 @@ def main(argv=None) -> int:
             # sanity-check the committee file before booting
             read_committee(args.committee)
             asyncio.run(
-                _run_node(args) if args.command == "run" else _run_many(args)
+                _run_node(args) if args.command == "run" else _run_many(args),
+                loop_factory=_new_event_loop,
             )
         except ConfigError as e:
             # a configuration this host cannot serve: unreadable files,

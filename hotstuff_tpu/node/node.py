@@ -299,7 +299,7 @@ class Node:
             read_parameters(parameters_file) if parameters_file else Parameters()
         )
 
-        self.store = Store(store_path)
+        self.store = Store(store_path, node=str(secret.name)[:8])
         # Committee-hash provenance: persisted consensus/execution state
         # is only valid under the committee that produced it.  A store
         # carrying another committee's history (the testbed's recycled
@@ -335,7 +335,7 @@ class Node:
             import shutil
 
             shutil.rmtree(store_path, ignore_errors=True)
-            self.store = Store(store_path)
+            self.store = Store(store_path, node=str(secret.name)[:8])
         # lint: allow(no-blocking-in-async) -- same one-time boot path
         self.store.engine.put(COMMITTEE_HASH_KEY, chash)
         signature_service = make_signing_service(secret.scheme, secret.secret)
@@ -453,27 +453,16 @@ class Node:
                 # in the merged trace (first journaled node wins)
                 telemetry.spans.attach_journal(self._journal)
             log.info("Flight recorder journaling to %s", jdir)
-        stats_task = None
-        probe_running = False
-        if tel is not None or os.environ.get("HOTSTUFF_WORK_STATS"):
+        if tel is not None:
             # per-node work accounting for the committee-scaling
-            # decomposition (utils/workstats.py): counted verifier +
-            # loop-lag probe, one parseable log line every few seconds.
-            # Telemetry reuses the same counted-verifier wrapper; the
-            # snapshot document is a superset of the Work stats one.
-            from ..utils.workstats import CountingVerifier, WorkStats, run_probe
+            # decomposition: the counted verifier feeds the snapshot
+            # document's verify keys; its loop-lag keys come from the
+            # process's Host stats probe (telemetry/hoststats.py)
+            from ..crypto.service import CountingVerifier, VerifyWork
 
-            stats = WorkStats()
-            verifier = CountingVerifier(verifier, stats)
-            if os.environ.get("HOTSTUFF_WORK_STATS"):
-                probe_running = True
-                stats_task = asyncio.ensure_future(
-                    run_probe(
-                        stats, logging.getLogger(f"workstats.{secret.name}")
-                    )
-                )
-            if tel is not None:
-                tel.attach_workstats(stats)
+            work = VerifyWork()
+            verifier = CountingVerifier(verifier, work)
+            tel.attach_verify_work(work)
 
         self.commit = asyncio.Queue(maxsize=self.CHANNEL_CAPACITY)
         self.consensus = await Consensus.spawn(
@@ -488,19 +477,13 @@ class Node:
             transport=transport,
             telemetry=tel,
         )
-        self._stats_task = stats_task
         self._snapshot_task = None
         if tel is not None:
             from ..telemetry.exporter import run_snapshot_logger
 
-            # the snapshot logger samples loop lag only when no workstats
-            # probe is doing it already (double-counting would halve the
-            # reported mean)
             self._snapshot_task = asyncio.ensure_future(
                 run_snapshot_logger(
-                    tel,
-                    logging.getLogger(f"telemetry.{secret.name}"),
-                    sample_lag=not probe_running,
+                    tel, logging.getLogger(f"telemetry.{secret.name}")
                 )
             )
         self._health_task = None
@@ -590,7 +573,7 @@ class Node:
         log.info("Node retired cleanly")
 
     async def shutdown(self) -> None:
-        for attr in ("_stats_task", "_snapshot_task", "_health_task"):
+        for attr in ("_snapshot_task", "_health_task"):
             task = getattr(self, attr, None)
             if task is not None:
                 task.cancel()

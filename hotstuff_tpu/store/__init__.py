@@ -14,6 +14,7 @@ from __future__ import annotations
 import asyncio
 from collections import deque
 
+from ..telemetry import spans as _spans
 from .engine import Engine, WalEngine
 
 
@@ -50,8 +51,11 @@ class Store:
     synchronizer's missing-parent wait is built on.
     """
 
-    def __init__(self, path: str, engine: Engine | None = None):
+    def __init__(
+        self, path: str, engine: Engine | None = None, node: str = ""
+    ):
         self.engine = engine if engine is not None else open_engine(path)
+        self.node = node  # the owner's short name: labels the spans
         self._obligations: dict[bytes, deque[asyncio.Future]] = {}
         self._closed = False
 
@@ -61,28 +65,32 @@ class Store:
 
     async def write(self, key: bytes, value: bytes) -> None:
         self._check_open()
-        self.engine.put(key, value)
-        waiters = self._obligations.pop(key, None)
-        if waiters:
-            for fut in waiters:
-                if not fut.done():
-                    fut.set_result(value)
+        with _spans.span("store.write", node=self.node):
+            self.engine.put(key, value)
+            waiters = self._obligations.pop(key, None)
+            if waiters:
+                for fut in waiters:
+                    if not fut.done():
+                        fut.set_result(value)
 
     async def read(self, key: bytes) -> bytes | None:
         self._check_open()
-        return self.engine.get(key)
+        with _spans.span("store.read", node=self.node):
+            return self.engine.get(key)
 
     async def delete(self, key: bytes) -> None:
         """Remove a key (no obligation wake-up — deletes never resolve a
         parked notify_read).  Used by the payload-body budget's eviction
         of uncommitted producer bodies."""
         self._check_open()
-        self.engine.delete(key)
+        with _spans.span("store.write", node=self.node):
+            self.engine.delete(key)
 
     async def notify_read(self, key: bytes) -> bytes:
         """Read that resolves when the key exists (possibly immediately)."""
         self._check_open()
-        value = self.engine.get(key)
+        with _spans.span("store.read", node=self.node):
+            value = self.engine.get(key)
         if value is not None:
             return value
         fut = asyncio.get_running_loop().create_future()

@@ -12,9 +12,10 @@ Three pieces (ISSUE 1 tentpole):
    node (co-located committees share the process).
 3. **Export** (``exporter.py``): an optional stdlib-only HTTP
    ``/metrics`` endpoint (Prometheus text format, off by default) plus
-   a periodic ``Telemetry snapshot: {json}`` log line whose document is
-   a superset of the ``Work stats:`` one (the scaling harness's scrape
-   contract is subsumed, not broken).
+   a periodic ``Telemetry snapshot: {json}`` log line whose document
+   carries the node's verification work and the process's loop lag at
+   its top level (``SNAPSHOT_WORK_KEYS``: the scaling harness's scrape
+   contract).
 
 Enablement: ``HOTSTUFF_TELEMETRY=1``, or setting a metrics port
 (``--metrics-port`` / ``HOTSTUFF_METRICS_PORT`` — a scrape endpoint
@@ -45,7 +46,20 @@ from .metrics import (
     Registry,
 )
 from .trace import EDGES, TraceRecorder
-from . import spans
+from . import hoststats, spans
+
+#: the keys the ``Telemetry snapshot:`` document carries at its top
+#: level for the scaling harness (benchmark/scaling.py): the node's
+#: ``VerifyWork`` and the process's loop lag; tests/test_telemetry.py
+#: pins the snapshot to this tuple
+SNAPSHOT_WORK_KEYS = (
+    "elapsed_s",
+    "verify_calls",
+    "verify_sigs",
+    "verify_wall_ms",
+    "loop_lag_mean_ms",
+    "loop_lag_max_ms",
+)
 
 _REGISTRY = Registry()
 _NODES: dict[str, "NodeTelemetry"] = {}
@@ -211,7 +225,7 @@ class NodeTelemetry:
         self.registry = registry if registry is not None else _REGISTRY
         self.labels = {"node": self.node}
         self.trace = TraceRecorder(self.registry, self.labels)
-        self.workstats = None  # utils.workstats.WorkStats, attached by Node
+        self.verify_work = None  # crypto.service.VerifyWork, attached by Node
         self.journal = None  # telemetry.journal.Journal, attached by Node
         self.flows = None  # telemetry.flows.FlowAccounting, attached by Node
         self._sections: dict[str, Callable[[], dict]] = {}
@@ -240,8 +254,8 @@ class NodeTelemetry:
 
     # ---- component registration ----------------------------------------
 
-    def attach_workstats(self, stats) -> None:
-        self.workstats = stats
+    def attach_verify_work(self, work) -> None:
+        self.verify_work = work
 
     def attach_journal(self, journal) -> None:
         """Attach the node's flight recorder (telemetry/journal.py);
@@ -493,12 +507,14 @@ class NodeTelemetry:
         return out
 
     def snapshot(self) -> dict:
-        """The ``Telemetry snapshot:`` document.  A strict superset of
-        ``WorkStats.to_json()`` (the ``Work stats:`` scrape contract) —
-        its keys stay at the top level."""
+        """The ``Telemetry snapshot:`` document.  ``SNAPSHOT_WORK_KEYS``
+        (the node's verification work, the process's loop lag as the
+        ``Host stats`` probe sampled it) stay at the top level: the
+        scaling harness's scrape contract."""
         doc: dict = {"node": self.node}
-        if self.workstats is not None:
-            doc.update(self.workstats.to_json())
+        if self.verify_work is not None:
+            doc.update(self.verify_work.to_json())
+            doc.update(hoststats.process().lag_json())
         doc["trace"] = self.trace.to_json()
         if self._senders:
             doc["net"] = self._net_section()
@@ -522,6 +538,8 @@ __all__ = [
     "LATENCY_BOUNDS_S",
     "SIZE_BOUNDS",
     "PEER_GAUGE_MAX_COMMITTEE",
+    "SNAPSHOT_WORK_KEYS",
+    "hoststats",
     "spans",
     "registry",
     "enable",

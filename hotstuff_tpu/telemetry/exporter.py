@@ -23,12 +23,11 @@ Routes:
   sequence ``N``, or a full frame when ``N`` is unknown — the fleet
   watcher pulls O(changed) per tick, not O(all)
 
-``run_snapshot_logger`` is the periodic per-node task: it samples
-event-loop lag (the same probe contract as ``utils/workstats.run_probe``
-— the direct host-starvation signal) and logs ``Telemetry snapshot:
-{json}`` every ``LOG_INTERVAL``.  The JSON is a strict superset of the
-``Work stats:`` document, so the scaling harness's scrape contract is
-subsumed, not broken.
+``run_snapshot_logger`` is the periodic per-node task: it logs
+``Telemetry snapshot: {json}`` every ``LOG_INTERVAL``.  The document's
+loop-lag keys come from the process's one probe
+(``telemetry/hoststats.py``, the ``Host stats:`` line); this task
+samples nothing itself.
 """
 
 from __future__ import annotations
@@ -46,7 +45,6 @@ OPENMETRICS_CONTENT_TYPE = (
 )
 
 LOG_INTERVAL = 5.0
-LAG_INTERVAL = 0.05
 
 _HTTP_STATUS = {200: "OK", 404: "Not Found", 405: "Method Not Allowed"}
 
@@ -153,27 +151,14 @@ class MetricsServer:
                 pass
 
 
-async def run_snapshot_logger(
-    tel, logger=None, sample_lag: bool = True
-) -> None:
+async def run_snapshot_logger(tel, logger=None) -> None:
     """Per-node periodic snapshot: ``Telemetry snapshot: {json}`` every
-    LOG_INTERVAL seconds.  When ``sample_lag`` (no separate workstats
-    probe running), also feeds the loop-lag probe into the node's
-    WorkStats so the snapshot's lag keys are live."""
+    LOG_INTERVAL seconds."""
     logger = logger or log
     loop = asyncio.get_running_loop()
     next_log = loop.time() + LOG_INTERVAL
-    stats = getattr(tel, "workstats", None)
     while True:
-        if sample_lag and stats is not None:
-            t0 = loop.time()
-            await asyncio.sleep(LAG_INTERVAL)
-            lag = max(loop.time() - t0 - LAG_INTERVAL, 0.0)
-            stats.lag_samples += 1
-            stats.lag_total_s += lag
-            stats.lag_max_s = max(stats.lag_max_s, lag)
-        else:
-            await asyncio.sleep(LOG_INTERVAL / 8)
+        await asyncio.sleep(LOG_INTERVAL / 8)
         if loop.time() >= next_log:
             next_log = loop.time() + LOG_INTERVAL
             try:
@@ -181,8 +166,8 @@ async def run_snapshot_logger(
             except Exception as e:  # noqa: BLE001 — never kill the task
                 logger.warning("telemetry snapshot failed: %s", e)
                 continue
-            # NOTE: this log entry is scraped (benchmark/logs.py) — it
-            # subsumes the 'Work stats:' document (superset of its keys).
+            # NOTE: this log entry is scraped (benchmark/logs.py,
+            # benchmark/scaling.py)
             logger.info("Telemetry snapshot: %s", doc)
 
 

@@ -1,0 +1,138 @@
+"""What the process itself costs the host: CPU seconds, event-loop
+lag and the collector's full passes, printed whether tracing is on or
+off.
+
+One ``Host stats:`` line per process every ``LOG_INTERVAL`` seconds,
+cumulative like ``Verify service stats`` (a reader takes last less
+first over its window)::
+
+    Host stats: elapsed_s=35.004 cpu_user_s=30.21 cpu_sys_s=3.02
+      lag_samples=640 lag_mean_ms=1.25 lag_max_ms=41.7 gc2=1 gc2_s=0.038
+
+- ``cpu_user_s`` / ``cpu_sys_s``: the process's CPU seconds, all threads
+  (``os.times``).  Over a window's wall time they say whether a long
+  round is the CPU's or a wait's.
+- ``lag_*``: the event-loop lag probe, the one probe the process has: a
+  ``LAG_INTERVAL`` sleep wakes late by the time the loop was busy or
+  the process was not scheduled.  ``lag_samples`` and ``lag_mean_ms``
+  are cumulative; ``lag_max_ms`` is the largest lag since the LAST line,
+  so a reader takes the largest line in its window.
+- ``gc2`` / ``gc2_s``: generation-2 collections and the seconds they
+  took (``gc.callbacks``): the collector's pauses, told apart from the
+  machine's.
+
+A pause in which this process and another both stand still shows as one
+``lag_max_ms`` the size of the pause with neither ``gc2_s`` nor CPU
+seconds behind it.  ``telemetry/__init__.py`` reads the lag keys of its
+snapshot from here; ``chipbench/readers/hoststats.py`` reads the line.
+"""
+
+from __future__ import annotations
+
+import asyncio
+import gc
+import logging
+import os
+import time
+
+log = logging.getLogger(__name__)
+
+LOG_INTERVAL = 5.0
+LAG_INTERVAL = 0.05
+
+
+class HostStats:
+    """The process's counters.  ``run`` is the probe and the printer;
+    the collector's callback is installed while it runs."""
+
+    def __init__(self):
+        self.started = time.monotonic()
+        self.lag_samples = 0
+        self.lag_total_s = 0.0
+        self.lag_max_s = 0.0  # since the start
+        self._lag_max_line_s = 0.0  # since the last line
+        self.gc2 = 0
+        self.gc2_s = 0.0
+        self._gc2_t0: float | None = None
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if info.get("generation") != 2:
+            return
+        if phase == "start":
+            self._gc2_t0 = time.perf_counter()
+        elif self._gc2_t0 is not None:
+            self.gc2 += 1
+            self.gc2_s += time.perf_counter() - self._gc2_t0
+            self._gc2_t0 = None
+
+    def observe_lag(self, lag_s: float) -> None:
+        self.lag_samples += 1
+        self.lag_total_s += lag_s
+        self.lag_max_s = max(self.lag_max_s, lag_s)
+        self._lag_max_line_s = max(self._lag_max_line_s, lag_s)
+
+    def lag_json(self) -> dict:
+        """The lag keys of the ``Telemetry snapshot:`` document."""
+        mean = self.lag_total_s / self.lag_samples if self.lag_samples else 0.0
+        return {
+            "loop_lag_mean_ms": round(mean * 1e3, 3),
+            "loop_lag_max_ms": round(self.lag_max_s * 1e3, 3),
+        }
+
+    def line(self) -> str:
+        """The counters as ``key=value`` pairs; resets the line's max."""
+        cpu = os.times()
+        mean = self.lag_total_s / self.lag_samples if self.lag_samples else 0.0
+        lag_max, self._lag_max_line_s = self._lag_max_line_s, 0.0
+        return (
+            f"elapsed_s={time.monotonic() - self.started:.3f} "
+            f"cpu_user_s={cpu.user:.3f} cpu_sys_s={cpu.system:.3f} "
+            f"lag_samples={self.lag_samples} lag_mean_ms={mean * 1e3:.3f} "
+            f"lag_max_ms={lag_max * 1e3:.3f} "
+            f"gc2={self.gc2} gc2_s={self.gc2_s:.4f}"
+        )
+
+    async def run(self, logger=None) -> None:
+        """Sample the loop's lag every ``LAG_INTERVAL`` and print the
+        line every ``LOG_INTERVAL``; cancelled at shutdown."""
+        logger = logger or log
+        loop = asyncio.get_running_loop()
+        next_log = loop.time() + LOG_INTERVAL
+        gc.callbacks.append(self._on_gc)
+        try:
+            while True:
+                t0 = loop.time()
+                await asyncio.sleep(LAG_INTERVAL)
+                now = loop.time()
+                self.observe_lag(max(now - t0 - LAG_INTERVAL, 0.0))
+                if now >= next_log:
+                    next_log = now + LOG_INTERVAL
+                    # NOTE: this log entry is scraped (benchmark/scaling.py,
+                    # chipbench/readers/hoststats.py)
+                    logger.info("Host stats: %s", self.line())
+        finally:
+            gc.callbacks.remove(self._on_gc)
+
+
+_PROCESS: HostStats | None = None
+
+
+def process() -> HostStats:
+    """The process's one ``HostStats``: a process has one event loop to
+    probe, one collector and one CPU account, however many nodes it
+    runs."""
+    global _PROCESS
+    if _PROCESS is None:
+        _PROCESS = HostStats()
+    return _PROCESS
+
+
+def start() -> asyncio.Task:
+    """Start the process's probe on the running loop (the node CLI's
+    entry points call this once; the caller cancels the task)."""
+    return asyncio.get_running_loop().create_task(
+        process().run(), name="host-stats"
+    )
+
+
+__all__ = ["HostStats", "LOG_INTERVAL", "LAG_INTERVAL", "process", "start"]

@@ -13,9 +13,6 @@ Span taxonomy (leaf stages sum to the wave's end-to-end time)::
     route.decide     the device-vs-CPU routing decision
     stage.pack       wave padding to the fixed bucket shape (ISSUE 6)
     stage.slot_wait  dispatch-loop handoff -> slot thread entry
-    queue.wait       executor handoff -> worker thread entry (legacy
-                     executor paths; the dispatch loop emits
-                     stage.slot_wait instead)
     flatten          claims -> flat (digest, pk, sig) arrays
     prepare          host staging: decompress lookup, hashing, padding
     dispatch         kernel call (device enqueue; returns a future)
@@ -33,13 +30,34 @@ plus parent spans (``e2e``, ``dispatch.wall``, ``agg.verify``,
 sums — ``benchmark/profile.py`` renders the per-stage waterfall and its
 coverage of the measured end-to-end latency.
 
+One call, two sinks (``docs/TELEMETRY.md``, "Verify-pipeline
+profiler").  ``span(name, **ids)`` is all a call site writes.  The ring
+below is one sink; the other is the profiler's own trace: while a
+``jax.profiler`` session is active (``start_trace``, the profiler
+server, ``chipbench/child.py``'s 7 s window) every span is also entered
+as a ``jax.profiler.TraceAnnotation(name, **ids)``, so it lands in the
+``/host:CPU`` plane of the same ``.xplane.pb`` as the device's
+``XLA Ops`` line, on the profiler's clock.  ``ids`` name the span's
+cause: ``wave=<serial>`` (with ``sigs``, ``bucket`` where known) on the
+verify pipeline, ``node=<8 chars>`` and ``round=<r>`` on consensus,
+network, store and ingest.  A frame (``PARENT_STAGES``: the slot
+thread's ``dispatch.wall``) hands its ids down to the spans entered
+inside it on the same thread, so ``flatten`` ... ``readback`` carry
+their wave's serial without the backends knowing it.  The profiler's
+events on one thread must nest and 64 cores share the event-loop
+thread, so **a span on the loop thread never contains an ``await``**
+(lint rule ``no-await-in-span``); waits across awaits or threads are
+derived by the trace's reader from the spans on either side, joined by
+``wave``.
+
 Design constraints (same contract as the journal):
 
 - **Off by default.**  ``HOTSTUFF_PROFILE=1`` / ``--profile`` /
-  :func:`enable` turn it on.  Disabled, :func:`span` returns one shared
-  no-op context manager and :func:`recorder` returns ``None`` — no
-  allocation, no clock reads, a single module-global test per call
-  site (asserted < 2% of a 1k-claim wave in tests/test_profile.py).
+  :func:`enable` turn the ring on; a profiler session turns the trace
+  sink on.  With neither, :func:`span` returns one shared no-op context
+  manager and :func:`recorder` returns ``None`` — no clock reads, one
+  module-global test and one ``is_enabled()`` per call site (asserted
+  < 2% of a 1k-claim wave in tests/test_profile.py).
 - **Bounded.**  Completed spans land in a ``deque(maxlen=capacity)``
   ring (default 65536): a run that outlives the ring loses its OLDEST
   spans, a flight recorder, not an archive.
@@ -59,6 +77,7 @@ aligned with the consensus rounds.
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -87,6 +106,26 @@ _RECORDER: "SpanRecorder | None" = None
 _ENV_CHECKED = False
 _SINK = None  # journal fan-out: fn(stage, dur_ns), set via attach_journal
 _NULL = nullcontext()  # the shared disabled-path context (reentrant)
+_LOCAL = threading.local()  # per-thread nesting depth and inherited ids
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+def _no_session() -> bool:
+    """Is a profiler session active?  Only a process that has imported
+    jax can hold one, and this module never imports it first: once jax
+    is there, the profiler's own ``is_enabled`` takes this function's
+    place."""
+    global _ANNOTATION, _tracing
+    if "jax" not in sys.modules:
+        return False
+    from jax.profiler import TraceAnnotation
+
+    _ANNOTATION = TraceAnnotation
+    _tracing = TraceAnnotation.is_enabled
+    return _tracing()
+
+
+_tracing = _no_session
 
 
 def _env_on() -> bool:
@@ -111,11 +150,21 @@ def recorder() -> "SpanRecorder | None":
     return _RECORDER
 
 
-def span(name: str):
-    """``with spans.span("prepare"): ...`` — a timed span when profiling
-    is on, the shared no-op context otherwise (no allocation)."""
-    rec = recorder()
-    return _NULL if rec is None else rec.span(name)
+def span(name: str, **ids):
+    """``with spans.span("prepare", wave=7): ...`` — a timed span in the
+    ring when profiling is on, an annotation in the profiler's trace
+    while a session is active, the shared no-op context otherwise."""
+    rec = _RECORDER if _ENV_CHECKED else recorder()
+    if _tracing():
+        if rec is None and name not in PARENT_STAGES:
+            # the trace alone, and no ids to hand down: the profiler's
+            # own context manager, with nothing of ours around it
+            frame = getattr(_LOCAL, "ids", None)
+            if frame:
+                ids = {**frame, **ids}
+            return _ANNOTATION(name, **ids)
+        return _Span(rec, name, ids, True)
+    return _NULL if rec is None else _Span(rec, name, ids, False)
 
 
 def enabled() -> bool:
@@ -153,38 +202,57 @@ def attach_journal(journal) -> None:
 
 
 class _Span:
-    """One live span (context manager).  Cheap by construction: two
-    clock reads, a thread-local depth bump, one ring append on exit."""
+    """One live span (context manager) feeding either sink or both.
+    Cheap by construction: two clock reads, a thread-local depth bump
+    and one ring append on exit; one ``TraceAnnotation`` when traced,
+    entered last and left first so that the trace's interval holds the
+    call site's work and little of this class's."""
 
-    __slots__ = ("_rec", "name", "t0", "depth")
+    __slots__ = ("_rec", "name", "ids", "_outer", "_note", "t0", "depth")
 
-    def __init__(self, rec: "SpanRecorder", name: str):
+    def __init__(self, rec: "SpanRecorder | None", name: str, ids: dict,
+                 traced: bool):
         self._rec = rec
         self.name = name
+        self.ids = ids
+        self._outer = None
+        self._note = traced
         self.t0 = 0
         self.depth = 0
 
     def __enter__(self) -> "_Span":
-        local = self._rec._local
+        local = _LOCAL
         self.depth = getattr(local, "depth", 0)
         local.depth = self.depth + 1
+        frame = self._outer = getattr(local, "ids", None)
+        if frame:
+            # the cause of a span inside a frame is the frame's
+            self.ids = {**frame, **self.ids}
+        if self.name in PARENT_STAGES:
+            local.ids = self.ids
         self.t0 = time.perf_counter_ns()
+        if self._note:
+            self._note = _ANNOTATION(self.name, **self.ids)
+            self._note.__enter__()
         return self
 
     def __exit__(self, *exc) -> None:
+        if self._note:
+            self._note.__exit__(*exc)
         dur = time.perf_counter_ns() - self.t0
-        self._rec._local.depth = self.depth
-        self._rec._emit(self.name, self.t0, dur, self.depth)
+        _LOCAL.depth = self.depth
+        _LOCAL.ids = self._outer
+        if self._rec is not None:
+            self._rec._emit(self.name, self.t0, dur, self.depth, self.ids)
 
 
 class SpanRecorder:
     """Ring buffer of completed spans ``(name, t0_ns, dur_ns, depth,
-    thread)`` with optional metric/journal fan-out."""
+    thread, ids)`` with optional metric/journal fan-out."""
 
     def __init__(self, capacity: int = DEFAULT_CAPACITY):
         self.capacity = max(1, int(capacity))
         self._ring: deque = deque(maxlen=self.capacity)
-        self._local = threading.local()
         self.spans_total = 0
         # None = undecided (checked on the first span so tests that
         # enable telemetry before profiling are seen); False = off
@@ -193,17 +261,21 @@ class SpanRecorder:
 
     # ---- recording -------------------------------------------------------
 
-    def span(self, name: str) -> _Span:
-        return _Span(self, name)
+    def span(self, name: str, **ids) -> _Span:
+        return _Span(self, name, ids, _tracing())
 
-    def add(self, name: str, t0_ns: int, dur_ns: int) -> None:
-        """A manually-timed span (stages whose start predates the code
-        that can observe them, e.g. coalesce.wait from submit stamps)."""
-        self._emit(name, t0_ns, max(0, int(dur_ns)), 0)
+    def add(self, name: str, t0_ns: int, dur_ns: int, **ids) -> None:
+        """A manually-timed span, in the ring only: a wait across an
+        ``await`` or between threads (``coalesce.wait`` from submit
+        stamps, ``stage.slot_wait``), which the profiler's trace must
+        not hold because it does not nest."""
+        self._emit(name, t0_ns, max(0, int(dur_ns)), 0, ids)
 
-    def _emit(self, name: str, t0_ns: int, dur_ns: int, depth: int) -> None:
+    def _emit(
+        self, name: str, t0_ns: int, dur_ns: int, depth: int, ids: dict
+    ) -> None:
         self._ring.append(
-            (name, t0_ns, dur_ns, depth, threading.current_thread().name)
+            (name, t0_ns, dur_ns, depth, threading.current_thread().name, ids)
         )
         self.spans_total += 1
         if self._metrics_on is None:

@@ -28,7 +28,6 @@ SPAN_LEAF_STAGES: tuple = (
     "pipeline.wait",
     "stage.pack",
     "stage.slot_wait",
-    "queue.wait",
     "flatten",
     "prepare",
     "dispatch",
@@ -65,6 +64,46 @@ SPAN_AGG_STAGES: tuple = (
     "agg.snapshot",
 )
 
+#: host stages outside the verify waterfall, one prefix a layer of
+#: PERF.md section 3: each wraps one SYNCHRONOUS segment on the
+#: event-loop thread (no ``await`` inside: lint rule no-await-in-span)
+#: and lands in the profiler's trace, where
+#: ``chipbench/hostspans.py`` sums a layer's self time by prefix.
+#: Never waterfall rows.
+SPAN_HOST_STAGES: tuple = (
+    # consensus: consensus/core.py, synchronizer.py, crypto/service.py
+    "core.claims",  # burst claim collection / verdict memoization
+    "core.proposal",  # leader check, Block.verify, QC processing
+    "core.ancestors",  # parent blocks deserialized from the store
+    "core.persist",  # ConsensusState / block serialization
+    "core.vote.make",  # safety rules, Vote, its frame
+    "core.sign",  # the signing call (votes, blocks, timeouts)
+    "core.vote",  # aggregator.add_vote, QC assembly, round advance
+    "core.commit",  # per committed block: log line, execution layer
+    # consensus/proposer.py
+    "proposer.make",  # payload take, Block, log line, propose frame
+    "proposer.cleanup",  # committed digests pruned, orphans re-buffered
+    # network: consensus/consensus.py handler, network/*sender.py
+    "net.decode",  # frame -> message on receive
+    "net.send",  # enqueue on a peer connection (send / broadcast)
+    "net.write",  # frame -> socket
+    "net.ack",  # a reliable sender's ACK resolved
+    # store: store/__init__.py, store/state.py via the core
+    "store.read",
+    "store.write",
+    "store.apply",  # a committed block through the execution layer
+    # ingest: the producer path
+    "ingest.admit",  # one producer frame: content check, admission
+    "ingest.buffer",  # digests into the proposer's buffer
+    # verify service, on the loop thread
+    "verify.submit",  # a core's claims join the pending wave
+    "verify.collect",  # the wave's dedup and collection
+    "verify.spawn",  # hand-off to a slot thread
+    "verify.deliver",  # verdicts to every waiter's future
+    # the loop's own waiting: select() with a timeout (node/main.py)
+    "loop.idle",
+)
+
 #: every registered span stage name (what ``span("...")`` /
 #: ``rec.add("...")`` call sites are checked against)
 SPAN_STAGES: frozenset = frozenset(
@@ -72,6 +111,7 @@ SPAN_STAGES: frozenset = frozenset(
     + SPAN_PARENT_STAGES
     + SPAN_ANNOTATION_STAGES
     + SPAN_AGG_STAGES
+    + SPAN_HOST_STAGES
 )
 
 # ---- journal edges (telemetry/journal.py records) --------------------------
@@ -232,6 +272,7 @@ __all__ = [
     "SPAN_PARENT_STAGES",
     "SPAN_ANNOTATION_STAGES",
     "SPAN_AGG_STAGES",
+    "SPAN_HOST_STAGES",
     "SPAN_STAGES",
     "BLOCK_EDGES",
     "CONTROL_EDGES",

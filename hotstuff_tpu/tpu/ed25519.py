@@ -445,30 +445,20 @@ class BatchVerifier:
         # (they are per-wave temporaries); external stage() users call
         # the kernel with donate's default False and may reuse arrays
         donate = self.donate_buffers
-        rec = _spans.recorder()
-        if rec is None:
+        # the dispatch split into its waterfall stages; the fence is
+        # part of the production path (ISSUE 5): overlap happens at the
+        # WAVE level — the dispatch pipeline parks this worker thread
+        # in device.execute (GIL released) while the next wave stages
+        # on another thread
+        with _spans.span("prepare"):
             kernel, arrays, valid_host = self.stage(
                 messages, pubkeys, signatures
             )
+        with _spans.span("dispatch"):
             ok = kernel(*arrays, donate=donate)
-            # same fence as the profiled path (ISSUE 5): overlap now
-            # happens at the WAVE level — the dispatch pipeline parks
-            # this worker thread here (GIL released) while the next
-            # wave stages on another thread — so the profiler measures
-            # exactly what production runs
+        with _spans.span("device.execute"):
             ok = jax.block_until_ready(ok)
-            return np.asarray(ok)[:n] & valid_host
-        # profiling: split the dispatch into its waterfall stages;
-        # structurally identical to the production path above
-        with rec.span("prepare"):
-            kernel, arrays, valid_host = self.stage(
-                messages, pubkeys, signatures
-            )
-        with rec.span("dispatch"):
-            ok = kernel(*arrays, donate=donate)
-        with rec.span("device.execute"):
-            ok = jax.block_until_ready(ok)
-        with rec.span("readback"):
+        with _spans.span("readback"):
             return np.asarray(ok)[:n] & valid_host
 
     def verify_packed(self, dig_buf, pk_buf, sig_buf, rows: int) -> np.ndarray:
@@ -494,19 +484,13 @@ class BatchVerifier:
                 [r.tobytes() for r in sig_v],
             )
         donate = self.donate_buffers
-        rec = _spans.recorder()
-        if rec is None:
+        with _spans.span("prepare"):
             valid_host, arrays = self.prepare_packed(dig_v, pk_v, sig_v)
+        with _spans.span("dispatch"):
             ok = self._run_kernel(*arrays, donate=donate)
+        with _spans.span("device.execute"):
             ok = jax.block_until_ready(ok)
-            return np.asarray(ok)[:rows] & valid_host
-        with rec.span("prepare"):
-            valid_host, arrays = self.prepare_packed(dig_v, pk_v, sig_v)
-        with rec.span("dispatch"):
-            ok = self._run_kernel(*arrays, donate=donate)
-        with rec.span("device.execute"):
-            ok = jax.block_until_ready(ok)
-        with rec.span("readback"):
+        with _spans.span("readback"):
             return np.asarray(ok)[:rows] & valid_host
 
     def prepare_packed(self, dig_v, pk_v, sig_v) -> tuple[np.ndarray, tuple]:

@@ -25,6 +25,7 @@ from hotstuff_tpu.analysis.rules import ALL_RULES
 from hotstuff_tpu.analysis.rules.blocking import NoBlockingInAsync
 from hotstuff_tpu.analysis.rules.env_knobs import EnvKnobRegistry
 from hotstuff_tpu.analysis.rules.guarded_by import GuardedBy
+from hotstuff_tpu.analysis.rules.span_await import NoAwaitInSpan
 from hotstuff_tpu.analysis.rules.taxonomy_rule import TaxonomyRegistry
 from hotstuff_tpu.analysis.rules.wire_bounds import WireDecoderBounds
 
@@ -256,6 +257,73 @@ def test_taxonomy_rule_catches_unregistered_edge_and_stage(tmp_path):
     )
     findings = run_rules([TaxonomyRegistry()], root)
     assert _codes(findings) == {"edge:commmit", "stage:dispatch.typo"}
+
+
+# no-await-in-span: the profiler's events on the loop thread must nest
+
+
+def test_span_await_rule_catches_a_span_held_across_a_suspension(tmp_path):
+    root = _tree(
+        tmp_path,
+        {
+            "hotstuff_tpu/consensus/core.py": """                async def vote(self, block):
+                    with spans.span("core.vote", node=self._node):
+                        await self.store.write(b"k", b"v")
+                    with _spans.span("core.commit"):
+                        async with self.lock:
+                            pass
+                    with _spans.span("core.claims"), open("f"):
+                        async for _ in self.queue:
+                            pass
+
+                def staged(self):
+                    with _spans.span("prepare"):
+                        yield 1
+                """,
+            "hotstuff_tpu/store/engine.py": """                async def fine(self, rec):
+                    with spans.span("store.write", node=self.node):
+                        self.engine.put(b"k", b"v")
+
+                        async def later():  # its own schedule
+                            await self.flush()
+                    await self.flush()
+                    with self.lock:
+                        await self.flush()  # not a span
+                    with rec.span("dispatch"):
+                        return 1
+                """,
+        },
+    )
+    findings = run_rules([NoAwaitInSpan()], root)
+    assert _codes(findings) == {
+        "span:core.vote", "span:core.commit", "span:core.claims",
+        "span:prepare",
+    }
+    assert {f.path for f in findings} == {"hotstuff_tpu/consensus/core.py"}
+
+
+def test_span_await_rule_covers_every_layer_with_span_sites():
+    """consensus, network, node, crypto and store hold the spans that
+    share the event-loop thread; the rule looks at all of them."""
+    covered = {t.split("/")[1] for t in NoAwaitInSpan.targets}
+    assert covered == {"consensus", "network", "node", "crypto", "store"}
+
+
+def test_every_span_site_uses_a_registered_stage():
+    """Each stage name a ``span(...)`` call site of the tree passes is
+    in ``telemetry/taxonomy.py``; the host stages are there with the
+    prefix of their layer, and ``queue.wait`` (no emitter) is gone."""
+    from hotstuff_tpu.telemetry import taxonomy
+
+    findings = run_rules([TaxonomyRegistry()], repo_root())
+    assert [f.render() for f in findings] == []
+    assert "queue.wait" not in taxonomy.SPAN_STAGES
+    prefixes = {name.split(".")[0] for name in taxonomy.SPAN_HOST_STAGES}
+    assert prefixes == {
+        "core", "proposer", "net", "store", "ingest", "verify", "loop"
+    }
+    assert set(taxonomy.SPAN_HOST_STAGES) <= taxonomy.SPAN_STAGES
+    assert not set(taxonomy.SPAN_HOST_STAGES) & set(taxonomy.SPAN_LEAF_STAGES)
 
 
 def test_taxonomy_rule_dynamic_edges_need_registered_prefix(tmp_path):

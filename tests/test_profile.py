@@ -55,6 +55,96 @@ def test_disabled_is_shared_noop():
         pass  # and it is a usable (reentrant) context manager
 
 
+def test_disabled_path_allocates_nothing():
+    """With no ring and no profiler session a call site keeps nothing
+    alive: every call returns the one shared context, whatever ids it
+    passes, and the interpreter's block count does not grow."""
+    import sys
+
+    assert spans.recorder() is None
+    for _ in range(100):  # warm the call site
+        spans.span("core.vote", node="abcdefgh", round=3)
+    before = sys.getallocatedblocks()
+    for i in range(10_000):
+        assert spans.span("core.vote", node="abcdefgh", round=3) is spans._NULL
+    assert sys.getallocatedblocks() - before < 50
+
+
+def test_ids_reach_the_ring_and_nest():
+    """A span carries its ids into the ring; a span entered inside a
+    frame inherits the frame's ids (a wave's stage spans get ``wave``
+    from the ``dispatch.wall`` frame around them; a leaf hands nothing
+    down), and manual ``add`` waits carry theirs."""
+    rec = spans.enable()
+    with spans.span("dispatch.wall", wave=7, sigs=38):
+        with spans.span("prepare", bucket=128):
+            pass
+    with spans.span("core.vote", node="abcdefgh", round=3):
+        with spans.span("store.write", node="abcdefgh"):
+            pass
+    rec.add("coalesce.wait", 0, 5, wave=7)
+    rows = {r[0]: r for r in rec.drain()}
+    assert rows["store.write"][5] == {"node": "abcdefgh"}  # not a frame's
+    assert rows["dispatch.wall"][5] == {"wave": 7, "sigs": 38}
+    assert rows["prepare"][5] == {"wave": 7, "sigs": 38, "bucket": 128}
+    assert rows["prepare"][3] == 1  # depth
+    assert rows["core.vote"][5] == {"node": "abcdefgh", "round": 3}
+    assert rows["coalesce.wait"][5] == {"wave": 7}
+    # and nothing leaks out of the frame
+    with spans.span("flatten"):
+        pass
+    assert rec.drain()[0][5] == {}
+
+
+def _host_plane_events(trace_dir):
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(
+        os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")
+    )
+    return [
+        (event.name, dict(event.stats))
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for event in line.events
+    ]
+
+
+def test_span_lands_in_the_profilers_trace(tmp_path):
+    """The second sink: under ``jax.profiler.start_trace`` (on the CPU
+    here) a span is a ``TraceAnnotation`` in the host plane of the same
+    ``.xplane.pb`` a device's operations go to, with its ids; the ring
+    gets the same span when it is on, and nothing is annotated once the
+    session has stopped."""
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0  # as chipbench/child.py traces
+    assert spans.span("prepare", wave=7) is spans._NULL  # no session yet
+    rec = spans.enable()
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with spans.span("dispatch.wall", wave=7):
+            with spans.span("prepare", bucket=128):
+                time.sleep(0.001)
+        with spans.span("core.vote", node="abcdefgh", round=3):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    assert [r[0] for r in rec.drain()] == [
+        "prepare", "dispatch.wall", "core.vote"
+    ]
+    spans.disable()  # the trace sink alone now
+    assert spans.span("prepare", wave=7) is spans._NULL  # session over
+    events = _host_plane_events(str(tmp_path))
+    prepare = [stats for name, stats in events if name == "prepare"]
+    assert prepare == [{"wave": 7, "bucket": 128}]
+    assert ("core.vote", {"node": "abcdefgh", "round": 3}) in events
+
+
 def test_env_knob(monkeypatch):
     monkeypatch.setenv("HOTSTUFF_PROFILE", "1")
     spans.disable()  # re-arm the one-time env check
@@ -368,7 +458,7 @@ def test_disabled_overhead_under_2pct():
     n = 100_000
     t0 = time.perf_counter()
     for _ in range(n):
-        spans.span("prepare")
+        spans.span("prepare", wave=7, bucket=128)
         spans.recorder()
     per_probe_s = (time.perf_counter() - t0) / n
 

@@ -2,7 +2,7 @@
 
 Covers the tentpole pieces — bounded trace recorder, log-bucket
 histograms, Prometheus /metrics golden output, the snapshot document's
-'Work stats:' superset contract, and a 4-node in-process run producing
+snapshot scrape contract and the 'Host stats:' line, and a 4-node in-process run producing
 a commit-latency breakdown — plus regressions for the satellite fixes
 (fd-limit RLIM_INFINITY, gc gen2 knob, reliable-sender idle eviction,
 broadcast pacing).
@@ -22,7 +22,8 @@ from hotstuff_tpu.telemetry.metrics import (
     Registry,
 )
 from hotstuff_tpu.telemetry.trace import TraceRecorder
-from hotstuff_tpu.utils.workstats import WORKSTATS_KEYS, WorkStats
+from hotstuff_tpu.crypto.service import VerifyWork
+from hotstuff_tpu.telemetry import SNAPSHOT_WORK_KEYS, hoststats
 
 from .common import async_test, committee, fresh_base_port, keys
 
@@ -221,22 +222,79 @@ def test_env_enablement(monkeypatch):
     assert telemetry.enabled()
 
 
-def test_snapshot_is_workstats_superset():
-    """The 'Telemetry snapshot:' document must carry every 'Work stats:'
-    key at top level — the scaling harness's scrape contract is
-    subsumed, not broken."""
+def test_snapshot_carries_the_work_keys():
+    """The 'Telemetry snapshot:' document must carry the node's
+    verification work and the process's loop lag at top level — the
+    scaling harness's scrape contract."""
     telemetry.enable()
     tel = telemetry.for_node("n0")
-    stats = WorkStats()
-    stats.verify_calls = 5
-    tel.attach_workstats(stats)
+    work = VerifyWork()
+    work.verify_calls = 5
+    tel.attach_verify_work(work)
+    hoststats.process().observe_lag(0.004)
     doc = tel.snapshot()
-    for key in WORKSTATS_KEYS:
-        assert key in doc, f"snapshot missing Work stats key {key!r}"
+    for key in SNAPSHOT_WORK_KEYS:
+        assert key in doc, f"snapshot missing work key {key!r}"
     assert doc["verify_calls"] == 5
+    assert doc["loop_lag_max_ms"] >= 4.0
     assert doc["node"] == "n0"
     assert "trace" in doc
     json.dumps(doc)  # and it is one JSON-serializable log line
+
+
+def test_host_stats_line_is_cumulative_but_for_the_lag_max():
+    """'Host stats:' is key=value pairs a reader takes last less first;
+    the lag's max alone is of the time since the last line."""
+    stats = hoststats.HostStats()
+    stats.observe_lag(0.010)
+    stats.observe_lag(0.002)
+    stats._on_gc("start", {"generation": 1})
+    stats._on_gc("stop", {"generation": 1})
+    stats._on_gc("start", {"generation": 2})
+    stats._on_gc("stop", {"generation": 2})
+    first = dict(item.split("=") for item in stats.line().split())
+    assert set(first) == {
+        "elapsed_s", "cpu_user_s", "cpu_sys_s", "lag_samples",
+        "lag_mean_ms", "lag_max_ms", "gc2", "gc2_s",
+    }
+    assert float(first["lag_max_ms"]) == 10.0
+    assert float(first["lag_mean_ms"]) == 6.0
+    assert first["lag_samples"] == "2" and first["gc2"] == "1"
+    assert float(first["cpu_user_s"]) > 0
+    stats.observe_lag(0.001)
+    second = dict(item.split("=") for item in stats.line().split())
+    assert float(second["lag_max_ms"]) == 1.0  # since the last line
+    assert second["lag_samples"] == "3"  # cumulative
+    assert stats.lag_json()["loop_lag_max_ms"] == 10.0  # since the start
+
+
+@async_test
+async def test_host_stats_probe_prints_and_uninstalls(monkeypatch):
+    """The one probe samples the loop's lag, prints the line through
+    the logger it is given and takes its collector hook out again."""
+    import logging
+
+    monkeypatch.setattr(hoststats, "LOG_INTERVAL", 0.05)
+    monkeypatch.setattr(hoststats, "LAG_INTERVAL", 0.01)
+    lines = []
+
+    class Catch(logging.Handler):
+        def emit(self, record):
+            lines.append(record.getMessage())
+
+    logger = logging.getLogger("test.hoststats")
+    logger.setLevel(logging.INFO)
+    logger.addHandler(Catch())
+    stats = hoststats.HostStats()
+    before = len(gc.callbacks)
+    task = asyncio.ensure_future(stats.run(logger))
+    await asyncio.sleep(0.2)
+    assert len(gc.callbacks) == before + 1
+    task.cancel()
+    await asyncio.gather(task, return_exceptions=True)
+    assert len(gc.callbacks) == before
+    assert stats.lag_samples >= 3
+    assert any(line.startswith("Host stats: elapsed_s=") for line in lines)
 
 
 def test_for_node_cached_per_name():
