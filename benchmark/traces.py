@@ -458,7 +458,9 @@ class TraceSet:
                     continue
                 if e in CONTROL_EDGES:
                     continue
-                if e == "recv.producer":
+                if e in ("recv.producer", "recv.relay"):
+                    # a relayed digest waits at the leader from the
+                    # relay frame's receipt, as a client's from its own
                     producer_seen.setdefault(r["d"], r["m"])
                     continue
                 if e == "payload.first":

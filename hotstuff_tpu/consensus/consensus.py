@@ -5,12 +5,16 @@ Parity target: reference ``Consensus::spawn`` + ``ConsensusReceiverHandler``
 
     NetworkReceiver -> {core, helper, producer->proposer}
     Core <-> Proposer (Make/Cleanup, loopback)
+    Proposer -> next leader's NetworkReceiver -> its Proposer (Relay)
     Synchronizer -> Core (loopback)
     Core -> tx_commit (application layer)
 
 Dispatch rules (consensus.rs:133-168): SyncRequest -> helper;
 Propose -> ACK on the same socket, then core; Producer -> ACK, then
-proposer; Vote/Timeout/TC -> core, no ACK.
+proposer; Vote/Timeout/TC -> core, no ACK.  Relay (this build's: the
+digests a peer admitted and hands to the node that makes the next
+block) -> proposer as one batch, no ACK, no admission and no body: the
+home node admitted them and keeps the bodies.
 """
 
 from __future__ import annotations
@@ -40,6 +44,7 @@ from .wire import (
     TAG_PRODUCER_V2,
     TAG_PROPOSE,
     TAG_RECONFIG,
+    TAG_RELAY,
     TAG_STATE_CHUNK,
     TAG_STATE_MANIFEST,
     TAG_STATE_READ,
@@ -135,7 +140,7 @@ class ConsensusReceiverHandler:
     TAG_NAMES = (
         "propose", "vote", "timeout", "tc", "sync_request", "producer",
         "producer_v2", "state_request", "state_manifest", "state_chunk",
-        "state_read", "reconfig",
+        "state_read", "reconfig", "relay",
     )
 
     def __init__(
@@ -275,6 +280,9 @@ class ConsensusReceiverHandler:
                     None,
                     str(payload.sponsor)[:8],
                 )
+            elif tag == TAG_RELAY:
+                # sampled like a producer batch: the first digest
+                j.record("recv.relay", 0, payload[0], "peer")
         if tag == TAG_SYNC_REQUEST:
             await self.tx_helper.put(payload)
         elif tag == TAG_PROPOSE:
@@ -385,6 +393,18 @@ class ConsensusReceiverHandler:
                     pass
         elif tag == TAG_STATE_READ:
             await self._serve_state_read(writer, payload)
+        elif tag == TAG_RELAY:
+            # one queue item a frame (a tuple, where a client's payload
+            # is a bare Digest): the proposer buffers it under its dedup
+            # and its bound, and never relays it on.  Best effort like
+            # the frame itself: a full queue drops it, and the home
+            # node sends again next round.
+            with _spans.span("ingest.relay", node=self.node):
+                try:
+                    self.tx_producer.put_nowait(payload)
+                except asyncio.QueueFull:
+                    if self._dropped is not None:
+                        self._dropped.inc()
         else:
             await self.tx_consensus.put((tag, payload))
 
@@ -994,6 +1014,10 @@ class Consensus:
             telemetry=telemetry,
             adversary=adversary,
             admission=admission,
+            leader_elector=leader_elector,
+            # the relay shares the core's best-effort sender: its frame
+            # goes to the node the vote goes to, on that connection
+            relay_network=self.core.network,
         )
         self._tasks.append(self.proposer.spawn())
         self.admission = admission

@@ -224,7 +224,7 @@ class ProposerMessage:
 
     __slots__ = (
         "kind", "round", "qc", "tc", "rounds", "allow_empty", "payloads",
-        "committed_round", "op",
+        "committed_round", "op", "tc_entered", "block",
     )
 
     MAKE = "make"
@@ -242,6 +242,8 @@ class ProposerMessage:
         payloads=frozenset(),
         committed_round=0,
         op=None,
+        tc_entered=0,
+        block=None,
     ):
         self.kind = kind
         self.round = round_
@@ -258,6 +260,13 @@ class ProposerMessage:
         # (see Core._commit / Proposer orphan recovery)
         self.payloads = payloads
         self.committed_round = committed_round
+        # the round the core has just entered by a TC, else 0 (no block
+        # comes with a TC: the proposer relays its clients' digests on
+        # this message then, see Proposer._relay)
+        self.tc_entered = tc_entered
+        # a block the core has just processed (anyone's): its payloads
+        # leave the proposer's buffer now, not only when they commit
+        self.block = block
 
     @classmethod
     def make(
@@ -267,13 +276,20 @@ class ProposerMessage:
 
     @classmethod
     def cleanup(
-        cls, rounds: list[Round], payloads=frozenset(), committed_round=0
+        cls,
+        rounds: list[Round],
+        payloads=frozenset(),
+        committed_round=0,
+        tc_entered: Round = 0,
+        block: Block | None = None,
     ) -> "ProposerMessage":
         return cls(
             cls.CLEANUP,
             rounds=rounds,
             payloads=payloads,
             committed_round=committed_round,
+            tc_entered=tc_entered,
+            block=block,
         )
 
     @classmethod
@@ -620,14 +636,17 @@ class Core:
             if b.reconfig is not None:
                 await self._apply_reconfig(b, cqc)
         # Tell the proposer what committed: (a) it prunes those digests
-        # from its buffer — with single-homed clients (node/client.py)
-        # queues are disjoint so this is defense-in-depth against
-        # producers that DO multi-home a payload (each would otherwise
-        # be re-proposed by every node that buffered it); (b) the
-        # committed_round lets it resolve its in-flight proposals —
-        # payloads of orphaned blocks return to the buffer (orphan
-        # recovery; the reference instead drops whole per-round buckets
-        # on cleanup, proposer.rs:164-173, losing them entirely).
+        # from its buffer.  A digest sits in more than one buffer as a
+        # rule, not as an exception: its home node relays it to the
+        # next leader every round until a processed block carries it,
+        # and a copy that arrived after that leader's Make stays behind
+        # (processed blocks prune first, _cleanup_proposer; this is the
+        # backstop, and the only pruning for a node that never saw the
+        # block); (b) the committed_round lets it resolve the processed
+        # blocks it tracks — payloads of orphaned blocks return to
+        # their home's buffer (orphan recovery; the reference instead
+        # drops whole per-round buckets on cleanup, proposer.rs:164-173,
+        # losing them entirely).
         with _spans.span("core.commit", node=self._node, round=block.round):
             if self.payload_bodies is not None:
                 self.payload_bodies.mark_committed(committed_payloads)
@@ -828,7 +847,10 @@ class Core:
         # (best effort — a full queue just means the signal is late).
         try:
             self.tx_proposer.put_nowait(
-                ProposerMessage.cleanup([self.round - 1])
+                ProposerMessage.cleanup(
+                    [self.round - 1],
+                    tc_entered=self.round if via_tc else 0,
+                )
             )
         except asyncio.QueueFull:
             pass
@@ -845,7 +867,9 @@ class Core:
 
     async def _cleanup_proposer(self, b0: Block, b1: Block, block: Block) -> None:
         await self.tx_proposer.put(
-            ProposerMessage.cleanup([b0.round, b1.round, block.round])
+            ProposerMessage.cleanup(
+                [b0.round, b1.round, block.round], block=block
+            )
         )
 
     def _process_qc(self, qc: QC) -> None:
@@ -1049,6 +1073,9 @@ class Core:
         b0, b1 = ancestors
 
         await self.store_block(block)
+        # before any Make that builds on this block: the proposer takes
+        # payloads only onto a parent whose processing it has seen
+        await self._cleanup_proposer(b0, b1, block)
         if block.payloads and block.round > self.last_payload_round:
             self.last_payload_round = block.round
             # If we lead the current round and our Make went out before
@@ -1066,7 +1093,6 @@ class Core:
                 and self.last_payload_round > self.last_committed_round
             ):
                 await self._generate_proposal(None)
-        await self._cleanup_proposer(b0, b1, block)
 
         # 2-chain commit rule.
         if b0.round + 1 == b1.round:

@@ -27,6 +27,41 @@ reference's scheme produces):
   cleanups for later rounds).  No store reads at all on the payload
   path; consensus paces itself to the payload arrival rate instead of
   spinning empty rounds into view changes.
+
+Digest relay (ISSUE 27).  A client hands a payload to ONE node, its
+home, and leaders rotate: left alone, a digest waits in its home's FIFO
+for the home's turn, n/2 rounds on the median (6.3 s of a 6.8 s commit
+latency at 64 nodes).  So the home hands the digest (never the body) to
+the node that makes the next block:
+
+- *When and to whom.*  Once a round, right after the core processed the
+  round's block (a ``Cleanup`` carrying ``block``) or entered the round
+  by a TC (``tc_entered``), the home sends ONE best-effort
+  frame (``wire.encode_relay``) with the digests it admitted that are
+  still in ``pending`` to ``leader(round + 1)``, the node its vote goes
+  to.  Nothing is sent when this node leads one of the next two rounds
+  (it proposes them itself; only digests an orphaned block has carried
+  still go, or a leader whose blocks always orphan, the one before a
+  dead node, would propose and lose them every rotation),
+  relayed-in digests are never relayed on, and a digest goes out again
+  every round until a processed block carries it: a frame that arrives
+  after the target's Make costs one round, not a rotation.  The
+  decision reads the elector and the round alone; there is no option.
+- *Exactly once.*  A digest now sits in several buffers, so two rules
+  keep it out of two blocks of one chain.  (1) Prune at processing:
+  the payloads of every block the core processes, anyone's, leave
+  ``pending`` and are tracked in ``inflight`` (round -> digests) until
+  the chain commits through that round; a relayed digest that is in a
+  tracked block or recently committed is refused on arrival.  Orphans
+  (the chain committed past the round without them) return to the
+  FRONT of their HOME's buffer only, which relays them again.  (2) No
+  payloads on an unseen parent: a ``Make`` whose ``qc.hash`` names a
+  block whose processing this proposer has not seen takes no payloads;
+  it proposes empty when ``allow_empty`` holds and otherwise waits for
+  that block's message.  ``Make`` and the processed-block message
+  travel on one queue, so their order is the core's order.
+- *What it tells.*  Span ``ingest.relay`` (one a frame sent, one a
+  frame received) and one ``Proposer stats:`` line a node every 5 s.
 """
 
 from __future__ import annotations
@@ -37,14 +72,14 @@ import os
 from collections import OrderedDict
 
 from ..crypto import Digest, PublicKey, SignatureService
-from ..network import ReliableSender
+from ..network import ReliableSender, SimpleSender
 from ..telemetry import spans as _spans
 from ..utils.clock import default_clock
 from .config import Committee
 from .core import ProposerMessage
 from .messages import MAX_BLOCK_PAYLOADS, QC, TC, Block, Round
 from .reconfig import ReconfigOp, newest_epoch
-from .wire import encode_propose
+from .wire import MAX_PRODUCER_BATCH, encode_propose, encode_relay
 
 log = logging.getLogger(__name__)
 
@@ -61,6 +96,12 @@ SEEN_CAP = 200_000
 # still unseen AFTER this many newer proposals resolved — and the
 # committed_seen LRU (SEEN_CAP deep) still filters those on resolution.
 MAX_INFLIGHT = 1_024
+# Digests of processed blocks remembered for the unseen-parent rule: a
+# Make builds on the newest QC's block, a few rounds old at most.
+PROCESSED_CAP = 1_024
+# One ``Proposer stats:`` line a node this often (seconds), beside the
+# verify service's and the process's own.
+STATS_EVERY_S = 5.0
 
 
 def _env_int(name: str, default: int) -> int:
@@ -86,8 +127,17 @@ class Proposer:
         telemetry=None,
         adversary=None,
         admission=None,
+        leader_elector=None,
+        relay_network: SimpleSender | None = None,
     ):
         self.name = name
+        # Digest relay (module docstring): who leads which round, and a
+        # best-effort sender to reach the next leader with (the core's
+        # own, so the frame travels on the connection the vote takes).
+        # Either None (component tests construct the proposer bare):
+        # nothing is relayed.
+        self.leader_elector = leader_elector
+        self.relay_network = relay_network
         # Ingest admission controller (ingest/admission.py): fed the
         # committed-payload counts from Cleanup messages — the drain
         # signal its credit window is derived from.  None = no ingest
@@ -111,15 +161,38 @@ class Proposer:
         self.tx_loopback = tx_loopback
         # FIFO with O(1) membership/removal: committed payloads are
         # pruned by digest on every commit (Core._commit cleanup).
-        self.pending: OrderedDict[Digest, None] = OrderedDict()
+        self.pending: OrderedDict[Digest, float | None] = OrderedDict()
         self.seen: OrderedDict[Digest, None] = OrderedDict()
-        # Our proposals whose fate is undecided: round -> payloads.
-        # With single-homed clients (node/client.py round-robin) only WE
-        # hold these digests — if the block orphans (a view change built
-        # the chain past it), they must return to the buffer or they are
-        # lost for good.  Resolved by commit signals (cleanup messages
-        # carrying committed_round).
+        # Digests this node admitted from its own clients and that have
+        # not committed: digest -> when it was admitted (monotonic s;
+        # 0.0 once a processed block has carried it).  Only these are
+        # relayed, and only these return to the buffer when a block
+        # that carried them orphans: the home is the one node sure to
+        # hold the digest, and its body.
+        self.home: dict[Digest, float] = {}
+        # Those of them an orphaned block carried and no block since:
+        # relayed even while this node leads soon (module docstring).
+        self.orphans: dict[Digest, None] = {}
+        # Processed blocks whose fate is undecided, own or others':
+        # round -> payloads.  If a block orphans (a view change built
+        # the chain past it), its payloads must return to their home's
+        # buffer or they are lost for good.  Resolved by commit signals
+        # (cleanup messages carrying committed_round).
         self.inflight: dict[Round, tuple] = {}
+        # Blocks whose processing this proposer has seen (its own count
+        # from their making): what a Make may put payloads on.
+        self.processed: OrderedDict[Digest, None] = OrderedDict()
+        # newest round relayed for / committed through
+        self.relayed_round: Round = 0
+        self.committed_round: Round = 0
+        # The ``Proposer stats`` line's counters, all cumulative.
+        self.relayed_digests = 0  # digests sent in relay frames
+        self.relay_frames = 0  # relay frames sent
+        self.proposed_relayed = 0  # payloads proposed for another home
+        self.proposed_home = 0  # payloads proposed for our own clients
+        self.wait_s_sum = 0.0  # admitted here -> in a processed block
+        self.wait_count = 0
+        self._next_stats = 0.0
         # Recently COMMITTED digests (bounded LRU): orphan recovery must
         # not re-buffer a payload that committed in an EARLIER walk via
         # another node's block (multi-homed producers) — the per-walk
@@ -164,6 +237,21 @@ class Proposer:
                 "Own proposals whose commit/orphan fate is undecided",
                 fn=lambda: len(self.inflight),
             )
+            for gauge, help_text, attr in (
+                ("proposer_relayed_digests",
+                 "Payload digests sent to the next leader", "relayed_digests"),
+                ("proposer_relay_frames",
+                 "Relay frames sent to the next leader", "relay_frames"),
+                ("proposer_proposed_relayed",
+                 "Payloads proposed here for another node's clients",
+                 "proposed_relayed"),
+                ("proposer_proposed_home",
+                 "Payloads proposed here for this node's own clients",
+                 "proposed_home"),
+            ):
+                telemetry.gauge(
+                    gauge, help_text, fn=lambda a=attr: getattr(self, a)
+                )
             telemetry.gauge(
                 "proposer_drop_newest",
                 "Payloads silently dropped at the full buffer "
@@ -171,7 +259,7 @@ class Proposer:
                 fn=lambda: self.drop_newest,
             )
 
-    def _buffer_payload(self, digest: Digest) -> None:
+    def _buffer_payload(self, digest: Digest, home: bool = True) -> None:
         if digest in self.seen:
             return  # duplicate of a buffered or recently proposed payload
         if len(self.pending) >= self.max_pending:
@@ -180,10 +268,25 @@ class Proposer:
         self.seen[digest] = None
         while len(self.seen) > SEEN_CAP:
             self.seen.popitem(last=False)
-        if self._payload_wait is not None:
-            self.pending[digest] = default_clock().monotonic()
-        else:
-            self.pending[digest] = None
+        now = default_clock().monotonic()
+        if home:
+            self.home[digest] = now
+        self.pending[digest] = now
+
+    def _buffer_item(self, item) -> None:
+        """One item of the producer queue: a client's digest this node
+        admitted (it is the digest's home), or a peer's relay frame, a
+        tuple of digests that peer admitted."""
+        if type(item) is not tuple:
+            self._buffer_payload(item)
+            return
+        # the frame may be older than the block that carried its
+        # digests: anything in a tracked block or recently committed
+        # must not enter the buffer again
+        tracked = self._tracked()
+        for digest in item:
+            if digest not in tracked and digest not in self.committed_seen:
+                self._buffer_payload(digest, home=False)
 
     async def _make_block(
         self, round_: Round, qc: QC, tc: TC | None, allow_empty: bool = False
@@ -213,35 +316,44 @@ class Proposer:
                     self.adversary.count("byz_forged_reconfigs")
                     self.adversary.record("reconfig-forge", round_)
                     self.log.info("byz reconfig-forge round %d", round_)
-            if not self.pending and not allow_empty and op is None:
-                # Defer: fire the moment the next payload arrives instead of
-                # wedging the round until the view-change timer (see module
-                # docstring).  A newer Make supersedes this one.
+            # No payloads on an unseen parent (module docstring): votes
+            # can overtake the proposal, and the block they certify may
+            # carry digests that are still in this buffer.
+            parent_seen = self._parent_seen(qc)
+            if (
+                (not self.pending or not parent_seen)
+                and not allow_empty
+                and op is None
+            ):
+                # Defer: fire the moment the next payload (or the parent's
+                # processed-block message) arrives instead of wedging the
+                # round until the view-change timer (see module docstring).
+                # A newer Make supersedes this one.
                 self.deferred = ProposerMessage.make(round_, qc, tc)
                 if self._deferred_makes is not None:
                     self._deferred_makes.inc()
-                self.log.info("Round: %d, no payloads yet - proposal deferred", round_)
+                self.log.info(
+                    "Round: %d, %s - proposal deferred", round_,
+                    "no payloads yet" if parent_seen
+                    else "parent not processed yet",
+                )
                 return
             # allow_empty: the core signalled that uncommitted payload blocks
             # are in flight — an empty block advances the 2-chain so they
             # commit now rather than on the producer's next burst.
             self.last_made_round = round_
-            take = min(len(self.pending), MAX_BLOCK_PAYLOADS)
-            if self._payload_wait is not None and take:
+            take = min(len(self.pending), MAX_BLOCK_PAYLOADS) if parent_seen else 0
+            popped = [self.pending.popitem(last=False) for _ in range(take)]
+            payloads = tuple(d for d, _ in popped)
+            if popped:
                 now = default_clock().monotonic()
-                popped = [self.pending.popitem(last=False) for _ in range(take)]
-                for _, arrived in popped:
-                    if arrived:  # re-buffered orphans may carry None
-                        self._payload_wait.observe(now - arrived)
-                payloads = tuple(d for d, _ in popped)
-            else:
-                payloads = tuple(
-                    self.pending.popitem(last=False)[0] for _ in range(take)
-                )
-            if payloads:
-                self.inflight[round_] = payloads
-                while len(self.inflight) > MAX_INFLIGHT:
-                    self._requeue_oldest_inflight()
+                if self._payload_wait is not None:
+                    for _, arrived in popped:
+                        if arrived:  # re-buffered orphans may carry None
+                            self._payload_wait.observe(now - arrived)
+                ours = sum(self._carried(d, now) for d in payloads)
+                self.proposed_home += ours
+                self.proposed_relayed += take - ours
 
             if op is not None and op is self.pending_reconfig:
                 self.pending_reconfig = None  # it rides in this block
@@ -250,6 +362,10 @@ class Proposer:
                 reconfig=op,
             )
             digest = block.digest()
+            # our own block counts as processed from here on: its
+            # payloads have left the buffer, and the core's message for
+            # it finds it tracked already
+            self._track(block)
         block.signature = await self.signature_service.request_signature(digest)
         with _spans.span("proposer.make", node=self._node, round=round_):
             if op is not None:
@@ -373,19 +489,152 @@ class Proposer:
             block.round, block.digest(), shadow.digest(), len(targets),
         )
 
+    def _parent_seen(self, qc: QC) -> bool:
+        return qc.is_genesis() or qc.hash in self.processed
+
+    def _carried(self, digest: Digest, now: float) -> bool:
+        """A block this proposer made or saw processed carries
+        ``digest``.  True if this node is its home; the first time,
+        that ends its wait (admitted here -> in a processed block)."""
+        admitted = self.home.get(digest)
+        if admitted is None:
+            return False
+        if admitted:
+            self.wait_s_sum += now - admitted
+            self.wait_count += 1
+            self.home[digest] = 0.0
+        else:
+            self.orphans.pop(digest, None)
+        return True
+
+    def _track(self, block: Block) -> bool:
+        """Remember a processed block (or one just made here) until the
+        chain commits through its round.  False if it is tracked
+        already, or too old to matter."""
+        digest = block.digest()
+        if digest in self.processed:
+            return False
+        self.processed[digest] = None
+        while len(self.processed) > PROCESSED_CAP:
+            self.processed.popitem(last=False)
+        if block.round <= self.committed_round:
+            # a block below the commit cursor (a late sync reply): if
+            # it is on the chain its payloads were pruned at commit, if
+            # it is not it decides nothing
+            return False
+        if block.payloads:
+            # an equivocating leader's twin shares its round
+            self.inflight[block.round] = (
+                self.inflight.get(block.round, ()) + block.payloads
+            )
+            while len(self.inflight) > MAX_INFLIGHT:
+                self._requeue_oldest_inflight()
+        return True
+
+    def _tracked(self) -> set:
+        """Every digest a tracked block carries: a few rounds' worth,
+        gathered when a relay frame arrives or a block orphans, not
+        kept up on every node for every block."""
+        return set().union(*self.inflight.values())
+
+    def _on_processed(self, block: Block) -> None:
+        """Prune at processing: the core processed ``block`` (anyone's),
+        so its payloads leave the buffer now, two rounds before they
+        commit, and a leader that builds on it cannot propose them
+        again."""
+        if not self._track(block):
+            return
+        # 64 nodes do this for every block: most hold none of its
+        # payloads, and a miss costs a hash
+        pending, home = self.pending, self.home
+        if pending:
+            for d in block.payloads:
+                pending.pop(d, None)
+        if home:
+            ours = [d for d in block.payloads if d in home]
+            if ours:
+                now = default_clock().monotonic()
+                for d in ours:
+                    self._carried(d, now)
+
+    async def _relay(self, round_: Round, made: bool) -> None:
+        """Hand this node's own clients' digests to the node that makes
+        block ``round_ + 1`` (module docstring).  ``made``: block
+        ``round_`` exists already (the call follows its processing);
+        after a TC it does not, and its leader's Make is still behind
+        this message in the queue."""
+        if self.leader_elector is None or self.relay_network is None:
+            return
+        if round_ <= self.relayed_round:
+            return
+        self.relayed_round = round_
+        if not self.home:
+            return
+        leader = self.leader_elector.get_leader
+        ours = self.home
+        for ahead in range(1 if made else 0, 3):
+            if leader(round_ + ahead) == self.name:
+                # we lead soon: these ride in our own block, unless a
+                # block of ours (or anyone's) has orphaned them before
+                ours = self.orphans
+                break
+        pending = self.pending
+        digests = []
+        for d in ours:
+            if d in pending:
+                digests.append(d)
+                if len(digests) == MAX_PRODUCER_BATCH:
+                    break
+        if not digests:
+            return
+        with _spans.span("ingest.relay", node=self._node, round=round_):
+            address = self.committee.for_round(round_ + 1).address(
+                leader(round_ + 1)
+            )
+            if address is None:
+                return
+            frame = encode_relay(digests)
+            self.relay_frames += 1
+            self.relayed_digests += len(digests)
+        await self.relay_network.send(address, frame)
+
+    def _log_stats(self) -> None:
+        now = default_clock().monotonic()
+        if now < self._next_stats:
+            return
+        self._next_stats = now + STATS_EVERY_S
+        # NOTE: this log entry is used to compute performance
+        # (chipbench/readers/proposerstats.py): cumulative counters, a
+        # reader takes last less first.
+        self.log.info(
+            "Proposer stats: relayed=%d relay_frames=%d proposed_relayed=%d "
+            "proposed_home=%d wait_ms_sum=%.1f wait_n=%d",
+            self.relayed_digests,
+            self.relay_frames,
+            self.proposed_relayed,
+            self.proposed_home,
+            self.wait_s_sum * 1e3,
+            self.wait_count,
+        )
+
     def _requeue_orphans(
         self, round_: Round, payloads: tuple, committed=frozenset(), note: str = ""
     ) -> None:
-        """Re-buffer a resolved/abandoned proposal's payloads at the
+        """Re-buffer a resolved/abandoned block's payloads at the
         FRONT of the queue (oldest-first order preserved by callers
-        iterating newest-round-first), skipping anything known
-        committed or already buffered."""
+        iterating newest-round-first): those this node is the home of,
+        skipping anything known committed, carried by another tracked
+        block, or already buffered."""
         orphaned = [
             d for d in payloads
-            if d not in committed
+            if d in self.home
+            and d not in committed
             and d not in self.committed_seen
             and d not in self.pending
         ]
+        if orphaned:
+            tracked = self._tracked()  # without this block: it was popped
+            orphaned = [d for d in orphaned if d not in tracked]
         if orphaned:
             self.log.info(
                 "Re-buffering %d payloads from %s block %d",
@@ -396,6 +645,7 @@ class Proposer:
         for digest in reversed(orphaned):
             self.pending[digest] = None
             self.pending.move_to_end(digest, last=False)
+        self.orphans.update(dict.fromkeys(orphaned))
 
     def _requeue_oldest_inflight(self) -> None:
         """Inflight overflow (MAX_INFLIGHT): re-buffer the oldest
@@ -409,12 +659,14 @@ class Proposer:
 
     def _resolve_inflight(self, message: ProposerMessage) -> None:
         """Orphan recovery: once the chain is committed through round R,
-        every proposal of ours at round <= R either committed (its
+        every tracked block at round <= R either committed (its
         payloads are in the accumulated committed sets) or was orphaned
-        by a view change — re-buffer the orphans at the FRONT of the
-        queue (oldest first) so single-homed payloads are never lost."""
+        by a view change — re-buffer our clients' orphans at the FRONT
+        of the queue (oldest first) so single-homed payloads are never
+        lost."""
         if not message.committed_round:
             return
+        self.committed_round = max(self.committed_round, message.committed_round)
         for round_ in sorted(
             (r for r in self.inflight if r <= message.committed_round),
             reverse=True,  # re-insert newest first so oldest ends up in front
@@ -440,17 +692,19 @@ class Proposer:
                 if prod_task in done:
                     # lint: allow(no-blocking-in-async) -- guarded by
                     # membership in asyncio.wait's done set
-                    digest = prod_task.result()
+                    item = prod_task.result()
                     with _spans.span("ingest.buffer", node=self._node):
-                        self._buffer_payload(digest)
+                        self._buffer_item(item)
                         # drain any burst backlog without extra loop passes
                         while not self.rx_producer.empty():
-                            self._buffer_payload(
-                                self.rx_producer.get_nowait()
-                            )
+                            self._buffer_item(self.rx_producer.get_nowait())
                     prod_task = asyncio.ensure_future(self.rx_producer.get())
-                    if self.deferred is not None and self.pending:
-                        make = self.deferred
+                    make = self.deferred
+                    if (
+                        make is not None
+                        and self.pending
+                        and self._parent_seen(make.qc)
+                    ):
                         self.deferred = None
                         await self._make_block(make.round, make.qc, make.tc)
                 if msg_task in done:
@@ -499,12 +753,37 @@ class Proposer:
                             if self.admission is not None and message.payloads:
                                 # drain signal for the ingest credit window
                                 self.admission.on_committed(len(message.payloads))
+                            home = self.home
                             for digest in message.payloads:
                                 self.pending.pop(digest, None)
                                 self.committed_seen[digest] = None
+                                if home and home.pop(digest, None) is not None:
+                                    self.orphans.pop(digest, None)
                             while len(self.committed_seen) > SEEN_CAP:
                                 self.committed_seen.popitem(last=False)
                             self._resolve_inflight(message)
+                            block = message.block
+                            if block is not None:
+                                self._on_processed(block)
+                                self._log_stats()
+                        # the round's relay: after its block was pruned
+                        # from the buffer, or on the TC that brought no
+                        # block (an older block, a sync reply, is behind
+                        # relayed_round and sends nothing)
+                        if block is not None:
+                            await self._relay(block.round, made=True)
+                        elif message.tc_entered:
+                            await self._relay(message.tc_entered, made=False)
+                        make = self.deferred
+                        if (
+                            make is not None
+                            and block is not None
+                            and self.pending
+                            and self._parent_seen(make.qc)
+                        ):
+                            # the parent a Make was waiting for
+                            self.deferred = None
+                            await self._make_block(make.round, make.qc, make.tc)
                     msg_task = asyncio.ensure_future(self.rx_message.get())
         finally:
             prod_task.cancel()

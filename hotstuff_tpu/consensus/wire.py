@@ -2,7 +2,9 @@
 
 Parity target: ``ConsensusMessage`` (reference consensus/src/consensus.rs:
 30-38): Propose(Block), Vote, Timeout, TC, SyncRequest(digest, origin),
-Producer(digest) — the fork's payload-ingest message.
+Producer(digest) — the fork's payload-ingest message — and, node to
+node, Relay(digests): payload digests a node admitted, handed to the
+node that makes the next block (consensus/proposer.py).
 """
 
 from __future__ import annotations
@@ -35,6 +37,7 @@ TAG_STATE_MANIFEST = 8
 TAG_STATE_CHUNK = 9
 TAG_STATE_READ = 10
 TAG_RECONFIG = 11
+TAG_RELAY = 12
 
 ACK = b"Ack"
 
@@ -139,6 +142,22 @@ def encode_producer_batch(items) -> bytes:
     for digest, body in items:
         enc.raw(digest.to_bytes())
         enc.var_bytes(body)
+    return enc.finish()
+
+
+def encode_relay(digests) -> bytes:
+    """Digest relay (docs/LOAD.md): the digests of payloads the sender
+    admitted from its clients and will not propose soon, for the node
+    that makes the next block.  Digests only — bodies stay with their
+    home node — and best effort: no ACK, the next round's frame is the
+    retry."""
+    if not digests or len(digests) > MAX_PRODUCER_BATCH:
+        raise ValueError(
+            f"relay frame must carry 1..{MAX_PRODUCER_BATCH} digests"
+        )
+    enc = Encoder().u8(TAG_RELAY).u32(len(digests))
+    for digest in digests:
+        enc.raw(digest.to_bytes())
     return enc.finish()
 
 
@@ -466,7 +485,7 @@ def decode_message(data: bytes, scheme: str | None = None):
     (Digest, body), ProducerV2 -> tuple of (Digest, body) pairs,
     StateRequest -> StateRequest, StateManifest -> StateManifestMsg,
     StateChunk -> StateChunkMsg, StateRead -> (space, key),
-    Reconfig -> ReconfigOp.
+    Reconfig -> ReconfigOp, Relay -> tuple of Digest.
 
     ``scheme`` (the committee's signature scheme) narrows accepted
     key/signature wire sizes to that scheme's; None accepts the union.
@@ -562,6 +581,14 @@ def decode_message(data: bytes, scheme: str | None = None):
             out = (space, dec.var_bytes(MAX_STATE_KEY))
         elif tag == TAG_RECONFIG:
             out = ReconfigOp.decode(dec)
+        elif tag == TAG_RELAY:
+            count = dec.u32()
+            if not 1 <= count <= MAX_PRODUCER_BATCH:
+                raise CodecError(
+                    f"relay digest count {count} outside "
+                    f"1..{MAX_PRODUCER_BATCH}"
+                )
+            out = tuple(Digest(dec.raw(Digest.SIZE)) for _ in range(count))
         else:
             raise CodecError(f"unknown message tag {tag}")
         dec.finish()

@@ -49,7 +49,7 @@ import os
 FRAME_OVERHEAD = 4
 
 #: first wire byte -> message class.  Tag values mirror
-#: consensus/wire.py (TAG_PROPOSE..TAG_RECONFIG, ACK[0], INGEST_ACK_TAG,
+#: consensus/wire.py (TAG_PROPOSE..TAG_RELAY, ACK[0], INGEST_ACK_TAG,
 #: STATE_VALUE_TAG); kept as literals so this module stays a telemetry
 #: leaf with no consensus import — tests/test_flows.py pins the parity.
 _TAG_CLASS: dict = {
@@ -65,6 +65,7 @@ _TAG_CLASS: dict = {
     9: "state-sync",  # TAG_STATE_CHUNK
     10: "state-sync",  # TAG_STATE_READ
     11: "reconfig",
+    12: "relay",
     0x41: "ack",  # ACK = b"Ack"
     0xA2: "ingest-ack",  # INGEST_ACK_TAG
     0xA3: "state-sync",  # STATE_VALUE_TAG (state-read reply)

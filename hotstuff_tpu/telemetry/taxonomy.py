@@ -95,6 +95,7 @@ SPAN_HOST_STAGES: tuple = (
     # ingest: the producer path
     "ingest.admit",  # one producer frame: content check, admission
     "ingest.buffer",  # digests into the proposer's buffer
+    "ingest.relay",  # one relay frame: built and sent, or received
     # verify service, on the loop thread
     "verify.submit",  # a core's claims join the pending wave
     "verify.collect",  # the wave's dedup and collection
@@ -151,7 +152,7 @@ CONTROL_EDGES: tuple = (
 )
 
 #: producer-channel edges: leader-side payload wait attribution
-PAYLOAD_EDGES: tuple = ("recv.producer", "payload.first")
+PAYLOAD_EDGES: tuple = ("recv.producer", "recv.relay", "payload.first")
 
 #: admission-plane edges: value records (shed count / credit window in
 #: the ``u`` field), rendered as the ingest-plane track
@@ -212,6 +213,7 @@ FLOW_CLASSES: tuple = (
     "ingest-ack",
     "state-sync",
     "reconfig",
+    "relay",
     "ack",
     "other",
 )

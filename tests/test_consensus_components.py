@@ -187,6 +187,9 @@ def test_proposer_inflight_bound_requeues_oldest():
     proposer.log = logging.getLogger("test-proposer")
 
     digests = [Digest(bytes([i]) * 32) for i in range(8)]
+    # this node admitted them all: only a digest's home re-buffers it
+    proposer.home = dict.fromkeys(digests, 0.0)
+    proposer.orphans = {}
     for r in range(1, 6):
         proposer.inflight[r] = (digests[r],)
     proposer.committed_seen[digests[1]] = None  # round 1's payload committed

@@ -76,6 +76,7 @@ def test_frame_class_pins_live_wire_tags():
     ):
         assert frame_class(bytes([tag])) == "state-sync"
     assert frame_class(bytes([wire.TAG_RECONFIG])) == "reconfig"
+    assert frame_class(bytes([wire.TAG_RELAY])) == "relay"
     assert frame_class(wire.ACK) == "ack"
     assert frame_class(bytes([wire.INGEST_ACK_TAG])) == "ingest-ack"
     # unknown tags and the empty frame land in "other", never dropped
