@@ -678,10 +678,11 @@ def main(argv=None) -> int:
     p.add_argument(
         "--no-claim-dedup",
         action="store_true",
-        help="give every core a PRIVATE verify service (no cross-core "
-        "claim coalescing/dedup) — measures the per-node capability a "
-        "one-node-per-host deployment would see, without the "
-        "co-location artifact",
+        help="cross-node claim dedup off (HOTSTUFF_NO_CLAIM_DEDUP=1): an "
+        "--in-process committee keeps its one shared dispatch stream, and "
+        "every node's own copy of every certificate takes lanes of its own "
+        "(no verdict crosses a node boundary); with one node a process "
+        "nothing is shared and nothing changes",
     )
     p.set_defaults(fn=task_local)
 

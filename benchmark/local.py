@@ -85,8 +85,11 @@ class LocalBench:
         self.rate = rate
         self.tx_size = tx_size
         self.payload_homes = payload_homes
-        # VERDICT r4 weak #2: per-node private verify services — no
-        # cross-core claim dedup, measuring undeduped per-node capability
+        # HOTSTUFF_NO_CLAIM_DEDUP=1 for every node process: a process's
+        # verify service keeps one lane for every submitted claim.  With
+        # one node a process nothing is shared, so nothing changes; an
+        # --in-process committee keeps its one dispatch stream and has
+        # every node's own copy of every certificate verified
         self.no_claim_dedup = no_claim_dedup
         # WAN emulation: write a 5-region link-delay spec and point the
         # committee at it (hotstuff_tpu/network/wan.py)

@@ -18,7 +18,11 @@ this parent never imports jax:
               (LazyDeviceVerifier("tpu").warmup); per pad shape a Mosaic
               custom call in the lowering and every lane of
               BatchVerifier.verify_device equal to crypto/ed25519_ref;
-              QC-shaped waves through AsyncVerifyService
+              QC-shaped waves through AsyncVerifyService; the wave of
+              the colo64.nodedup deployment (64 submitters' own copies
+              of one proposal, claim dedup off, 2,816 signatures) in
+              three kernel calls of 1,024 lanes, every submitter's
+              verdict equal to crypto/ed25519_ref's
 2. build      native/build/ removed, `make -C native`, every library
               loaded (no jax; second, so a host without a chip has
               already failed)
@@ -75,6 +79,14 @@ DEPLOYMENT = (
 SERVICE_BUCKETS = (16, 64, 256, 1024)
 SERVICE_WAVES = 10
 SPOIL_EVERY = 10
+
+#: the colo64.nodedup wave: every node hands in its own copy of one
+#: proposal's certificates (a 43-vote QC claim and the block's
+#: signature); in each wave a few submitters' copies are corrupt
+FANOUT_SUBMITTERS = 64
+FANOUT_QC_VOTES = 43
+FANOUT_WAVES = 6
+FANOUT_LANES = [1024, 1024, 1024]
 
 RESULT_TAG = "RESULT "
 TRACEBACK = "Traceback (most recent call last)"
@@ -435,6 +447,140 @@ def _drive_service(backend, keys) -> dict:
     return stats
 
 
+def _drive_fanout_wave(backend, keys) -> dict:
+    """The wave of the ``colo64.nodedup`` deployment, formed as the
+    timed path forms it: 64 submitters hand their own copy of one
+    proposal's claims to the one shared service with the claim dedup
+    off.  Every submitter's verdicts equal crypto/ed25519_ref's for its
+    own copy (a corrupt copy fails for its submitter alone), and the
+    2,816 signatures reach the device in three calls of 1,024 lanes."""
+    import asyncio
+    import random
+
+    from hotstuff_tpu.crypto import Digest, Signature
+    from hotstuff_tpu.crypto import ed25519_ref as ref
+    from hotstuff_tpu.crypto.async_service import AsyncVerifyService
+
+    os.environ["HOTSTUFF_FORCE_DEVICE_ROUTE"] = "1"
+    os.environ["HOTSTUFF_NO_CLAIM_DEDUP"] = "1"
+    view = backend.async_backend
+    calls: list[int] = []
+    inner = view.verify_many
+
+    def counted(digests, pks, sigs, aggregate_ok=False):
+        calls.append(len(digests))
+        return inner(digests, pks, sigs, aggregate_ok)
+
+    memo: dict[tuple, bool] = {}
+
+    def by_reference(claim) -> bool:
+        rows = (
+            [(claim[1], claim[2], claim[3])]
+            if claim[0] == "one"
+            else [(claim[1], pk, sig) for pk, sig in claim[2]]
+        )
+        for row in rows:
+            if row not in memo:
+                memo[row] = ref.verify(row[2], row[1], row[0])
+        return all(memo[row] for row in rows)
+
+    def flipped(sig: bytes) -> bytes:
+        return bytes([sig[0] ^ 1]) + sig[1:]
+
+    def submissions(wave: int) -> list[list]:
+        """Each submitter's [QC claim, block signature claim]; three
+        submitters hold a QC copy with one vote's signature corrupt,
+        two a corrupt block signature (one holds both)."""
+        rng = random.Random(28_000 + wave)
+        parent = Digest.of(b"chip smoke fan-out parent %d" % wave)
+        votes = [
+            (pk.to_bytes(), Signature.new(parent, sk).to_bytes())
+            for pk, sk in keys[:FANOUT_QC_VOTES]
+        ]
+        block = Digest.of(b"chip smoke fan-out block %d" % wave)
+        author_pk, author_sk = keys[FANOUT_QC_VOTES + wave]
+        author_sig = Signature.new(block, author_sk).to_bytes()
+        bad_qc = rng.sample(range(FANOUT_SUBMITTERS), 3)
+        bad_block = [bad_qc[0], rng.randrange(FANOUT_SUBMITTERS)]
+        out = []
+        for i in range(FANOUT_SUBMITTERS):
+            copy = list(votes)
+            if i in bad_qc:
+                at = rng.randrange(FANOUT_QC_VOTES)
+                copy[at] = (copy[at][0], flipped(copy[at][1]))
+            out.append([
+                ("shared", parent.to_bytes(), tuple(copy)),
+                ("one", block.to_bytes(), author_pk.to_bytes(),
+                 flipped(author_sig) if i in bad_block else author_sig),
+            ])
+        return out
+
+    async def drive() -> dict:
+        svc = AsyncVerifyService(backend, device=True)
+        walls, wrong = [], 0
+        try:
+            for wave in range(FANOUT_WAVES):
+                handed = submissions(wave)
+                want = [[by_reference(c) for c in cs] for cs in handed]
+                t0 = time.perf_counter()
+                got = await asyncio.gather(
+                    *(svc.verify_claims(cs) for cs in handed)
+                )
+                walls.append((time.perf_counter() - t0) * 1e3)
+                wrong += sum(g != w for g, w in zip(got, want))
+                failed = sum(not all(w) for w in want)
+                if not 3 <= failed <= 5:
+                    raise SmokeFailure(
+                        f"fan-out wave {wave}: {failed} corrupt copies planted"
+                    )
+            return {
+                "waves": FANOUT_WAVES,
+                "submitters": FANOUT_SUBMITTERS,
+                "wrong_submitters": wrong,
+                "submitted_sigs": svc.submitted_sigs,
+                "device_sigs": svc.device_sigs,
+                "cpu_sigs": svc.cpu_sigs,
+                "lanes": svc.lanes,
+                "chunks": svc.chunks,
+                "device_dispatches": svc.device_dispatches,
+                "deadline_misses": svc.deadline_misses,
+                "kernel_call_rows": sorted(set(map(tuple, (
+                    calls[i:i + 3] for i in range(0, len(calls), 3)
+                )))),
+                "ewma_ms": round((svc._device_ewma_s or 0.0) * 1e3, 3),
+                "wave_ms": [round(w, 2) for w in walls],
+            }
+        finally:
+            svc.close()
+
+    view.verify_many = counted
+    try:
+        stats = asyncio.run(drive())
+    finally:
+        view.verify_many = inner
+        del os.environ["HOTSTUFF_NO_CLAIM_DEDUP"]
+    say(f"fan-out wave: {json.dumps(stats)}")
+    sigs = FANOUT_WAVES * FANOUT_SUBMITTERS * (FANOUT_QC_VOTES + 1)
+    if (
+        stats["wrong_submitters"]
+        or stats["submitted_sigs"] != sigs
+        or stats["device_sigs"] != sigs
+        or stats["cpu_sigs"]
+        or stats["device_dispatches"] != FANOUT_WAVES
+        or stats["chunks"] != FANOUT_WAVES * len(FANOUT_LANES)
+        or stats["lanes"] != FANOUT_WAVES * sum(FANOUT_LANES)
+        or stats["kernel_call_rows"] != [tuple(FANOUT_LANES)]
+        or stats["deadline_misses"] > 1
+    ):
+        raise SmokeFailure(
+            "fan-out wave: every submitter's verdicts must equal the "
+            f"reference's, each wave of {sigs // FANOUT_WAVES} signatures "
+            f"one dispatch of kernel calls {FANOUT_LANES}, none on the "
+            f"CPU and at most one past its deadline: {stats}"
+        )
+    return stats
+
+
 def child_verify() -> None:
     try:
         from hotstuff_tpu.tpu import require_tpu  # the compile-cache rule
@@ -496,6 +642,7 @@ def child_verify() -> None:
     }
     doc.update(_check_lanes(backend._materialize(), PALLAS_PAD_SIZES))
     doc["service"] = _drive_service(backend, keys)
+    doc["fanout_wave"] = _drive_fanout_wave(backend, keys)
     _emit(doc)
 
 

@@ -79,6 +79,7 @@ from __future__ import annotations
 
 import asyncio
 import atexit
+import itertools
 import logging
 import queue
 import threading
@@ -753,7 +754,10 @@ class AsyncVerifyService:
     """
 
     _registry: dict[tuple, tuple] = {}  # (loop id, kind) -> (loop, service)
-    _serial = 0  # distinguishes private services' cumulative stat lines
+    _serial = 0  # distinguishes the services' cumulative stat lines
+    # one counter a process: a wave's serial joins its spans in a trace
+    # (``wave=<serial>``), whichever service of the process made it
+    _wave_serials = itertools.count(1)
 
     def __init__(
         self, backend, device: bool = False, pipeline_depth: int | None = None
@@ -761,10 +765,10 @@ class AsyncVerifyService:
         AsyncVerifyService._serial += 1
         # stable tag for the scraped stats line: kind#pid.serial —
         # cumulative counters from different service instances must be
-        # separable in MERGED logs: the serial separates private
-        # per-core services (--no-claim-dedup) within one process, the
-        # pid separates processes (every node process restarts the
-        # class counter at 1, and the parser sums the last line per tag)
+        # separable in MERGED logs: the serial separates the services
+        # of one process (one a loop and kind), the pid separates
+        # processes (every node process restarts the class counter at
+        # 1, and the parser sums the last line per tag)
         import os
 
         kind = getattr(backend, "async_kind", None) or getattr(
@@ -779,6 +783,9 @@ class AsyncVerifyService:
         # forced-device dispatch view, ``host.cpu_backend`` the fallback.
         self.backend = backend
         self.device = device
+        # HOTSTUFF_NO_CLAIM_DEDUP=1 (see for_backend): a wave keeps one
+        # entry for every submitted claim, not one for every distinct one
+        self._dedup = not os.environ.get("HOTSTUFF_NO_CLAIM_DEDUP")
         self._pending: list[tuple[list, asyncio.Future]] = []
         # profiling: perf_counter_ns stamps of device-path submissions in
         # the current coalescing window (empty unless HOTSTUFF_PROFILE)
@@ -827,6 +834,13 @@ class AsyncVerifyService:
         )
         self.device_sigs = 0
         self.cpu_sigs = 0
+        # what the cores handed in (before collection), the rows handed
+        # to the device (pads included) and the backend calls that took
+        # them: submitted over evaluated is the fan-out the dedup
+        # removes, device_sigs over lanes the buckets' occupancy
+        self.submitted_sigs = 0
+        self.lanes = 0
+        self.chunks = 0
         # compact-certificate ("agg") claims and the signer count they
         # covered — the one-pairing route (ISSUE 9); surfaced on the
         # stats line for benchmark/logs.py's agg columns
@@ -945,19 +959,14 @@ class AsyncVerifyService:
         pair — in-process committees all submit into the same dispatch
         stream; everything else gets a private inline service.
 
-        ``HOTSTUFF_NO_CLAIM_DEDUP=1`` gives every core a PRIVATE device
-        service instead: no cross-core claim coalescing or dedup.  This
-        is the honesty knob for in-process scale results (VERDICT r4
-        weak #2) — a real one-node-per-host deployment gets zero dedup,
-        and the per-node capability must be measurable without the
-        co-location artifact."""
-        import os
-
+        ``HOTSTUFF_NO_CLAIM_DEDUP=1`` keeps that one shared stream and
+        turns the cross-node dedup off: a wave carries every submitted
+        claim on lanes of its own, in submission order, and each core's
+        verdicts are read from its own lanes (a co-located committee
+        whose every node has its own certificates verified)."""
         kind = getattr(backend, "async_kind", None)
         if kind is None:
             return cls(backend, device=False)
-        if os.environ.get("HOTSTUFF_NO_CLAIM_DEDUP"):
-            return cls(backend, device=True)
         try:
             loop = asyncio.get_running_loop()
         except RuntimeError:
@@ -1032,7 +1041,7 @@ class AsyncVerifyService:
             # a wave is named when its batch gets its first claim, so
             # every span from this submit to the verdicts' delivery
             # carries the one serial
-            self._wave_serial += 1
+            self._wave_serial = next(self._wave_serials)
         with _spans.span("verify.submit", wave=self._wave_serial):
             loop = asyncio.get_running_loop()
             fut: asyncio.Future = loop.create_future()
@@ -1050,13 +1059,16 @@ class AsyncVerifyService:
 
     # ---- the dispatcher ----------------------------------------------------
 
-    def _deadline_s(self) -> float:
+    def _deadline_s(self, chunks: int = 1) -> float:
         """Per-dispatch deadline: a stall mid-dispatch must not
         stall the committee.  Backends may raise the floor (BLS: an
         adversarial storm legitimately takes ~0.4 s off-loop;
-        re-running it inline would BE the stall)."""
+        re-running it inline would BE the stall).  The floor is one
+        backend call's: a wave cut into ``chunks`` calls, run one after
+        another on its slot, gets as many floors (the EWMA mixes large
+        waves with small ones and cannot stand for either)."""
         return max(
-            getattr(self.backend, "dispatch_deadline_s", 0.1),
+            chunks * getattr(self.backend, "dispatch_deadline_s", 0.1),
             4 * (self._device_ewma_s or 0.1),
         )
 
@@ -1096,18 +1108,37 @@ class AsyncVerifyService:
             self._pad_claim = make_pad_claim()
         return self._pad_claim
 
-    def _pack_wave(self, claims: list, n_sigs: int) -> list:
-        """Pad a device-routed wave to the smallest bucket >= n_sigs
-        with copies of the pad claim.  Exact fits and waves past the
-        largest bucket pass through unpadded (the backend chunks
-        oversized batches on its own grid)."""
-        bucket = next((b for b in self.wave_buckets if b >= n_sigs), None)
-        if bucket is None or bucket == n_sigs:
-            return claims
+    def _pack_wave(self, claims: list, n_sigs: int) -> list[tuple]:
+        """A device-routed wave as the backend calls it takes: chunks
+        ``(claims, real, lanes)``, each padded to the smallest bucket >=
+        its signatures with copies of the pad claim (``real`` claims,
+        then pads; ``lanes`` rows in all).  A wave past the largest
+        bucket is cut between claims into chunks of at most that
+        bucket, so every call hits a warm shape; an exact fit goes
+        through unpadded, and so does a single claim past the largest
+        bucket (the backend cuts that on its own grid)."""
+        buckets = self.wave_buckets
+        parts = [(claims, n_sigs)]
+        if n_sigs > buckets[-1]:
+            parts, start, held = [], 0, 0
+            for i, claim in enumerate(claims):
+                k = claim_sig_count(claim)
+                if held and held + k > buckets[-1]:
+                    parts.append((claims[start:i], held))
+                    start, held = i, 0
+                held += k
+            parts.append((claims[start:], held))
         pad = self._pad_claim_tuple()
-        self.packed_waves += 1
-        self.pad_sigs += bucket - n_sigs
-        return list(claims) + [pad] * (bucket - n_sigs)
+        chunks = []
+        for part, sigs in parts:
+            bucket = next((b for b in buckets if b >= sigs), sigs)
+            real = len(part)
+            if bucket > sigs:
+                self.packed_waves += 1
+                self.pad_sigs += bucket - sigs
+                part = list(part) + [pad] * (bucket - sigs)
+            chunks.append((part, real, bucket))
+        return chunks
 
     def warm_buckets(self) -> None:
         """Pre-compile every wave bucket shape (ISSUE 6 warmup): drive
@@ -1201,16 +1232,17 @@ class AsyncVerifyService:
     def _spawn_device(
         self,
         loop,
-        claims: list,
+        chunks: list[tuple],
         measure_only: bool = False,
         deadline: float | None = None,
         wave: "AdoptedWave | None" = None,
         serial: int = 0,
     ):
-        """Start a device dispatch on the dedicated dispatch loop and
-        register it in the in-flight table (occupancy + deadline stamp
-        drive routing) under ``serial``, the wave's serial (the id of
-        its spans).  The slot thread delivers completion back to the
+        """Start a device dispatch of ``chunks`` (``_pack_wave``'s) on
+        the dedicated dispatch loop and register it in the in-flight
+        table (occupancy + deadline stamp drive routing) under
+        ``serial``, the wave's serial (the id of its spans).  The slot
+        thread delivers completion back to the
         event loop with ``call_soon_threadsafe``; delivery frees the
         slot, wakes any dispatcher queued in _wait_for_slot, and marks
         exceptions retrieved so abandoned waves (deadline-miss /
@@ -1232,7 +1264,9 @@ class AsyncVerifyService:
         # thread); every access is a single dict bytecode, atomic under
         # the GIL, and the routing reads tolerate one-wave staleness
         self._inflight[serial] = time.monotonic() + (
-            deadline if deadline is not None else self._deadline_s()
+            deadline
+            if deadline is not None
+            else self._deadline_s(len(chunks))
         )
         self.peak_inflight = max(self.peak_inflight, len(self._inflight))
         rec = _spans.recorder()
@@ -1275,7 +1309,7 @@ class AsyncVerifyService:
 
         self._dispatch.submit(
             lambda: self._dispatch_sync(
-                claims, t_spawn, end_holder, wave, serial
+                chunks, t_spawn, end_holder, wave, serial
             ),
             _on_done,
         )
@@ -1283,18 +1317,21 @@ class AsyncVerifyService:
 
     def _dispatch_sync(
         self,
-        claims: list,
+        chunks: list[tuple],
         t_spawn: int | None = None,
         end_holder: list | None = None,
         wave: "AdoptedWave | None" = None,
         serial: int = 0,
     ) -> list[bool]:
-        """Slot-thread body: evaluate on the forced-device dispatch
-        view, timing the dispatch for the routing EWMA.  An adopted
-        zero-copy wave stages from its arena columns instead of
-        flattening claim tuples (released inside eval_claims_arena).
-        The ``dispatch.wall`` frame hands ``wave=serial`` down to the
-        stage spans inside it (``flatten`` ... ``readback``)."""
+        """Slot-thread body: evaluate the wave's chunks one after
+        another on the forced-device dispatch view, timing the dispatch
+        for the routing EWMA; returns the real claims' verdicts, pads
+        dropped.  An adopted zero-copy wave (one chunk, its claims)
+        stages from its arena columns instead of flattening claim
+        tuples (released inside eval_claims_arena).  The
+        ``dispatch.wall`` frame hands ``wave=serial`` down to the stage
+        spans inside it (``flatten`` ... ``readback``), each chunk's
+        ``dispatch.chunk`` frame its ``chunk`` and ``lanes``."""
         rec = _spans.recorder()
         if rec is not None and t_spawn is not None:
             # dispatch-loop handoff -> slot thread entry (thread
@@ -1309,9 +1346,12 @@ class AsyncVerifyService:
         t0 = time.perf_counter()
         with _spans.span("dispatch.wall", wave=serial):
             if wave is not None:
-                out = eval_claims_arena(target, wave, claims)
+                out = eval_claims_arena(target, wave, chunks[0][0])
             else:
-                out = eval_claims_sync(target, claims)
+                out = []
+                for i, (part, real, lanes) in enumerate(chunks):
+                    with _spans.span("dispatch.chunk", chunk=i, lanes=lanes):
+                        out += eval_claims_sync(target, part)[:real]
         wall = time.perf_counter() - t0
         if rec is not None and end_holder is not None:
             end_holder.append(time.perf_counter_ns())
@@ -1365,6 +1405,9 @@ class AsyncVerifyService:
             arrivals, self._arrivals = self._arrivals, []
             if not batch:
                 return  # drained — the next submit respawns the task
+            # what the cores handed in, in submission order
+            submitted = [c for cs, _ in batch for c in cs]
+            handed_sigs = sum(claim_sig_count(c) for c in submitted)
             # the serial the batch's first submit took (verify_claims)
             serial = self._wave_serial
             rec = _spans.recorder()
@@ -1384,21 +1427,28 @@ class AsyncVerifyService:
             # the same certificate once per node (n x the work this
             # layer exists to avoid).  Each core still applies its OWN
             # stake/quorum/safety rules to the verdicts; no per-node
-            # acceptance state crosses node boundaries.
-            with _spans.span("verify.collect", wave=serial):
-                unique: dict = {}
-                for cs, _ in batch:
-                    for c in cs:
-                        unique.setdefault(c, None)
-                claims = list(unique.keys())
-                n_sigs = sum(claim_sig_count(c) for c in claims)
+            # acceptance state crosses node boundaries.  With the dedup
+            # off (HOTSTUFF_NO_CLAIM_DEDUP) no verdict does either: the
+            # wave's lanes are the submitted signatures, in submission
+            # order, and _verdicts_by_submission reads each core's own.
+            with _spans.span(
+                "verify.collect",
+                wave=serial,
+                sigs=handed_sigs,
+                claims=len(submitted),
+            ):
+                if self._dedup:
+                    claims = list(dict.fromkeys(submitted))
+                    n_sigs = sum(claim_sig_count(c) for c in claims)
+                else:
+                    claims, n_sigs = submitted, handed_sigs
                 agg_in_wave = [c for c in claims if c[0] == "agg"]
                 if agg_in_wave:
                     self.agg_claims += len(agg_in_wave)
                     self.agg_sigs += sum(len(c[3]) for c in agg_in_wave)
                 self.dispatches += 1
                 if self._tel_wave is not None:
-                    self._tel_claims_submitted.inc(sum(len(cs) for cs, _ in batch))
+                    self._tel_claims_submitted.inc(len(submitted))
                     self._tel_claims_unique.inc(len(claims))
                     self._tel_wave.observe(n_sigs)
 
@@ -1449,6 +1499,10 @@ class AsyncVerifyService:
                             wave=serial,
                         )
                     route = self._route_device(n_sigs)
+                # counted where the route is, with device_sigs or
+                # cpu_sigs below, so that no stats line holds a wave's
+                # submitted signatures without its evaluated ones
+                self.submitted_sigs += handed_sigs
                 if self._tel_route is not None:
                     # sharded backends label their device waves "mesh"
                     # so dashboards separate multi-chip dispatches
@@ -1457,19 +1511,25 @@ class AsyncVerifyService:
                         if route == "device"
                         else route
                     ].inc()
-                dispatch_claims = claims
+                # what a slot thread is handed: (claims, how many of
+                # them are real, rows); an adopted arena holds its own
+                # bucket-shaped rows
+                chunks = [
+                    (claims, len(claims), adopted.rows if adopted else n_sigs)
+                ]
                 if (
                     route in ("device", "probe")
                     and self._packing_on
                     and adopted is None
                 ):
                     # fixed-shape wave (ISSUE 6): pad to the bucket so
-                    # the dispatch hits a warm jitted callable.  Probes
-                    # pack too — they measure the shape real waves use.
-                    # Adopted waves skip this: the arena is already
-                    # bucket-shaped with native-padded rows.
+                    # the dispatch hits a warm jitted callable, a wave
+                    # past the largest bucket cut into chunks that do.
+                    # Probes pack too — they measure the shape real
+                    # waves use.  Adopted waves skip this: the arena is
+                    # already bucket-shaped with native-padded rows.
                     with _spans.span("stage.pack", wave=serial, sigs=n_sigs):
-                        dispatch_claims = self._pack_wave(claims, n_sigs)
+                        chunks = self._pack_wave(claims, n_sigs)
                 if route == "probe":
                     # measurement-only device dispatch: results are
                     # discarded (EWMA updates when it lands); the batch
@@ -1478,7 +1538,7 @@ class AsyncVerifyService:
                     self.probe_dispatches += 1
                     with _spans.span("verify.spawn", wave=serial):
                         self._spawn_device(
-                            loop, dispatch_claims, measure_only=True,
+                            loop, chunks, measure_only=True,
                             wave=adopted, serial=serial,
                         )
                     adopted = None  # released by the probe dispatch
@@ -1487,20 +1547,20 @@ class AsyncVerifyService:
                     if self._device_route_label == "mesh":
                         self.mesh_dispatches += 1
                     self.device_sigs += n_sigs
-                    deadline = self._deadline_s()
+                    lanes = sum(rows for _, _, rows in chunks)
+                    self.lanes += lanes
+                    self.chunks += len(chunks)
+                    deadline = self._deadline_s(len(chunks))
                     with _spans.span(
                         "verify.spawn",
                         wave=serial,
                         sigs=n_sigs,
-                        # the rows the device is handed: an adopted
-                        # arena's, else the claims' signatures and one
-                        # for each pad claim
-                        bucket=adopted.rows
-                        if adopted is not None
-                        else n_sigs + len(dispatch_claims) - len(claims),
+                        # the rows the device is handed, pads included
+                        bucket=lanes,
+                        chunks=len(chunks),
                     ):
                         exec_fut, end_holder = self._spawn_device(
-                            loop, dispatch_claims, deadline=deadline,
+                            loop, chunks, deadline=deadline,
                             wave=adopted, serial=serial,
                         )
                     adopted = None  # released by the slot thread
@@ -1513,7 +1573,7 @@ class AsyncVerifyService:
                     # next wave.
                     lander = loop.create_task(
                         self._land_device(
-                            batch, dispatch_claims, exec_fut, end_holder,
+                            batch, claims, exec_fut, end_holder,
                             wave_t0, deadline, serial,
                         ),
                         name="verify-lander",
@@ -1567,16 +1627,20 @@ class AsyncVerifyService:
         cpu = getattr(self.backend, "cpu_backend", self.backend)
         memo: dict = {}
         for cs, fut in batch:
-            todo = [c for c in cs if c not in memo]
+            # with the dedup off a submission is evaluated whole, for
+            # its submitter alone
+            todo = [c for c in cs if c not in memo] if self._dedup else cs
+            results = []
             if todo:
                 t0 = time.perf_counter()
                 results = eval_claims_sync(cpu, todo)
                 if self._tel_host_wall is not None:
                     self._tel_host_wall.add(time.perf_counter() - t0)
-                for c, r in zip(todo, results):
-                    memo[c] = r
+            if self._dedup:
+                memo.update(zip(todo, results))
+                results = [memo[c] for c in cs]
             if not fut.done():
-                fut.set_result([memo[c] for c in cs])
+                fut.set_result(results)
             await asyncio.sleep(0)
 
     async def _serve_cpu_arena(self, batch, claims: list, wave) -> None:
@@ -1589,11 +1653,28 @@ class AsyncVerifyService:
         results = eval_claims_arena(cpu, wave, claims)
         if self._tel_host_wall is not None:
             self._tel_host_wall.add(time.perf_counter() - t0)
-        memo = dict(zip(claims, results))
-        for cs, fut in batch:
+        for fut, verdicts in self._verdicts_by_submission(
+            batch, claims, results
+        ):
             if not fut.done():
-                fut.set_result([memo[c] for c in cs])
+                fut.set_result(verdicts)
             await asyncio.sleep(0)
+
+    def _verdicts_by_submission(self, batch, claims: list, results: list):
+        """``(future, its verdicts)`` for each submission of a wave
+        whose ``claims`` evaluated to ``results``.  With the dedup on, a
+        claim's one verdict serves every core that handed it in; off,
+        ``claims`` is the submissions laid end to end and each core
+        reads its own lanes."""
+        if self._dedup:
+            verdict = dict(zip(claims, results))
+            for cs, fut in batch:
+                yield fut, [verdict[c] for c in cs]
+        else:
+            at = 0
+            for cs, fut in batch:
+                yield fut, results[at : at + len(cs)]
+                at += len(cs)
 
     async def _land_device(
         self,
@@ -1649,10 +1730,11 @@ class AsyncVerifyService:
             return
         fan_t0 = end_holder[0] if (rec is not None and end_holder) else None
         with _spans.span("verify.deliver", wave=serial):
-            verdict = dict(zip(claims, results))
-            for cs, fut in batch:
+            for fut, verdicts in self._verdicts_by_submission(
+                batch, claims, results
+            ):
                 if not fut.done():
-                    fut.set_result([verdict[c] for c in cs])
+                    fut.set_result(verdicts)
         if rec is not None:
             end_ns = time.perf_counter_ns()
             if fan_t0 is not None:
@@ -1676,7 +1758,8 @@ class AsyncVerifyService:
                 "Verify service stats [%s]: dispatches=%d device=%d "
                 "cpu=%d probe=%d device_sigs=%d cpu_sigs=%d "
                 "deadline_misses=%d waits=%d depth=%d mesh=%d "
-                "agg=%d agg_sigs=%d ewma_ms=%.1f zc=%d fb=%d",
+                "agg=%d agg_sigs=%d ewma_ms=%.1f zc=%d fb=%d "
+                "submitted_sigs=%d lanes=%d chunks=%d",
                 self._stats_tag,
                 self.dispatches,
                 self.device_dispatches,
@@ -1693,6 +1776,9 @@ class AsyncVerifyService:
                 (self._device_ewma_s or 0.0) * 1e3,
                 self.zero_copy_waves,
                 self.fallback_waves,
+                self.submitted_sigs,
+                self.lanes,
+                self.chunks,
             )
 
 

@@ -25,10 +25,10 @@ Span taxonomy (leaf stages sum to the wave's end-to-end time)::
     host.pairing     BLS pairing equality on the host
     verdict.fanout   worker completion -> every waiter's future resolved
 
-plus parent spans (``e2e``, ``dispatch.wall``, ``agg.verify``,
-``scheme.route``) that frame the leaves but are excluded from waterfall
-sums — ``benchmark/profile.py`` renders the per-stage waterfall and its
-coverage of the measured end-to-end latency.
+plus parent spans (``e2e``, ``dispatch.wall``, ``dispatch.chunk``,
+``agg.verify``, ``scheme.route``) that frame the leaves but are excluded
+from waterfall sums — ``benchmark/profile.py`` renders the per-stage
+waterfall and its coverage of the measured end-to-end latency.
 
 One call, two sinks (``docs/TELEMETRY.md``, "Verify-pipeline
 profiler").  ``span(name, **ids)`` is all a call site writes.  The ring
@@ -41,11 +41,13 @@ as a ``jax.profiler.TraceAnnotation(name, **ids)``, so it lands in the
 cause: ``wave=<serial>`` (with ``sigs``, ``bucket`` where known) on the
 verify pipeline, ``node=<8 chars>`` and ``round=<r>`` on consensus,
 network, store and ingest.  A frame (``PARENT_STAGES``: the slot
-thread's ``dispatch.wall``) hands its ids down to the spans entered
+thread's ``dispatch.wall``, and inside it one ``dispatch.chunk`` for
+each backend call of the wave) hands its ids down to the spans entered
 inside it on the same thread, so ``flatten`` ... ``readback`` carry
-their wave's serial without the backends knowing it.  The profiler's
-events on one thread must nest and 64 cores share the event-loop
-thread, so **a span on the loop thread never contains an ``await``**
+their wave's serial and their ``chunk`` without the backends knowing
+either.  The profiler's events on one thread must nest and 64 cores
+share the event-loop thread, so **a span on the loop thread never
+contains an ``await``**
 (lint rule ``no-await-in-span``); waits across awaits or threads are
 derived by the trace's reader from the spans on either side, joined by
 ``wave``.

@@ -43,6 +43,7 @@ SPAN_LEAF_STAGES: tuple = (
 SPAN_PARENT_STAGES: tuple = (
     "e2e",
     "dispatch.wall",
+    "dispatch.chunk",  # one backend call of a wave: hands down chunk, lanes
     "agg.verify",
     "scheme.route",
 )

@@ -827,35 +827,3 @@ async def test_dispatch_loop_shuts_down_on_close():
         raise AssertionError("closed dispatch loop accepted a submit")
     except RuntimeError:
         pass
-
-
-def test_no_claim_dedup_gives_private_services(monkeypatch):
-    """HOTSTUFF_NO_CLAIM_DEDUP=1 (the --no-claim-dedup harness knob)
-    must give every core a private device service: no cross-core
-    coalescing registry entry, distinct instances per acquisition."""
-
-    class DeviceBackend(CpuVerifier):
-        async_kind = "nodedup-test"
-        device_ready = False
-
-    backend = DeviceBackend()
-    monkeypatch.setenv("HOTSTUFF_NO_CLAIM_DEDUP", "1")
-
-    async def acquire_two():
-        return (
-            AsyncVerifyService.for_backend(backend),
-            AsyncVerifyService.for_backend(backend),
-        )
-
-    loop = asyncio.new_event_loop()
-    try:
-        s1, s2 = loop.run_until_complete(acquire_two())
-        assert s1 is not s2
-        assert s1.device and s2.device
-        assert not any(
-            s in (s1, s2) for _, s in AsyncVerifyService._registry.values()
-        )
-    finally:
-        s1.close()
-        s2.close()
-        loop.close()
