@@ -27,7 +27,9 @@ RULE = "no-blocking-in-async"
 
 #: method names that block when invoked on a store engine (receiver
 #: name containing "engine"): the sync Engine protocol of store/
-_ENGINE_BLOCKING = {"put", "get", "delete", "keys", "compact"}
+_ENGINE_BLOCKING = {
+    "put", "put_many", "get", "get_many", "delete", "keys", "compact",
+}
 
 #: blocking socket methods (receiver name containing "sock")
 _SOCKET_BLOCKING = {"recv", "recv_into", "accept", "connect", "listen", "sendall"}

@@ -480,34 +480,33 @@ class Core:
         await self.store.write(CONSENSUS_STATE_KEY, data)
 
     async def store_block(self, block: Block) -> None:
-        with _spans.span("core.persist", node=self._node, round=block.round):
-            key, data = block.digest().to_bytes(), block.serialize()
-        await self.store.write(key, data)
-
-        # Maintain the per-round payload index + latest-round key the
-        # proposer's payload buffering feeds on (core.rs:117-148).
+        """The block, then the per-round payload index and latest-round
+        key the proposer's payload buffering feeds on (core.rs:117-148),
+        in that order as ONE store batch: one WAL append, in the log
+        before this returns."""
         latest_raw = await self.store.read(LATEST_ROUND_KEY)
         latest = int.from_bytes(latest_raw, "big") if latest_raw else 0
+        raw = None
         if latest == block.round:
             raw = await self.store.read(round_key(block.round))
-            with _spans.span(
-                "core.persist", node=self._node, round=block.round
-            ):
-                payloads = decode_payload_index(raw) if raw else []
-                known = set(payloads)
-                for p in block.payloads:
-                    if p not in known:
-                        known.add(p)
-                        payloads.append(p)
-        elif latest < block.round:
-            payloads = list(block.payloads)
-        else:
-            self.log.warning("The block round is less than the last round")
-            return
         with _spans.span("core.persist", node=self._node, round=block.round):
-            key, index = round_key(block.round), encode_payload_index(payloads)
-        await self.store.write(key, index)
-        await self.store.write(LATEST_ROUND_KEY, key)
+            records = [(block.digest().to_bytes(), block.serialize())]
+            if latest <= block.round:
+                if latest == block.round:
+                    payloads = decode_payload_index(raw) if raw else []
+                    known = set(payloads)
+                    for p in block.payloads:
+                        if p not in known:
+                            known.add(p)
+                            payloads.append(p)
+                else:
+                    payloads = list(block.payloads)
+                key = round_key(block.round)
+                records.append((key, encode_payload_index(payloads)))
+                records.append((LATEST_ROUND_KEY, key))
+        await self.store.write_many(records)
+        if latest > block.round:
+            self.log.warning("The block round is less than the last round")
 
     # ---- voting and committing ---------------------------------------------
 

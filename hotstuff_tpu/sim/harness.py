@@ -172,7 +172,7 @@ class SimCluster:
         than follow) lands at the tail, exactly what a power cut during
         ``WalEngine.put`` leaves behind.  Recovery's ``_replay`` must
         truncate it.  We APPEND garbage rather than truncate completed
-        records — the engine flushes per put, so completed records are
+        records — the engine flushes per append, so completed records are
         durable by contract, and deleting a persisted vote would
         manufacture a genuine (not injected) double-vote."""
         node = self.nodes[i]

@@ -23,7 +23,8 @@ def open_engine(
 ) -> Engine:
     """Open the best available engine at ``path`` (C++ if built, else the
     pure-Python WAL).  Both speak the same on-disk format.  fsync_mode:
-    0 = flush per put, 1 = fsync per put, 2 = fsync on close."""
+    0 = flush per append, 1 = fsync per append, 2 = fsync on close; an
+    append is one put, one delete or one whole write batch."""
     if prefer_native:
         try:
             from .native import NativeEngine  # noqa: PLC0415
@@ -63,15 +64,31 @@ class Store:
         if self._closed:
             raise RuntimeError("Store is closed")
 
+    def _wake(self, key: bytes, value: bytes) -> None:
+        waiters = self._obligations.pop(key, None)
+        if waiters:
+            for fut in waiters:
+                if not fut.done():
+                    fut.set_result(value)
+
     async def write(self, key: bytes, value: bytes) -> None:
         self._check_open()
         with _spans.span("store.write", node=self.node):
             self.engine.put(key, value)
-            waiters = self._obligations.pop(key, None)
-            if waiters:
-                for fut in waiters:
-                    if not fut.done():
-                        fut.set_result(value)
+            self._wake(key, value)
+
+    async def write_many(self, pairs: list[tuple[bytes, bytes]]) -> None:
+        """Write the records as one batch: one WAL append, in order, in
+        the log (and synced as the engine's ``fsync_mode`` says) before
+        this returns.  A crash inside the append leaves a prefix of
+        whole records, as a crash between as many ``write`` calls
+        would."""
+        self._check_open()
+        with _spans.span("store.write", node=self.node):
+            self.engine.put_many(pairs)
+            if self._obligations:
+                for key, value in pairs:
+                    self._wake(key, value)
 
     async def read(self, key: bytes) -> bytes | None:
         self._check_open()

@@ -12,6 +12,15 @@ this image, so the framework ships its own engines behind one interface:
 
 WAL record format (little-endian): u32 klen | u32 vlen | key | value.
 A record with vlen == 0xFFFFFFFF is a tombstone (delete).
+
+A write batch (``put_many``) is the same records, in the order given,
+joined into one buffer and appended with one write: the file holds
+byte for byte what ``put`` a record would have left, so replay,
+compaction and the other engine read it as they always did.  The batch
+is written, and synced as ``fsync_mode`` says, before ``put_many``
+returns.  A crash inside the write tears the tail: replay keeps the
+whole records before the tear, a prefix of the batch in its order,
+which is a state a crash between two ``put`` calls could leave too.
 """
 
 from __future__ import annotations
@@ -24,10 +33,40 @@ _HDR = struct.Struct("<II")
 TOMBSTONE = 0xFFFFFFFF
 
 
+class WalCounts:
+    """Appends (writes of a WAL) and the records they carried, over
+    every engine of the process since it started: the ``store_appends=``
+    and ``store_records=`` of the ``Host stats:`` line."""
+
+    def __init__(self):
+        self.appends = 0
+        self.records = 0
+
+    def add(self, records: int) -> None:
+        self.appends += 1
+        self.records += records
+
+
+#: the process's one count, as ``hoststats.process()`` is its one probe
+WAL_COUNTS = WalCounts()
+
+
+def pack_records(pairs) -> bytes:
+    """``pairs`` of (key, value) as WAL records, in order, one buffer."""
+    parts = []
+    for key, value in pairs:
+        parts += (_HDR.pack(len(key), len(value)), key, value)
+    return b"".join(parts)
+
+
 class Engine(Protocol):
     def put(self, key: bytes, value: bytes) -> None: ...
 
+    def put_many(self, pairs: list[tuple[bytes, bytes]]) -> None: ...
+
     def get(self, key: bytes) -> bytes | None: ...
+
+    def get_many(self, keys: list[bytes]) -> list[bytes | None]: ...
 
     def delete(self, key: bytes) -> None: ...
 
@@ -39,9 +78,11 @@ class Engine(Protocol):
 class WalEngine:
     """Append-only WAL + in-memory hash index.
 
-    ``fsync_mode``: 0 = flush to the OS page cache per put (survives
+    ``fsync_mode``: 0 = flush to the OS page cache per append (survives
     process death — the default, matching the benchmark configuration),
-    1 = fsync per put (survives OS/power loss), 2 = fsync on close only.
+    1 = fsync per append (survives OS/power loss), 2 = fsync on close
+    only.  An append is one ``put``, one ``delete`` or one ``put_many``
+    batch, whole.
     On open, a log carrying more than ``COMPACT_RATIO`` x its live bytes
     (and at least ``COMPACT_MIN`` bytes) is rewritten to bound disk
     growth across restarts.
@@ -121,15 +162,29 @@ class WalEngine:
         self._wal.write(value)
         self._sync()
         self._index[key] = value
+        WAL_COUNTS.add(1)
+
+    def put_many(self, pairs: list[tuple[bytes, bytes]]) -> None:
+        """The batch as one append: one buffer, one write, one sync."""
+        if not pairs:
+            return
+        self._wal.write(pack_records(pairs))
+        self._sync()
+        self._index.update(pairs)
+        WAL_COUNTS.add(len(pairs))
 
     def get(self, key: bytes) -> bytes | None:
         return self._index.get(key)
+
+    def get_many(self, keys: list[bytes]) -> list[bytes | None]:
+        return [self._index.get(key) for key in keys]
 
     def delete(self, key: bytes) -> None:
         self._wal.write(_HDR.pack(len(key), TOMBSTONE))
         self._wal.write(key)
         self._sync()
         self._index.pop(key, None)
+        WAL_COUNTS.add(1)
 
     def keys(self) -> Iterator[bytes]:
         return iter(list(self._index.keys()))

@@ -6,18 +6,25 @@ other's files.  The shared library is built with ``make -C native`` (or
 automatically on first import when a compiler is available); set
 ``HOTSTUFF_STORE_NATIVE=0`` to force the Python engine.
 
-Durability: ``fsync_mode`` 0 = flush per put (process-crash safe),
-1 = fdatasync per put (power-loss safe), 2 = fdatasync on close.
+Durability: ``fsync_mode`` 0 = flush per append (process-crash safe),
+1 = fdatasync per append (power-loss safe), 2 = fdatasync on close.  An
+append is one ``put``, one ``delete`` or one whole ``put_many`` batch:
+the batch crosses into the library once, packed as the WAL records it
+becomes, and is written with one ``write``.
 """
 
 from __future__ import annotations
 
 import ctypes
 import os
+import struct
 import subprocess
 from typing import Iterator
 
+from .engine import TOMBSTONE, WAL_COUNTS, pack_records
+
 _LIB_NAME = "libhs_store.so"
+_U32 = struct.Struct("<I")
 
 
 def _native_dir() -> str:
@@ -52,6 +59,21 @@ def _load_lib() -> ctypes.CDLL:
         ctypes.c_uint32,
         ctypes.c_char_p,
         ctypes.c_uint32,
+    ]
+    lib.hs_put_many.restype = ctypes.c_int
+    lib.hs_put_many.argtypes = [
+        ctypes.c_void_p,
+        ctypes.c_char_p,
+        ctypes.c_uint64,
+    ]
+    lib.hs_get_many.restype = ctypes.c_int
+    lib.hs_get_many.argtypes = [
+        ctypes.c_void_p,
+        ctypes.c_char_p,
+        ctypes.c_uint32,
+        ctypes.c_char_p,
+        ctypes.POINTER(ctypes.POINTER(ctypes.c_uint8)),
+        ctypes.POINTER(ctypes.c_uint64),
     ]
     lib.hs_get.restype = ctypes.c_int
     lib.hs_get.argtypes = [
@@ -105,6 +127,16 @@ class NativeEngine:
     def put(self, key: bytes, value: bytes) -> None:
         if self._lib.hs_put(self._h, key, len(key), value, len(value)) != 0:
             raise OSError("hs_put failed")
+        WAL_COUNTS.add(1)
+
+    def put_many(self, pairs: list[tuple[bytes, bytes]]) -> None:
+        """The batch as one append, in one crossing."""
+        if not pairs:
+            return
+        buf = pack_records(pairs)
+        if self._lib.hs_put_many(self._h, buf, len(buf)) != 0:
+            raise OSError("hs_put_many failed")
+        WAL_COUNTS.add(len(pairs))
 
     def get(self, key: bytes) -> bytes | None:
         out = ctypes.POINTER(ctypes.c_uint8)()
@@ -121,9 +153,40 @@ class NativeEngine:
         finally:
             self._lib.hs_free(out)
 
+    def get_many(self, keys: list[bytes]) -> list[bytes | None]:
+        """A value or None a key, in one crossing."""
+        count = len(keys)
+        if not count:
+            return []
+        out = ctypes.POINTER(ctypes.c_uint8)()
+        outlen = ctypes.c_uint64()
+        if self._lib.hs_get_many(
+            self._h,
+            struct.pack("<%dI" % count, *map(len, keys)),
+            count,
+            b"".join(keys),
+            ctypes.byref(out),
+            ctypes.byref(outlen),
+        ):
+            raise OSError("hs_get_many failed")
+        try:
+            blob = ctypes.string_at(out, outlen.value)
+        finally:
+            self._lib.hs_free(out)
+        values: list[bytes | None] = []
+        off = 4 * count
+        for vlen in struct.unpack_from("<%dI" % count, blob):
+            if vlen == TOMBSTONE:
+                values.append(None)
+            else:
+                values.append(blob[off : off + vlen])
+                off += vlen
+        return values
+
     def delete(self, key: bytes) -> None:
         if self._lib.hs_delete(self._h, key, len(key)) != 0:
             raise OSError("hs_delete failed")
+        WAL_COUNTS.add(1)
 
     def keys(self) -> Iterator[bytes]:
         out = ctypes.POINTER(ctypes.c_uint8)()
@@ -134,11 +197,11 @@ class NativeEngine:
             blob = ctypes.string_at(out, outlen.value)
         finally:
             self._lib.hs_free(out)
-        (count,) = __import__("struct").unpack_from("<I", blob, 0)
+        (count,) = _U32.unpack_from(blob, 0)
         off = 4
         result = []
         for _ in range(count):
-            (klen,) = __import__("struct").unpack_from("<I", blob, off)
+            (klen,) = _U32.unpack_from(blob, off)
             off += 4
             result.append(blob[off : off + klen])
             off += klen

@@ -208,8 +208,8 @@ class StateMachine:
         )
         self.reported_root = raw[_META.size :]
 
-    def _persist_meta(self) -> None:
-        self.store.engine.put(
+    def _meta_record(self) -> tuple[bytes, bytes]:
+        return (
             META_KEY,
             _META.pack(self.version, self.last_round, self.root,
                        self.applied_payloads) + self.reported_root,
@@ -233,38 +233,46 @@ class StateMachine:
         engine = self.store.engine
         real_digest = block.digest()
         round_ = block.round
-        for seq, digest in enumerate(block.payloads):
-            raw = digest.to_bytes()
-            engine.put(LEDGER_PREFIX + raw, _LEDGER_VAL.pack(round_, seq))
-            self.applied_payloads += 1
-            body = engine.get(b"p" + raw)
+        raws = [digest.to_bytes() for digest in block.payloads]
+        # bodies live under ``p``, which an apply never writes: one read
+        # of them all, before the batch, sees what per-payload reads saw
+        bodies = engine.get_many([b"p" + raw for raw in raws])
+        # the block's records in the order they are to reach the WAL:
+        # a payload's ledger entry, then its typed operations' entries
+        batch = []
+        for seq, (raw, body) in enumerate(zip(raws, bodies)):
+            batch.append((LEDGER_PREFIX + raw, _LEDGER_VAL.pack(round_, seq)))
             if body is not None:
                 ops = decode_ops(body)
                 if ops:
-                    self._apply_ops(round_, ops)
+                    self._apply_ops(round_, ops, batch)
+        self.applied_payloads += len(raws)
         self.version += 1
         self.applied_blocks += 1
         self.last_round = round_
-        self.root = fold_root(self.root, round_, real_digest.to_bytes(),
-                              block.payloads)
+        self.root = fold_root(self.root, round_, real_digest.to_bytes(), raws)
         if reported_digest is None or reported_digest == real_digest:
             reported = real_digest.to_bytes()
         else:
             reported = reported_digest.to_bytes()
         self.reported_root = fold_root(self.reported_root, round_,
-                                       reported, block.payloads)
-        self._persist_meta()
+                                       reported, raws)
+        # one append a committed block, the meta cursor its last record:
+        # a tail torn inside it replays without the cursor, so the node
+        # reopens at the previous round and applies the block again,
+        # over entries that the second apply rewrites to the same bytes
+        batch.append(self._meta_record())
+        engine.put_many(batch)
         return self.reported_root
 
-    def _apply_ops(self, round_: int, ops) -> None:
-        engine = self.store.engine
+    def _apply_ops(self, round_: int, ops, batch: list) -> None:
         for op in ops:
             if op[0] == "put":
                 _, key, value = op
-                engine.put(USER_PREFIX + key,
-                           _USER_HDR.pack(round_, 1) + value)
+                batch.append((USER_PREFIX + key,
+                              _USER_HDR.pack(round_, 1) + value))
             else:
-                engine.put(USER_PREFIX + op[1], _USER_HDR.pack(round_, 0))
+                batch.append((USER_PREFIX + op[1], _USER_HDR.pack(round_, 0)))
             self.typed_ops += 1
 
     # ---- read path ------------------------------------------------------
@@ -328,19 +336,20 @@ class StateMachine:
         adopted, not recomputed — a chained root summarizes history the
         snapshot deliberately omits; trust comes from the QC anchor and
         manifest quorum the sync client verified before calling this."""
-        engine = self.store.engine
+        batch = []
         for key, value in entries:
             if not key.startswith(STATE_PREFIX) or key == META_KEY:
                 raise StateError(f"snapshot entry outside state namespace: "
                                  f"{key[:16]!r}")
-            engine.put(key, value)
+            batch.append((key, value))
         self.version = manifest.version
         self.root = manifest.root
         self.reported_root = manifest.root
         self.last_round = manifest.last_round
         self.applied_payloads = manifest.applied_payloads
         self.synced_from_snapshot = True
-        self._persist_meta()
+        batch.append(self._meta_record())
+        self.store.engine.put_many(batch)
 
     # ---- telemetry ------------------------------------------------------
 

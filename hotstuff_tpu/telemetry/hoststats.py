@@ -8,6 +8,7 @@ first over its window)::
 
     Host stats: elapsed_s=35.004 cpu_user_s=30.21 cpu_sys_s=3.02
       lag_samples=640 lag_mean_ms=1.25 lag_max_ms=41.7 gc2=1 gc2_s=0.038
+      store_appends=5210 store_records=38877
 
 - ``cpu_user_s`` / ``cpu_sys_s``: the process's CPU seconds, all threads
   (``os.times``).  Over a window's wall time they say whether a long
@@ -20,6 +21,10 @@ first over its window)::
 - ``gc2`` / ``gc2_s``: generation-2 collections and the seconds they
   took (``gc.callbacks``): the collector's pauses, told apart from the
   machine's.
+- ``store_appends`` / ``store_records``: writes of a WAL and the records
+  they carried, every store engine of the process
+  (``store/engine.py`` ``WAL_COUNTS``): records over appends is how far
+  the write batch engages.
 
 A pause in which this process and another both stand still shows as one
 ``lag_max_ms`` the size of the pause with neither ``gc2_s`` nor CPU
@@ -34,6 +39,8 @@ import gc
 import logging
 import os
 import time
+
+from ..store.engine import WAL_COUNTS
 
 log = logging.getLogger(__name__)
 
@@ -89,7 +96,9 @@ class HostStats:
             f"cpu_user_s={cpu.user:.3f} cpu_sys_s={cpu.system:.3f} "
             f"lag_samples={self.lag_samples} lag_mean_ms={mean * 1e3:.3f} "
             f"lag_max_ms={lag_max * 1e3:.3f} "
-            f"gc2={self.gc2} gc2_s={self.gc2_s:.4f}"
+            f"gc2={self.gc2} gc2_s={self.gc2_s:.4f} "
+            f"store_appends={WAL_COUNTS.appends} "
+            f"store_records={WAL_COUNTS.records}"
         )
 
     async def run(self, logger=None) -> None:

@@ -43,6 +43,9 @@ extern "C" {
 void* hs_open(const char* path, int fsync_mode);
 int hs_put(void* h, const uint8_t* k, uint32_t klen, const uint8_t* v,
            uint32_t vlen);
+int hs_put_many(void* h, const uint8_t* buf, uint64_t n);
+int hs_get_many(void* h, const uint32_t* klens, uint32_t count,
+                const uint8_t* keys, uint8_t** out, uint64_t* outlen);
 int hs_get(void* h, const uint8_t* k, uint32_t klen, uint8_t** out,
            uint32_t* outlen);
 int hs_delete(void* h, const uint8_t* k, uint32_t klen);
@@ -99,6 +102,51 @@ std::string key_of(int t, int i) {
   return buf;
 }
 
+void pack_u32(std::string* to, uint32_t v) {
+  to->append(reinterpret_cast<const char*>(&v), 4);
+}
+
+// A write batch of `n` records beside the per-record puts, keys of the
+// same space (the last of them twice), read back with one hs_get_many.
+bool batch_round_trip(void* h, int t, int i, int n) {
+  std::string batch, keys, last;
+  std::vector<uint32_t> klens;
+  for (int j = 0; j <= n; j++) {
+    std::string k = key_of(t, i + (j < n ? j : n - 1));
+    last.assign(1 + (i + j) % 80, char('a' + t));
+    pack_u32(&batch, k.size());
+    pack_u32(&batch, last.size());
+    batch += k + last;
+    if (j < n) {
+      klens.push_back(k.size());
+      keys += k;
+    }
+  }
+  if (hs_put_many(h, (const uint8_t*)batch.data(), batch.size()) != 0)
+    return false;
+  // a batch cut short is refused whole, nothing written
+  if (hs_put_many(h, (const uint8_t*)batch.data(), batch.size() - 1) == 0)
+    return false;
+  uint8_t* out = nullptr;
+  uint64_t outlen = 0;
+  if (hs_get_many(h, klens.data(), n, (const uint8_t*)keys.data(), &out,
+                  &outlen) != 0)
+    return false;
+  // every key is there, and the key given twice holds its last value
+  uint64_t off = 4ull * n;
+  bool ok = outlen >= off;
+  for (int j = 0; j < n && ok; j++) {
+    uint32_t vlen;
+    std::memcpy(&vlen, out + 4 * j, 4);
+    ok = vlen != 0xFFFFFFFFu && off + vlen <= outlen;
+    if (ok && j == n - 1)
+      ok = vlen == last.size() && std::memcmp(out + off, last.data(), vlen) == 0;
+    off += vlen;
+  }
+  hs_free(out);
+  return ok && off == outlen;
+}
+
 void store_worker(const std::string& dir, int t) {
   std::string path = dir + "/own_" + std::to_string(t) + ".wal";
   void* h = hs_open(path.c_str(), 0);
@@ -119,6 +167,8 @@ void store_worker(const std::string& dir, int t) {
     hs_free(out);
     if (i % 11 == 3)
       hs_delete(h, (const uint8_t*)k.data(), k.size());
+    if (i % 5 == 2 && !batch_round_trip(h, t, i, 1 + i % 23))
+      return fail("hs_put_many round-trip");
     if (i % 97 == 50) hs_compact(h);
     if (i % 151 == 100) {
       // close/reopen exercises WAL replay + compaction-on-open
@@ -155,6 +205,8 @@ void store_stress(const std::string& dir) {
           return fail("hs_put(shared)");
         if (i % 13 == 7)
           hs_delete(h, (const uint8_t*)k.data(), k.size());
+        if (i % 9 == 4 && !batch_round_trip(h, t, i, 1 + i % 23))
+          return fail("hs_put_many(shared)");
       }
     });
   }

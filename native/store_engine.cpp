@@ -7,7 +7,7 @@
 // durability requirement is crash-recovery replay (SURVEY.md §5 "the
 // store IS the checkpoint").  So the engine is an append-only WAL with
 // an in-memory open-addressing index — O(1) gets with zero read
-// amplification, one sequential write per put.
+// amplification, one sequential write per put or per write batch.
 //
 // WAL record format (little-endian), shared bit-for-bit with the Python
 // WalEngine (hotstuff_tpu/store/engine.py) so either implementation can
@@ -15,9 +15,15 @@
 //   u32 klen | u32 vlen | key bytes | value bytes
 //   vlen == 0xFFFFFFFF marks a tombstone (delete; no value bytes).
 //
-// Durability modes (hs_open's fsync_mode):
-//   0 = flush to the OS page cache per put (survives process death)
-//   1 = fdatasync per put               (survives OS/power loss)
+// A write batch (hs_put_many) takes its records already packed in that
+// format and appends the buffer as it is with one write: the log holds
+// byte for byte what hs_put a record would have left.  A torn tail
+// inside a batch replays to the whole records before the tear.
+//
+// Durability modes (hs_open's fsync_mode), an append being one hs_put,
+// hs_delete or hs_put_many:
+//   0 = flush to the OS page cache per append (survives process death)
+//   1 = fdatasync per append              (survives OS/power loss)
 //   2 = fdatasync on close only
 //
 // Compaction: on open, after replay, if the log carries more than
@@ -27,8 +33,9 @@
 // thread racing the single writer.
 //
 // C ABI (consumed via ctypes from hotstuff_tpu/store/native.py):
-//   hs_open / hs_put / hs_get / hs_delete / hs_keys_blob / hs_count /
-//   hs_compact / hs_wal_bytes / hs_free / hs_close
+//   hs_open / hs_put / hs_put_many / hs_get / hs_get_many / hs_delete /
+//   hs_keys_blob / hs_count / hs_compact / hs_wal_bytes / hs_free /
+//   hs_close
 
 #include <cerrno>
 #include <cstdint>
@@ -77,6 +84,16 @@ bool write_all(int fd, const uint8_t* p, size_t n) {
   return true;
 }
 
+// One append: the bytes in one write, then the sync mode 1 asks for.
+bool append_bytes(Engine* e, const uint8_t* p, size_t n) {
+  if (!write_all(e->fd, p, n)) return false;
+  e->wal_bytes += n;
+  if (e->fsync_mode == 1) {
+    if (::fdatasync(e->fd) != 0) return false;
+  }
+  return true;
+}
+
 bool append_record(Engine* e, const uint8_t* k, uint32_t klen,
                    const uint8_t* v, uint32_t vlen, bool tombstone) {
   uint8_t hdr[8];
@@ -88,12 +105,18 @@ bool append_record(Engine* e, const uint8_t* k, uint32_t klen,
   buf.insert(buf.end(), hdr, hdr + 8);
   buf.insert(buf.end(), k, k + klen);
   if (!tombstone && vlen > 0) buf.insert(buf.end(), v, v + vlen);
-  if (!write_all(e->fd, buf.data(), buf.size())) return false;
-  e->wal_bytes += buf.size();
-  if (e->fsync_mode == 1) {
-    if (::fdatasync(e->fd) != 0) return false;
+  return append_bytes(e, buf.data(), buf.size());
+}
+
+void index_put(Engine* e, const uint8_t* k, uint32_t klen, const uint8_t* v,
+               uint32_t vlen) {
+  std::string key(reinterpret_cast<const char*>(k), klen);
+  auto it = e->index.find(key);
+  if (it != e->index.end()) {
+    e->live_bytes -= record_size(it->first.size(), it->second.size());
   }
-  return true;
+  e->live_bytes += record_size(klen, vlen);
+  e->index[std::move(key)].assign(reinterpret_cast<const char*>(v), vlen);
 }
 
 // Replay the WAL into the index; truncate any torn tail.  Returns false
@@ -222,13 +245,37 @@ int hs_put(void* h, const uint8_t* k, uint32_t klen, const uint8_t* v,
   auto* e = static_cast<Engine*>(h);
   if (vlen == kTombstone) return -1;  // reserved
   if (!append_record(e, k, klen, v, vlen, false)) return -1;
-  std::string key(reinterpret_cast<const char*>(k), klen);
-  auto it = e->index.find(key);
-  if (it != e->index.end()) {
-    e->live_bytes -= record_size(it->first.size(), it->second.size());
+  index_put(e, k, klen, v, vlen);
+  return 0;
+}
+
+// A write batch: `buf` holds `n` bytes of whole put records in the WAL's
+// own format.  Checked first (a record that overruns the buffer or is a
+// tombstone refuses the batch, nothing written), appended with one
+// write, then indexed in order, so a key given twice keeps its last
+// value.
+int hs_put_many(void* h, const uint8_t* buf, uint64_t n) {
+  auto* e = static_cast<Engine*>(h);
+  uint64_t off = 0;
+  while (off < n) {
+    if (n - off < 8) return -1;
+    uint32_t klen, vlen;
+    std::memcpy(&klen, buf + off, 4);
+    std::memcpy(&vlen, buf + off + 4, 4);
+    if (vlen == kTombstone) return -1;
+    uint64_t body = static_cast<uint64_t>(klen) + vlen;
+    if (n - off - 8 < body) return -1;
+    off += 8 + body;
   }
-  e->live_bytes += record_size(klen, vlen);
-  e->index[std::move(key)].assign(reinterpret_cast<const char*>(v), vlen);
+  if (n == 0) return 0;
+  if (!append_bytes(e, buf, n)) return -1;
+  for (off = 0; off < n;) {
+    uint32_t klen, vlen;
+    std::memcpy(&klen, buf + off, 4);
+    std::memcpy(&vlen, buf + off + 4, 4);
+    index_put(e, buf + off + 8, klen, buf + off + 8 + klen, vlen);
+    off += 8 + static_cast<uint64_t>(klen) + vlen;
+  }
   return 0;
 }
 
@@ -241,6 +288,41 @@ int hs_get(void* h, const uint8_t* k, uint32_t klen, uint8_t** out,
   *out = static_cast<uint8_t*>(std::malloc(it->second.size() ? it->second.size() : 1));
   if (*out == nullptr) return -2;
   std::memcpy(*out, it->second.data(), it->second.size());
+  return 0;
+}
+
+// Several gets in one crossing.  The `count` keys lie end to end in
+// `keys`, their lengths in `klens`; the answer is one blob of `count`
+// u32 value lengths (0xFFFFFFFF for a key not there), then the values
+// that are there, end to end, in the keys' order.
+int hs_get_many(void* h, const uint32_t* klens, uint32_t count,
+                const uint8_t* keys, uint8_t** out, uint64_t* outlen) {
+  auto* e = static_cast<Engine*>(h);
+  std::vector<const std::string*> found(count, nullptr);
+  uint64_t total = 4ull * count;
+  for (uint32_t i = 0; i < count; i++) {
+    auto it = e->index.find(
+        std::string(reinterpret_cast<const char*>(keys), klens[i]));
+    keys += klens[i];
+    if (it != e->index.end()) {
+      found[i] = &it->second;
+      total += it->second.size();
+    }
+  }
+  auto* buf = static_cast<uint8_t*>(std::malloc(total ? total : 1));
+  if (buf == nullptr) return -2;
+  uint8_t* at = buf + 4ull * count;
+  for (uint32_t i = 0; i < count; i++) {
+    uint32_t vlen =
+        found[i] ? static_cast<uint32_t>(found[i]->size()) : kTombstone;
+    std::memcpy(buf + 4ull * i, &vlen, 4);
+    if (found[i]) {
+      std::memcpy(at, found[i]->data(), found[i]->size());
+      at += found[i]->size();
+    }
+  }
+  *out = buf;
+  *outlen = total;
   return 0;
 }
 

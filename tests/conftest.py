@@ -18,6 +18,7 @@ if "xla_force_host_platform_device_count" not in flags:
     ).strip()
 
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", 8)
@@ -31,3 +32,18 @@ def pytest_configure(config):
         "slow: full-scenario committee runs excluded from the tier-1 "
         "sweep (-m 'not slow')",
     )
+
+
+@pytest.fixture(params=["wal", "native"])
+def engine_cls(request):
+    """Each store engine's class in turn; the native one is skipped
+    where its library cannot be built."""
+    if request.param == "wal":
+        from hotstuff_tpu.store.engine import WalEngine
+
+        return WalEngine
+    try:
+        from hotstuff_tpu.store.native import NativeEngine
+    except (ImportError, OSError):  # no compiler in this environment
+        pytest.skip("native lib not built")
+    return NativeEngine

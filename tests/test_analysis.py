@@ -14,6 +14,8 @@ import subprocess
 import sys
 import textwrap
 
+import pytest
+
 from hotstuff_tpu.analysis import (
     Finding,
     load_allowlist,
@@ -72,6 +74,24 @@ def test_blocking_rule_catches_sync_calls_in_async_def(tmp_path):
         "sock.recv",
     }
     assert all(f.rule == "no-blocking-in-async" for f in findings)
+
+
+@pytest.mark.parametrize("method", ["put_many", "get_many"])
+def test_blocking_rule_knows_the_engines_batch_calls(tmp_path, method):
+    """A write batch or a batched read on an engine blocks like a put:
+    an actor goes through the Store (``write_many``), not the engine."""
+    root = _tree(
+        tmp_path,
+        {
+            "hotstuff_tpu/consensus/actor.py": f"""\
+                async def persist(self, records):
+                    self.store.engine.{method}(records)
+                    await self.store.write_many(records)
+                """,
+        },
+    )
+    findings = run_rules([NoBlockingInAsync()], root)
+    assert _codes(findings) == {f"self.store.engine.{method}"}
 
 
 def test_blocking_rule_ignores_sync_defs_and_nested_functions(tmp_path):

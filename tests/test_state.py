@@ -10,6 +10,11 @@ relies on.
 
 from __future__ import annotations
 
+import collections
+import os
+import random
+import struct
+
 import pytest
 
 from hotstuff_tpu.consensus.errors import SerializationError
@@ -33,6 +38,7 @@ from hotstuff_tpu.consensus.wire import (
     encode_state_request,
     encode_state_value,
 )
+from hotstuff_tpu.consensus.messages import QC, Block
 from hotstuff_tpu.crypto import Digest
 from hotstuff_tpu.store import Store
 from hotstuff_tpu.store.state import (
@@ -171,6 +177,249 @@ def test_meta_persists_across_reopen(tmp_path):
     assert sm2.anchor() == anchor
     assert sm2.reported_root == reported
     assert sm2.applied_payloads == sm.applied_payloads
+
+
+# ---- one append a block: the batched apply against a plain reference ------
+
+
+def _random_blocks(rng, store, count: int):
+    """Blocks of 0 to 30 payloads.  Some payloads' bodies are in the
+    store, as the ingest plane leaves them at a payload's home: typed
+    (puts and deletes over a small key space, so one block writes a key
+    several times, a put and a delete of one key in one body included),
+    opaque, or malformed."""
+    (author, _), = keys()[:1]
+    blocks, round_ = [], 0
+    user_keys = [b"k%d" % i for i in range(6)]
+    for _ in range(count):
+        round_ += rng.randrange(1, 4)
+        payloads = [
+            Digest(bytes(rng.randrange(256) for _ in range(32)))
+            for _ in range(rng.randrange(0, 31))
+        ]
+        if payloads and rng.random() < 0.3:
+            payloads.append(payloads[0])  # one digest twice in a block
+        for digest in payloads:
+            draw = rng.random()
+            if draw < 0.25:
+                ops = []
+                for _ in range(rng.randrange(0, 5)):
+                    key = rng.choice(user_keys)
+                    if rng.random() < 0.6:
+                        ops.append(("put", key, b"v%d" % rng.randrange(1000)))
+                    else:
+                        ops.append(("del", key))
+                if rng.random() < 0.5:
+                    ops += [("put", b"both", b"x"), ("del", b"both")]
+                body = _typed_body(ops)
+            elif draw < 0.35:
+                body = b"\x00" * OP_BODY_OFFSET + b"opaque-body"
+            elif draw < 0.40:
+                body = _typed_body([("put", b"cut", b"short")])[:-3]
+            else:
+                continue
+            store.engine.put(b"p" + digest.to_bytes(), body)
+        blocks.append(Block(qc=QC.genesis(), tc=None, author=author,
+                            round=round_, payloads=tuple(payloads)))
+    return blocks
+
+
+class _Reference:
+    """The execution layer as it was written record by record: a dict
+    for the engine, one put a ledger entry, a typed operation and the
+    meta cursor, in that order."""
+
+    def __init__(self, bodies: dict):
+        self.kv = dict(bodies)
+        self.version = self.last_round = self.applied = 0
+        self.root = self.reported = GENESIS_ROOT
+
+    def apply(self, block, reported_digest=None):
+        if block.round <= self.last_round:
+            return None
+        for seq, digest in enumerate(block.payloads):
+            raw = digest.to_bytes()
+            self.kv[b"s/l" + raw] = struct.pack("<QI", block.round, seq)
+            self.applied += 1
+            body = self.kv.get(b"p" + raw)
+            for op in (decode_ops(body) or ()) if body is not None else ():
+                alive = op[0] == "put"
+                self.kv[b"s/u" + op[1]] = struct.pack(
+                    "<QB", block.round, alive
+                ) + (op[2] if alive else b"")
+        self.version += 1
+        self.last_round = block.round
+        real = block.digest().to_bytes()
+        self.root = fold_root(self.root, block.round, real, block.payloads)
+        shadow = real if reported_digest is None else reported_digest.to_bytes()
+        self.reported = fold_root(
+            self.reported, block.round, shadow, block.payloads
+        )
+        self.kv[b"s/meta"] = struct.pack(
+            "<QQ32sQ", self.version, self.last_round, self.root, self.applied
+        ) + self.reported
+        return self.reported
+
+
+def _state_of(engine) -> dict:
+    return {k: engine.get(k) for k in engine.keys()}
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3, 4])
+def test_batched_apply_equals_the_per_record_reference(
+    tmp_path, engine_cls, seed
+):
+    rng = random.Random(seed)
+    path = str(tmp_path / "db")
+    store = Store(path, engine=engine_cls(path))
+    blocks = _random_blocks(rng, store, 25)
+    ref = _Reference(_state_of(store.engine))
+    sm = StateMachine(store)
+    for i, block in enumerate(blocks):
+        shadow = Digest.random() if i % 7 == 3 else None
+        assert sm.apply_block(block, shadow) == ref.apply(block, shadow)
+        if i % 5 == 0:  # the recovery overlap: an applied round again
+            assert sm.apply_block(block) is None and ref.apply(block) is None
+        assert (sm.root, sm.reported_root) == (ref.root, ref.reported)
+    assert _state_of(store.engine) == ref.kv  # meta, ledger, user-KV, bodies
+    assert (sm.version, sm.last_round, sm.applied_payloads) == (
+        ref.version, ref.last_round, ref.applied
+    )
+    assert sm.reported_root != sm.root  # a shadow digest was reported
+    for key in [b"k%d" % i for i in range(6)] + [b"both", b"cut"]:
+        raw = ref.kv.get(b"s/u" + key)
+        alive = raw is not None and raw[8] == 1
+        expect = (int.from_bytes(raw[:8], "little"), raw[9:]) if alive else None
+        assert sm.read_user(key) == expect
+    assert sm.read_user(b"both") is None  # put then deleted in one body
+    snapshot = [e for i in range(sm.manifest().chunk_count) for e in sm.chunk(i)]
+    assert snapshot == sorted(
+        (k, v) for k, v in ref.kv.items()
+        if k.startswith(b"s/") and k != b"s/meta"
+    )
+    store.close()
+    # and the log holds it all: a reopened node is where this one was
+    reopened = StateMachine(Store(path, engine=engine_cls(path)))
+    assert reopened.anchor() == sm.anchor()
+    assert reopened.reported_root == sm.reported_root
+    assert _state_of(reopened.store.engine) == ref.kv
+    reopened.store.close()
+
+
+class _CountingEngine:
+    """Counts the calls that cross into an engine."""
+
+    def __init__(self, inner):
+        self.inner = inner
+        self.calls = collections.Counter()
+
+    def __getattr__(self, name):
+        attr = getattr(self.inner, name)
+        if not callable(attr):
+            return attr
+
+        def counted(*args):
+            self.calls[name] += 1
+            return attr(*args)
+
+        return counted
+
+
+def test_apply_is_exactly_one_append_and_two_crossings_a_block(
+    tmp_path, engine_cls
+):
+    from hotstuff_tpu.store.engine import WAL_COUNTS
+
+    path = str(tmp_path / "db")
+    engine = _CountingEngine(engine_cls(path))
+    store = Store(path, engine=engine)
+    blocks = _random_blocks(random.Random(9), store, 12)
+    sm = StateMachine(store)
+    engine.calls.clear()
+    appends, records = WAL_COUNTS.appends, WAL_COUNTS.records
+    for block in blocks:
+        sm.apply_block(block)
+    assert engine.calls == {"put_many": len(blocks), "get_many": len(blocks)}
+    assert WAL_COUNTS.appends - appends == len(blocks)
+    assert WAL_COUNTS.records - records == (
+        sm.applied_payloads + sm.typed_ops + len(blocks)
+    )
+    store.close()
+
+
+def test_wal_torn_inside_an_apply_reopens_at_the_previous_round(
+    tmp_path, engine_cls
+):
+    """Whatever prefix of a block's one append a crash leaves, the node
+    reopens at the round before it (the meta cursor is the batch's last
+    record) and applying the block again lands on the same root and
+    the same state."""
+    rng = random.Random(5)
+    path = str(tmp_path / "db")
+    store = Store(path, engine=engine_cls(path))
+    blocks = [b for b in _random_blocks(rng, store, 8) if b.payloads]
+    *before, torn = blocks
+    sm = StateMachine(store)
+    for block in before:
+        sm.apply_block(block)
+    anchor, reported = sm.anchor(), sm.reported_root
+    store.engine.close()
+    wal = os.path.join(path, "wal.log")
+    with open(wal, "rb") as f:
+        head = f.read()
+    store = Store(path, engine=engine_cls(path))
+    sm = StateMachine(store)
+    final_reported = sm.apply_block(torn)
+    final_anchor, final_state = sm.anchor(), _state_of(store.engine)
+    store.engine.close()
+    with open(wal, "rb") as f:
+        full = f.read()
+    assert full[: len(head)] == head and len(full) > len(head) + 100
+    cuts = sorted(
+        set(range(len(head), len(full), 7))
+        | set(range(len(head), len(head) + 60))
+        | set(range(len(full) - 130, len(full)))
+    )
+    for cut in cuts:
+        again = str(tmp_path / ("db-%d" % cut))
+        os.makedirs(again)
+        with open(os.path.join(again, "wal.log"), "wb") as f:
+            f.write(full[:cut])
+        store = Store(again, engine=engine_cls(again))
+        sm = StateMachine(store)
+        assert sm.anchor() == anchor and sm.reported_root == reported, cut
+        assert sm.apply_block(before[-1]) is None
+        assert sm.apply_block(torn) == final_reported
+        assert sm.anchor() == final_anchor
+        assert _state_of(store.engine) == final_state, cut
+        store.close()
+    # the whole append in the log: the node reopens past the block
+    store = Store(path, engine=engine_cls(path))
+    sm = StateMachine(store)
+    assert sm.anchor() == final_anchor and sm.apply_block(torn) is None
+    store.close()
+
+
+def test_adopt_writes_the_snapshot_and_its_cursor_as_one_append(tmp_path):
+    from hotstuff_tpu.store.engine import WAL_COUNTS
+
+    src = StateMachine(_store(tmp_path, "src"))
+    for block in chain(5):
+        src.apply_block(block)
+    entries = [e for i in range(src.manifest().chunk_count) for e in src.chunk(i)]
+    dst_store = _store(tmp_path, "dst")
+    dst = StateMachine(dst_store)
+    appends = WAL_COUNTS.appends
+    dst.adopt(src.manifest(), entries)
+    assert WAL_COUNTS.appends - appends == 1
+    # a poisoned snapshot writes nothing at all
+    with pytest.raises(StateError):
+        dst.adopt(src.manifest(), entries + [(b"p" + b"\x00" * 32, b"body")])
+    assert WAL_COUNTS.appends - appends == 1
+    dst_store.engine.close()
+    reopened = StateMachine(_store(tmp_path, "dst"))
+    assert reopened.anchor() == src.anchor()
+    assert reopened._entries() == entries
 
 
 # ---- typed ops and the read path -------------------------------------------

@@ -5,6 +5,8 @@ the reference lacks (SURVEY.md §4 gaps)."""
 import asyncio
 import os
 
+import pytest
+
 from hotstuff_tpu.store import Store, WalEngine
 
 
@@ -178,3 +180,140 @@ def test_overwrite_uses_latest(tmp_path):
     eng2 = WalEngine(path)
     assert eng2.get(b"k") == b"new"
     eng2.close()
+
+
+# ---- the write batch: one WAL append for several records -------------------
+
+
+def _wal_bytes(path: str) -> bytes:
+    with open(os.path.join(path, "wal.log"), "rb") as f:
+        return f.read()
+
+
+#: a block's worth of records: sizes from empty to past a file buffer
+BATCH = [
+    (b"s/l" + bytes([i]) * 32, b"%d" % i * (i % 7)) for i in range(20)
+] + [(b"", b"empty-key"), (b"ev", b""), (b"big", b"\xab" * 20000),
+     (b"s/meta", b"cursor")]
+
+
+def test_batch_leaves_the_bytes_and_index_of_per_record_puts(
+    tmp_path, engine_cls
+):
+    one, many = str(tmp_path / "one"), str(tmp_path / "many")
+    a, b = engine_cls(one), engine_cls(many)
+    a.put(b"before", b"x")
+    b.put(b"before", b"x")
+    for key, value in BATCH:
+        a.put(key, value)
+    b.put_many(BATCH)
+    b.put_many([])  # nothing to write, nothing written
+    a.put(b"after", b"y")
+    b.put(b"after", b"y")
+    keys = [key for key, _ in BATCH] + [b"before", b"after", b"missing"]
+    assert b.get_many(keys) == [a.get(key) for key in keys]
+    assert a.get_many(keys) == b.get_many(keys)
+    assert b.get_many([]) == []
+    assert sorted(a.keys()) == sorted(b.keys())
+    a.close()
+    b.close()
+    assert _wal_bytes(many) == _wal_bytes(one)
+
+
+def test_batch_with_a_key_twice_keeps_the_last(tmp_path, engine_cls):
+    path = str(tmp_path / "db")
+    e = engine_cls(path)
+    e.put(b"k", b"old")
+    e.put_many([(b"k", b"first"), (b"other", b"1"), (b"k", b"last")])
+    assert e.get(b"k") == b"last"
+    assert len(e) == 2
+    e.close()
+    e2 = engine_cls(path)
+    assert e2.get_many([b"k", b"other"]) == [b"last", b"1"]
+    e2.close()
+
+
+def test_batch_counts_one_append_whatever_its_records(tmp_path, engine_cls):
+    from hotstuff_tpu.store.engine import WAL_COUNTS
+
+    e = engine_cls(str(tmp_path / "db"))
+    a0, r0 = WAL_COUNTS.appends, WAL_COUNTS.records
+    e.put_many(BATCH)
+    e.put(b"k", b"v")
+    e.delete(b"k")
+    e.put_many([])
+    e.get_many([b"k"])
+    e.close()
+    assert WAL_COUNTS.appends - a0 == 3
+    assert WAL_COUNTS.records - r0 == len(BATCH) + 2
+
+
+def test_batch_chopped_at_every_byte_replays_to_a_prefix(tmp_path, engine_cls):
+    """Crash-chop the log at EVERY byte offset inside a batch: replay
+    keeps the records before the batch and the whole records of the
+    batch before the cut, in order, drops the rest, and the engine
+    stays writable."""
+    batch = [(b"a", b"new-a"), (b"s/l" + b"\x07" * 32, b"ledger!"),
+             (b"a", b"newest-a"), (b"s/meta", b"m" * 30)]
+    src = str(tmp_path / "src")
+    e = engine_cls(src)
+    e.put(b"a", b"old-a")
+    e.put(b"keep", b"1")
+    e.close()
+    head = _wal_bytes(src)
+    e = engine_cls(src)
+    e.put_many(batch)
+    e.close()
+    full = _wal_bytes(src)
+    ends, at = [], len(head)
+    for key, value in batch:
+        at += 8 + len(key) + len(value)
+        ends.append(at)
+    assert at == len(full)
+    for cut in range(len(head), len(full) + 1):
+        path = str(tmp_path / ("db-%d" % cut))
+        os.makedirs(path)
+        with open(os.path.join(path, "wal.log"), "wb") as f:
+            f.write(full[:cut])
+        whole = sum(1 for end in ends if end <= cut)
+        expect = {b"a": b"old-a", b"keep": b"1"}
+        expect.update(batch[:whole])
+        e2 = engine_cls(path)
+        assert {k: e2.get(k) for k in e2.keys()} == expect, cut
+        e2.put(b"post", b"crash")
+        e2.close()
+        kept = ends[whole - 1] if whole else len(head)
+        assert _wal_bytes(path)[:kept] == full[:kept]
+        e3 = engine_cls(path)
+        assert e3.get(b"post") == b"crash"
+        assert e3.get(b"a") == expect[b"a"]
+        e3.close()
+
+
+def test_write_many_wakes_every_keys_readers_and_refuses_a_closed_store(
+    tmp_path, engine_cls
+):
+    async def body():
+        path = str(tmp_path / "db")
+        store = Store(path, engine=engine_cls(path))
+        w1 = asyncio.create_task(store.notify_read(b"block"))
+        w2 = asyncio.create_task(store.notify_read(b"block"))
+        w3 = asyncio.create_task(store.notify_read(b"index"))
+        idle = asyncio.create_task(store.notify_read(b"never"))
+        await asyncio.sleep(0.05)
+        assert not (w1.done() or w2.done() or w3.done())
+        await store.write_many(
+            [(b"block", b"B"), (b"index", b"I"), (b"latest", b"L")]
+        )
+        got = await asyncio.wait_for(asyncio.gather(w1, w2, w3), 1)
+        assert got == [b"B", b"B", b"I"]
+        assert not idle.done()
+        assert await store.read(b"latest") == b"L"
+        assert await store.notify_read(b"index") == b"I"
+        store.close()
+        await asyncio.gather(idle, return_exceptions=True)
+        assert idle.cancelled()
+        with pytest.raises(RuntimeError):
+            await store.write_many([(b"k", b"v")])
+
+    run(body())

@@ -173,3 +173,154 @@ def test_store_actor_uses_native_engine(tmp_path):
     e = open_engine(str(tmp_path / "db"))
     assert type(e).__name__ == "NativeEngine"
     e.close()
+
+
+# ---- the write batch --------------------------------------------------------
+
+
+@needs_native
+@pytest.mark.parametrize("writer", ["wal", "native"])
+def test_batch_written_by_one_engine_is_recovered_by_the_other(tmp_path, writer):
+    """A batch leaves plain WAL records: the other engine replays them,
+    appends a batch of its own, and the first reads both back."""
+    first, second = (
+        (WalEngine, NativeEngine) if writer == "wal" else (NativeEngine, WalEngine)
+    )
+    path = str(tmp_path / "db")
+    batch = [(b"s/l" + bytes([i]) * 32, struct.pack("<QI", 9, i)) for i in range(21)]
+    batch.append((b"s/meta", b"m" * 88))
+    e = first(path)
+    e.put(b"gone", b"soon")
+    e.put_many(batch)
+    e.delete(b"gone")
+    e.close()
+    e2 = second(path)
+    assert len(e2) == len(batch)
+    assert e2.get_many([k for k, _ in batch]) == [v for _, v in batch]
+    e2.put_many([(b"s/meta", b"n" * 88), (b"from", b"the-other")])
+    e2.close()
+    e3 = first(path)
+    assert e3.get(b"s/meta") == b"n" * 88
+    assert e3.get(b"from") == b"the-other"
+    assert e3.get(b"gone") is None
+    assert len(e3) == len(batch) + 1
+    e3.close()
+
+
+@needs_native
+@pytest.mark.parametrize(
+    "buf",
+    [
+        struct.pack("<II", 1, 1) + b"k",  # value overruns the buffer
+        struct.pack("<II", 1, 1) + b"kv" + b"\x01\x00\x00",  # torn header
+        struct.pack("<II", 1, 0xFFFFFFFF) + b"k",  # a tombstone is no put
+    ],
+    ids=["overrun", "torn-header", "tombstone"],
+)
+def test_native_refuses_a_malformed_batch_whole(tmp_path, buf):
+    """hs_put_many checks the packed buffer before it writes: a bad
+    batch leaves the log and the index as they were."""
+    path = str(tmp_path / "db")
+    e = NativeEngine(path)
+    e.put(b"a", b"1")
+    good = struct.pack("<II", 1, 1) + b"a2"
+    assert e._lib.hs_put_many(e._h, good + buf, len(good + buf)) == -1
+    assert e.get(b"a") == b"1" and len(e) == 1
+    before = e.wal_bytes()
+    assert e._lib.hs_put_many(e._h, good, len(good)) == 0
+    assert e.get(b"a") == b"2" and e.wal_bytes() == before + len(good)
+    e.close()
+
+
+_SHIM_C = r"""
+#define _GNU_SOURCE
+#include <fcntl.h>
+#include <stdio.h>
+#include <stdlib.h>
+#include <sys/syscall.h>
+#include <unistd.h>
+
+static int logfd = -1;
+
+static void note(const char* op, int fd, long n) {
+  if (logfd < 0) {
+    const char* p = getenv("WAL_SHIM_LOG");
+    if (!p) return;
+    logfd = syscall(SYS_openat, AT_FDCWD, p, O_WRONLY | O_APPEND | O_CREAT, 0644);
+    if (logfd < 0) return;
+  }
+  char b[64];
+  int m = snprintf(b, sizeof b, "%s %d %ld\n", op, fd, n);
+  syscall(SYS_write, logfd, b, m);
+}
+
+ssize_t write(int fd, const void* p, size_t n) {
+  note("write", fd, (long)n);
+  return syscall(SYS_write, fd, p, n);
+}
+int fdatasync(int fd) { note("sync", fd, 0); return syscall(SYS_fdatasync, fd); }
+int fsync(int fd) { note("sync", fd, 0); return syscall(SYS_fsync, fd); }
+"""
+
+_SHIM_SCRIPT = r"""
+import os, sys
+sys.path.insert(0, {root!r})
+from hotstuff_tpu.store.engine import WalEngine
+from hotstuff_tpu.store.native import NativeEngine
+e = {cls}({path!r}, fsync_mode={mode})
+e.put(b"warm", b"up")
+fd = next(int(n) for n in os.listdir("/proc/self/fd")
+          if os.path.realpath("/proc/self/fd/" + n).endswith("wal.log"))
+os.write(1, b"fd %d\n" % fd)
+os.write(fd, b"")  # a marker in the shim's log: the batch follows
+e.put_many([(b"s/l%d" % i, b"v" * i) for i in range(21)] + [(b"s/meta", b"m")])
+os.write(fd, b"")
+e.close()
+"""
+
+
+@pytest.fixture(scope="module")
+def wal_shim(tmp_path_factory):
+    """An LD_PRELOAD library that notes every write and sync a process
+    makes, by descriptor: both engines reach the WAL through libc."""
+    d = tmp_path_factory.mktemp("shim")
+    src, lib = d / "shim.c", d / "shim.so"
+    src.write_text(_SHIM_C)
+    try:
+        subprocess.run(
+            ["gcc", "-O1", "-shared", "-fPIC", "-o", str(lib), str(src)],
+            check=True, capture_output=True, timeout=60,
+        )
+    except (OSError, subprocess.SubprocessError) as e:
+        pytest.skip(f"cannot build the shim: {e}")
+    return str(lib)
+
+
+@needs_native
+@pytest.mark.parametrize("mode", [0, 1])
+@pytest.mark.parametrize("cls", ["WalEngine", "NativeEngine"])
+def test_batch_is_one_write_and_in_mode_1_one_sync_after_it(
+    tmp_path, wal_shim, cls, mode
+):
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    path, log = str(tmp_path / "db"), str(tmp_path / "calls.log")
+    proc = subprocess.run(
+        [sys.executable, "-c",
+         _SHIM_SCRIPT.format(root=root, path=path, cls=cls, mode=mode)],
+        capture_output=True, timeout=60,
+        env={**os.environ, "LD_PRELOAD": wal_shim, "WAL_SHIM_LOG": log},
+    )
+    assert proc.returncode == 0, proc.stderr.decode()
+    fd = proc.stdout.split()[1].decode()
+    calls = [
+        (op, int(n)) for op, f, n in
+        (line.split() for line in open(log).read().splitlines()) if f == fd
+    ]
+    first = calls.index(("write", 0))
+    last = calls.index(("write", 0), first + 1)
+    size = sum(8 + len(b"s/l%d" % i) + i for i in range(21)) + 8 + 6 + 1
+    expect = [("write", size)] + ([("sync", 0)] if mode == 1 else [])
+    assert calls[first + 1 : last] == expect
+    e = WalEngine(path)
+    assert len(e) == 23 and e.get(b"s/meta") == b"m"
+    e.close()
