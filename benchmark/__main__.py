@@ -938,10 +938,10 @@ def main(argv=None) -> int:
         "--diff",
         default=None,
         metavar="REF.json",
-        help="reference attribution to gate against (a committed "
-        "scripts/perf/BENCH_rXX.json, a bench JSON doc, or a prior "
-        "logs/critpath.json); exit 1 when any stage's latency share "
-        "grew beyond HOTSTUFF_CRITPATH_DIFF_PP percentage points",
+        help="reference attribution to gate against (a bench JSON "
+        "document or a prior attribution document); exit 1 when any "
+        "stage's latency share grew beyond "
+        "HOTSTUFF_CRITPATH_DIFF_PP percentage points",
     )
     p.add_argument(
         "--json",

@@ -12,9 +12,8 @@ every commit's latency to the registered stage taxonomy, and
 - with ``--diff REF.json``, gate on ATTRIBUTION SHAPE: exit nonzero when
   any stage's share of commit latency regressed beyond the tolerance
   (HOTSTUFF_CRITPATH_DIFF_PP percentage points, default 10) even if the
-  scalar latency held.  REF may be a committed bench reference
-  (scripts/perf/BENCH_rXX.json — its parsed doc's "critpath" block), a
-  bench JSON line document, or a previously written critpath.json.
+  scalar latency held.  REF may be a bench JSON document or a prior
+  attribution document (a previously written critpath.json).
 """
 
 from __future__ import annotations
@@ -40,8 +39,8 @@ def diff_share_pp() -> float:
 def load_reference_attribution(path: str) -> dict | None:
     """Extract an attribution document from ``path``: a raw
     critpath.json ({"stages": ...}), a bench JSON doc with a "critpath"
-    block, or a committed reference record ({"parsed": {...}} /
-    {"tail": "..."} from scripts/perf/BENCH_rXX.json)."""
+    block, or a record that wraps one ({"parsed": {...}} /
+    {"tail": "..."})."""
     try:
         with open(path) as f:
             doc = json.load(f)

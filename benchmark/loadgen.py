@@ -768,31 +768,6 @@ def format_load_block(result: dict) -> str:
     return "\n".join(lines) + "\n"
 
 
-def quick_load(
-    nodes: int = 4,
-    rate: int = 2_000,
-    duration: float = 10.0,
-    max_pending: int | None = None,
-    read_fraction: float = 0.0,
-) -> dict:
-    """One fixed-rate run for the bench.py ``load`` block / perfgate
-    guards: goodput + client percentiles without the full sweep."""
-    row = LoadBench(
-        nodes=nodes, rate=rate, duration=duration, max_pending=max_pending,
-        read_fraction=read_fraction,
-    ).run()
-    return {
-        "offered_tx_s": row["offered_tx_s"],
-        "goodput_tx_s": row["goodput_tx_s"],
-        "client_p50_ms": row["client_p50_ms"],
-        "client_p99_ms": row["client_p99_ms"],
-        "shed_server": row["shed_server"],
-        "shed_client": row["shed_client"],
-        "drop_newest": row["drop_newest"],
-        **({"reads": row["reads"]} if row.get("reads") else {}),
-    }
-
-
 # ---- fleet CLI (the client process LoadBench spawns) ------------------------
 
 

@@ -627,9 +627,9 @@ class LogParser:
 
     def net_summary(self) -> dict | None:
         """Committee-wide wire flow rollup (ISSUE 19), or None when no
-        node exported an ENABLED flows section.  The perfgate ``net``
-        block and the scaling table read this instead of re-deriving it
-        from raw snapshots."""
+        node exported an ENABLED flows section.  The SUMMARY's
+        ``+ NET`` block and the scaling table read this instead of
+        re-deriving it from raw snapshots."""
         live = [f for f in self.flow_docs if f.get("enabled")]
         if not live:
             return None

@@ -48,7 +48,7 @@ def _percentile(values: list[float], pct: float) -> float:
 
 def make_qc_claim(n: int, scheme: str = "ed25519"):
     """One "shared" claim with n committee signatures over one digest —
-    the QC verify shape (bench.py's make_qc_batch, claim-shaped).
+    the QC verify shape.
     ``scheme="bls"`` builds the same claim over BLS12-381 material
     (96-byte G2 pubkeys, 48-byte G1 signatures)."""
     from hotstuff_tpu.crypto import Digest

@@ -316,9 +316,7 @@ def format_report(nodes: int, results: dict[str, dict[str, float]]) -> str:
                 f"{_fmt_ms(m['offloop_max_stall_s'])}",
             ]
     lines += [
-        " NOTE: every device dispatch includes the dispatch latency;",
-        " bench.py's device_ms slope metric isolates the per-batch",
-        " device time.",
+        " NOTE: every device time above includes the dispatch latency.",
         "-" * 64,
     ]
     return "\n".join(lines)

@@ -840,8 +840,8 @@ def phase_committee(
 ) -> dict:
     """The committee of the module docstring (the defaults; a CPU dry
     run of this code path passes nodes=4, verifier="cpu"), run from a
-    working directory under the smoke's output directory so that no
-    tracked file under results/ is appended to."""
+    working directory under the smoke's output directory so that it
+    writes nothing into the checkout."""
     work = os.path.join(OUT, "committee")
     shutil.rmtree(work, ignore_errors=True)
     os.makedirs(work)

@@ -380,44 +380,9 @@ def eval_claims_sync(backend, claims: list) -> list[bool]:
 #: tests/test_wire_fuzz.py asserts this constant against the live one.
 INGEST_TAG_VOTE = 1
 
+#: arenas in the native ring: pipeline depth 2, a probe, and headroom
+#: before pack falls back
 DEFAULT_INGEST_RING_DEPTH = 6
-
-
-def zero_copy_from_env() -> bool:
-    """HOTSTUFF_ZERO_COPY: "0"/"off" disables the native ingest-arena
-    fast path; default on (subject to native-packer availability)."""
-    import os
-
-    raw = os.environ.get("HOTSTUFF_ZERO_COPY", "").strip().lower()
-    return raw not in ("0", "off", "no", "false", "none")
-
-
-def ingest_arena_rows_from_env() -> int:
-    """HOTSTUFF_INGEST_ARENA_ROWS: staging-arena capacity in claim rows;
-    default = the largest canonical wave bucket, so every bucket-shaped
-    wave is a prefix view of one arena."""
-    import os
-
-    raw = os.environ.get("HOTSTUFF_INGEST_ARENA_ROWS", "")
-    try:
-        rows = int(raw)
-    except ValueError:
-        rows = 0
-    return rows if rows > 0 else DEFAULT_WAVE_BUCKETS[-1]
-
-
-def ingest_ring_from_env() -> int:
-    """HOTSTUFF_INGEST_RING: staging arenas in the native ring (min 2:
-    one open for packing while sealed ones are in flight); default 6 —
-    pipeline depth 2, a probe, and headroom before pack falls back."""
-    import os
-
-    raw = os.environ.get("HOTSTUFF_INGEST_RING", "")
-    try:
-        depth = int(raw)
-    except ValueError:
-        depth = 0
-    return depth if depth >= 2 else DEFAULT_INGEST_RING_DEPTH
 
 
 _pad_claim_cached: tuple | None = None
@@ -494,9 +459,12 @@ class ZeroCopyIngest:
     ):
         from .native_ed25519 import WavePacker
 
-        cap = capacity if capacity else ingest_arena_rows_from_env()
-        depth = ring_depth if ring_depth else ingest_ring_from_env()
-        self.packer = WavePacker(cap, depth)
+        # the largest canonical wave bucket by default, so every
+        # bucket-shaped wave is a prefix view of one arena
+        self.packer = WavePacker(
+            capacity or DEFAULT_WAVE_BUCKETS[-1],
+            ring_depth or DEFAULT_INGEST_RING_DEPTH,
+        )
         pad = make_pad_claim()
         if not self.packer.set_pad(pad[1], pad[2], pad[3]):
             raise RuntimeError("wave packer pad install failed")
@@ -579,28 +547,26 @@ class ZeroCopyIngest:
         return out
 
 
-#: None = never tried; False = disabled/unavailable (cached); else the
+#: None = never tried; False = unavailable (cached); else the
 #: live ZeroCopyIngest
 _zero_copy: "ZeroCopyIngest | bool | None" = None
 
 
 def zero_copy_ingest() -> "ZeroCopyIngest | None":
     """The process-global ingest plane, created on first use by a
-    receiver; None when disabled (``HOTSTUFF_ZERO_COPY=0``) or the
-    native packer is unavailable (no toolchain — cached, never retried
-    per frame)."""
+    receiver; None when the native packer is unavailable (no toolchain
+    — cached, never retried per frame)."""
     global _zero_copy
     if _zero_copy is None:
-        created: ZeroCopyIngest | bool = False
-        if zero_copy_from_env():
-            from . import native_ed25519
+        from . import native_ed25519
 
-            if native_ed25519.wave_pack_available():
-                try:
-                    created = ZeroCopyIngest()
-                except Exception as e:  # noqa: BLE001 — ingest must
-                    # degrade to the Python path, never break receive
-                    log.info("zero-copy ingest unavailable: %s", e)
+        created: ZeroCopyIngest | bool = False
+        if native_ed25519.wave_pack_available():
+            try:
+                created = ZeroCopyIngest()
+            except Exception as e:  # noqa: BLE001 — ingest must
+                # degrade to the Python path, never break receive
+                log.info("zero-copy ingest unavailable: %s", e)
         _zero_copy = created
     return _zero_copy if type(_zero_copy) is ZeroCopyIngest else None
 
@@ -1184,8 +1150,6 @@ class AsyncVerifyService:
         a pipeline slot, so a full pipeline never probes."""
         import os
 
-        if os.environ.get("HOTSTUFF_FORCE_CPU_ROUTE"):
-            return "cpu"  # diagnostic: keep jax warm but never dispatch
         if not getattr(self.backend, "device_ready", True):
             return "cpu"
         now = time.monotonic()
@@ -1790,15 +1754,12 @@ __all__ = [
     "eval_claims_arena",
     "eval_claims_sync",
     "flatten_claims",
-    "ingest_arena_rows_from_env",
     "ingest_note_frame",
-    "ingest_ring_from_env",
     "make_pad_claim",
     "pipeline_depth_from_env",
     "wave_buckets_from_env",
     "resolve_wave_buckets",
     "coalesce_window_s_from_env",
-    "zero_copy_from_env",
     "zero_copy_ingest",
     "zero_copy_ingest_if_active",
     "CPU_US_PER_SIG",

@@ -5,11 +5,11 @@ This is the dalek-parity CPU batch path: the reference's
 ``Signature::verify_batch`` (crypto/src/lib.rs:213-226) delegates to
 ed25519-dalek's random-linear-combination batch verification; this
 bridge exposes the same equation implemented in C++ (Pippenger
-multiscalar over the 51-bit-limb field).  Measured on this rig it
-verifies a 256-vote QC ~3.7x faster than the per-signature OpenSSL
-loop — it is both the production fast path for QC-shaped verification
-(``CpuVerifier.verify_shared_msg``) and the honest CPU baseline
-``bench.py`` compares the TPU kernel against.
+multiscalar over the 51-bit-limb field).  On the pre-chip rig it
+verified a 256-vote QC ~3.7x faster than the per-signature OpenSSL
+loop — it is the production fast path for QC-shaped verification
+(``CpuVerifier.verify_shared_msg``) and the CPU baseline a device
+verifier has to beat.
 
 The ctypes call releases the GIL for the whole batch, so off-thread
 callers (AsyncVerifyService workers) overlap it with event-loop work.

@@ -389,15 +389,7 @@ class Node:
         # device pays off far below the per-node min_device_batch and the
         # warm shapes must cover whole-committee waves.
         colocated = int(os.environ.get("HOTSTUFF_COLOCATED_NODES", "1") or 1)
-        # HOTSTUFF_SKIP_WARMUP (diagnostic): run the device-verifier
-        # plumbing with jax never imported — the service's ready gate
-        # keeps everything on CPU.  Must skip the WHOLE warmup block,
-        # not just the co-location boost.
-        if (
-            verifier_backend != "cpu"
-            and hasattr(verifier, "warmup")
-            and not os.environ.get("HOTSTUFF_SKIP_WARMUP")
-        ):
+        if verifier_backend != "cpu" and hasattr(verifier, "warmup"):
             min_batch = getattr(verifier, "min_device_batch", 0)
             if committee_size >= min_batch or colocated > 1:
                 # A device verifier means the chip: refuse any other

@@ -204,8 +204,8 @@ class ShardedBatchVerifier(BatchVerifier):
             # must include the intermediate multiples: (128, 128, 1024)
             # made a 256-vote QC pad to 1024 — 4x the work — which was
             # the whole "sharded route pays ~4x at mesh 1" anomaly
-            # (VERDICT r4 weak #4; BENCH_r04 sharded_route 2.008 ms vs
-            # 0.526 single-device).
+            # (sharded route 2.008 ms vs 0.526 single-device, pre-chip,
+            # through the remote link).
             self.pad_sizes = tuple(
                 m * k * pallas_dsm.LANE_TILE for k in (1, 2, 4, 8)
             )

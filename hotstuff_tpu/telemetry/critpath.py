@@ -125,9 +125,9 @@ class CritPathReport:
     stage_totals: dict = field(default_factory=dict)  # stage -> summed ms
 
     def attribution(self) -> dict:
-        """The machine-readable attribution document: bench.py's
-        "critpath" block, the perfgate guards, SimVerdict.attribution,
-        and both sides of the --diff gate all speak this shape."""
+        """The machine-readable attribution document:
+        SimVerdict.attribution and both sides of the --diff gate speak
+        this shape."""
         totals = [c.total_ms for c in self.commits]
         measured = sum(totals)
         stages: dict[str, dict] = {}
@@ -439,7 +439,7 @@ def diff(
     catching "same scalar, different shape" drifts the latency ratchet
     is blind to.  Stages below ``min_share`` on both sides are noise
     and ignored; stages or whole documents missing on either side are
-    skipped (skip-if-missing, like the perfgate guards)."""
+    skipped."""
     fails: list[str] = []
     cur_stages = (current or {}).get("stages") or {}
     ref_stages = (reference or {}).get("stages") or {}

@@ -1,7 +1,7 @@
 """Verify-pipeline span profiler: where a claim wave's wall time goes.
 
-``BENCH_r05.json`` shows the QC-256 verify at ~0.46 ms on-device but
-~91 ms p50 end-to-end on the rig — a ~180x host-side gap that neither
+The QC-256 verify read ~0.46 ms on-device but ~91 ms p50 end-to-end
+(pre-chip, through the remote link) — a ~180x host-side gap that neither
 the metric counters (ISSUE 1), the flight recorder (ISSUE 2), nor the
 chaos plane (ISSUE 3) can attribute to a *stage*.  This module is the
 missing instrument: a ring-buffered span recorder the verify pipeline

@@ -215,12 +215,7 @@ class BatchVerifier:
     @property
     def use_pallas(self) -> bool:
         if self._use_pallas is None:
-            import os
-
-            self._use_pallas = (
-                jax.default_backend() == "tpu"
-                and not os.environ.get("HOTSTUFF_NO_PALLAS")
-            )
+            self._use_pallas = jax.default_backend() == "tpu"
         return self._use_pallas
 
     @property
@@ -568,16 +563,15 @@ class BatchVerifier:
 
     def stage(self, messages, pubkeys, signatures):
         """(kernel_fn, kernel arrays, host_validity) for this batch —
-        the production dispatch point (bench.py uses it to time exactly
-        what production dispatches; the mesh-sharded subclass overrides
-        ``_run_kernel``).
+        the production dispatch point (the mesh-sharded subclass
+        overrides ``_run_kernel``).
 
         NOTE (round 3): a split-scalar kernel variant (each signature as
         two 128-bit half rows, 16 macro steps) lived here through round
         2.  It was DELETED together with its 2^128-point caches, doubled
         base tables and interleave layout: its entire win was avoiding
         the old 256-lane minimum pad, and the kernel is VPU-throughput-
-        bound (~linear cost in lanes — scripts/probe_tile_scaling.py),
+        bound (~linear cost in lanes, pre-chip rig),
         so with the 128-lane tile a 64-vote QC at 32 steps x 128 lanes
         costs the same as 16 steps x 256 lanes, without ~600 lines of
         machinery."""
@@ -709,8 +703,8 @@ class BatchVerifier:
         """Device dispatch — overridden by the mesh-sharded verifier.
         ``donate=True`` selects the buffer-donating compilation of the
         same kernel (callers must not reuse the staging arrays after);
-        the default keeps external stage() users (bench.py re-dispatches
-        the same staged arrays) on the non-consuming variant."""
+        the default keeps external stage() users, which may re-dispatch
+        the same staged arrays, on the non-consuming variant."""
         if self.use_pallas:
             kernel = (
                 _verify_kernel_pallas_donated

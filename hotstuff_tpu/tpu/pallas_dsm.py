@@ -47,11 +47,11 @@ NCOLS = 2 * NL - 1  # 39
 LANE_TILE = 128  # minimum batch tile (lane width)
 # Batch tile.  128 (one lane width) since round 3: the kernel is
 # VPU-THROUGHPUT-bound — slope-timing at 128/256/512 lanes measured
-# 1.83/3.28/6.47 ms, ~linear in lanes (scripts/probe_tile_scaling.py) —
+# 1.83/3.28/6.47 ms, ~linear in lanes (pre-chip rig) —
 # so narrower tiles cost nothing, and the round-3 wave batching (which
 # roughly triples per-tile transients: the mul waves materialize
 # [NL, NL, 4*Bt] outer products) blows the 16M scoped-VMEM cap at 256
-# lanes (21.7M, measured via scripts/probe_vmem_shapes.py).
+# lanes (21.7M, pre-chip rig).
 BT = 128
 
 # A 512-lane "wide tile" for the split kernel (one 16-step scan for a

@@ -14,9 +14,7 @@ digest and asserts, end to end:
     in committee size up to the bitmap byte, vs n x 144 for vote lists;
   * FLATNESS — compact verify p50 (one pairing over the memoized key
     sum) at the largest size stays within ``--flat-ratio`` (default
-    2.0) of the smallest — the one-pairing promise;
-  * HANDEL — the in-process two-level aggregation run covers the whole
-    quorum with <= log2(n) leader-side merges.
+    2.0) of the smallest — the one-pairing promise.
 
 At the smallest size the quorum additionally flows through the REAL
 ``Aggregator`` (consensus/aggregator.py) so the running-sum emission
@@ -66,7 +64,6 @@ def build_quorum(n: int, digest):
 
 def check_size(n: int, reps: int) -> tuple[float, list[str]]:
     """(compact verify p50 ms, failure messages) for one committee."""
-    from hotstuff_tpu.consensus.handel import HandelTopology, simulate
     from hotstuff_tpu.consensus.messages import QC, make_signer_bitmap
     from hotstuff_tpu.crypto import Digest, Signature
     from hotstuff_tpu.crypto.scheme import make_cpu_verifier
@@ -124,26 +121,9 @@ def check_size(n: int, reps: int) -> tuple[float, list[str]]:
     samples.sort()
     p50 = samples[len(samples) // 2]
 
-    # Handel: full quorum coverage in <= log2(n) leader merges
-    topo = HandelTopology.for_round(n, round_=3)
-    index_of = {pk: i for i, pk in enumerate(pks)}
-    final, top_merges, _ = simulate(
-        topo, {index_of[pk]: sig.to_bytes() for pk, sig in votes}
-    )
-    if final.weight != len(votes):
-        fails.append(
-            f"n={n}: Handel coverage {final.weight} != quorum {len(votes)}"
-        )
-    if top_merges > topo.levels:
-        fails.append(
-            f"n={n}: Handel leader merged {top_merges} partials "
-            f"(> {topo.levels} levels)"
-        )
-
     print(
         f"   n={n:4d}: compact {cb}B vs vote-list {vb}B, "
-        f"verify p50 {p50:.2f} ms, handel merges {top_merges}/"
-        f"{topo.levels} levels"
+        f"verify p50 {p50:.2f} ms"
     )
     return p50, fails
 

@@ -37,8 +37,6 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-# the check IS the zero-copy plane: force it on regardless of caller env
-os.environ["HOTSTUFF_ZERO_COPY"] = "1"
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
@@ -145,9 +143,9 @@ async def run(args) -> int:
 
         # paced open loop: at most two waves outstanding, like a real
         # committee where vote arrival tracks commit rate.  A flat-out
-        # flood would just overflow the staging arena (capacity
-        # HOTSTUFF_INGEST_ARENA_ROWS) and measure the resync path, not
-        # the steady state.
+        # flood would just overflow the staging arena (the largest
+        # wave bucket's rows) and measure the resync path, not the
+        # steady state.
         t0 = time.perf_counter()
         deadline = time.monotonic() + args.timeout
         for w in range(args.waves):

@@ -65,7 +65,7 @@ def _run_local(rate: int, duration: int) -> tuple[int, str]:
 
 def _amp_from_tables(flows: dict) -> list[float]:
     """Per-node propose amplification (wire/logical egress) from the
-    sim verdict's flow tables — the same rollup bench.py publishes."""
+    sim verdict's flow tables."""
     amps = []
     for tables in flows.values():
         wire = logical = 0

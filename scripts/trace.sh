@@ -9,19 +9,11 @@
 # logs/trace.json — open the latter at https://ui.perfetto.dev.
 # Timeout-bounded so a hung committee cannot wedge a CI job.
 #
-#   PERFGATE=1 scripts/trace.sh   # also run the perf regression gate
-#                                 # (scripts/perfgate.py) afterwards
 #   BYZ=1 scripts/trace.sh        # ONLY the Byzantine adversary matrix
 #                                 # (scripts/byz_check.py): equivocation
 #                                 # caught-and-attributed, collusion
 #                                 # FAILs with non-zero exit, withholding
 #                                 # recovers liveness
-#   MESH=1 scripts/trace.sh       # ONLY the mesh scale-out check
-#                                 # (scripts/mesh_check.py): wave trains
-#                                 # at mesh 1 and 8 on the virtual
-#                                 # 8-device CPU mesh, non-zero exit if
-#                                 # mesh-8 scaling efficiency falls
-#                                 # below the committed-reference floor
 #   AGG=1 scripts/trace.sh        # ONLY the compact-certificate sweep
 #                                 # (scripts/agg_check.py): compact vs
 #                                 # vote-list QC parity + one-pairing
@@ -113,11 +105,6 @@ set -euo pipefail
 
 cd "$(dirname "$0")/.."
 
-if [ "${MESH:-0}" = "1" ]; then
-    exec timeout -k 10 1800 env JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
-        python scripts/mesh_check.py "$@"
-fi
-
 if [ "${AGG:-0}" = "1" ]; then
     exec timeout -k 10 1800 env JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
         python scripts/agg_check.py "$@"
@@ -182,8 +169,3 @@ fi
 timeout -k 10 240 env JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
     python -m benchmark local \
     --nodes 4 --rate 500 --duration 10 --journal "$@"
-
-if [ "${PERFGATE:-0}" = "1" ]; then
-    timeout -k 10 1800 env JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
-        python scripts/perfgate.py
-fi

@@ -1,6 +1,6 @@
 """Aggregated compact certificates (ISSUE 9): parity with the vote-list
-form, the QC-verify memo, the device running sum, the Handel aggregation
-plane, and the async claims routing.
+form, the QC-verify memo, the device running sum, and the async claims
+routing.
 
 The load-bearing property is VERDICT PARITY: for every input — honest
 quorum, forged certificate, equivocating twin — the compact form (one
@@ -317,62 +317,6 @@ def test_compact_tc_from_timeout_quorum():
     )
     with pytest.raises(ConsensusError):
         bad.verify(com, verifier)
-
-
-def test_handel_topology_and_merges():
-    """Handel plane: deterministic seeded permutation, disjoint level
-    blocks, overlap rejection, and O(log n) leader merges at full
-    participation."""
-    from hotstuff_tpu.consensus.handel import (
-        HandelTopology,
-        PartialAggregate,
-        PartialOverlap,
-        simulate,
-    )
-
-    n = 64
-    t1 = HandelTopology.for_round(n, round_=4)
-    t2 = HandelTopology.for_round(n, round_=4)
-    assert t1.validator_at == t2.validator_at  # same round, same order
-    t3 = HandelTopology.for_round(n, round_=5)
-    assert t1.validator_at != t3.validator_at  # new round reshuffles
-    # the permutation is a bijection
-    assert sorted(t1.validator_at) == list(range(n))
-    assert t1.levels == 6  # log2(64)
-
-    # partial aggregates: disjoint merges combine, overlaps raise
-    com, by_pk = bls_committee(4)
-    digest = Digest.of(b"handel")
-    msg = QC(hash=digest, round=4).digest().to_bytes()
-    sigs = {
-        i: by_pk[pk].sign(msg).to_bytes()
-        for i, pk in enumerate(com.sorted_keys())
-    }
-    nbytes = 1
-    a = PartialAggregate.single(sigs[0], 0, nbytes)
-    b = PartialAggregate.single(sigs[1], 1, nbytes)
-    ab = a.merge(b)
-    assert ab.weight == 2
-    with pytest.raises(PartialOverlap):
-        ab.merge(b)  # validator 1 contributed twice
-
-    # full simulation at 64: every contribution lands, leader does at
-    # most `levels` merges — O(log n), not O(n)
-    big_sigs = {
-        i: BlsSecretKey(i + 2).sign(msg).to_bytes() for i in range(n)
-    }
-    topo = HandelTopology.for_round(n, round_=4)
-    final, top_merges, total = simulate(topo, big_sigs)
-    assert final.weight == n
-    assert top_merges <= topo.levels
-    # the tree-combined aggregate equals the flat host sum
-    flat = G1Point.sum(
-        [
-            G1Point.from_bytes(s, subgroup_check=False)
-            for s in big_sigs.values()
-        ]
-    )
-    assert final.point.to_bytes() == flat.to_bytes()
 
 
 def test_async_claims_route_agg():

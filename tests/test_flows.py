@@ -239,8 +239,14 @@ async def test_native_reactor_loopback_matches_python_ledger():
     # reactor ground truth: both directions of this loopback ran through
     # the one shared reactor, so its cumulative deltas cover our frames
     # plus the receiver's ACK replies — nothing else ran native here
-    after = reactor.counters()
     acks = rx_acc.tx_bytes()
+    # the ACKs are charged when queued and written by the reactor thread:
+    # give it a moment to count the last ones
+    for _ in range(200):
+        after = reactor.counters()
+        if after["tx_bytes"] - before["tx_bytes"] >= expected + acks:
+            break
+        await asyncio.sleep(0.01)
     assert after["tx_bytes"] - before["tx_bytes"] == expected + acks
     assert (
         after["tx_frames"] - before["tx_frames"]

@@ -300,7 +300,7 @@ def test_capped_decoder_truncation_sweep():
 
 def _bls_compact_fixture(n: int = 4):
     """(committee, sorted pks, quorum votes, compact QC) over one block
-    digest, using small-scalar secrets (bench.py fixture idiom)."""
+    digest, using small-scalar secrets."""
     from hotstuff_tpu.consensus.config import Committee
     from hotstuff_tpu.consensus.messages import QC, make_signer_bitmap
     from hotstuff_tpu.crypto import PublicKey
