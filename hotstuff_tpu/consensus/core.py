@@ -483,7 +483,9 @@ class Core:
         """The block, then the per-round payload index and latest-round
         key the proposer's payload buffering feeds on (core.rs:117-148),
         in that order as ONE store batch: one WAL append, in the log
-        before this returns."""
+        before this returns.  Only then is the block handed to the
+        synchronizer to keep (its ancestor lookups answer from the kept
+        object instead of decoding the stored bytes again)."""
         latest_raw = await self.store.read(LATEST_ROUND_KEY)
         latest = int.from_bytes(latest_raw, "big") if latest_raw else 0
         raw = None
@@ -505,6 +507,7 @@ class Core:
                 records.append((key, encode_payload_index(payloads)))
                 records.append((LATEST_ROUND_KEY, key))
         await self.store.write_many(records)
+        self.synchronizer.keep(block)
         if latest > block.round:
             self.log.warning("The block round is less than the last round")
 

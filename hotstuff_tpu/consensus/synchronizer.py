@@ -17,6 +17,13 @@ re-broadcasting to the whole committee every retry tick.  After
 ``sync_giveup`` seconds the request is abandoned: waiters are
 cancelled, the suspended children are forgotten (a live chain re-sends
 them via a later QC), and the store obligation is dropped.
+
+Also beyond the reference: the synchronizer KEEPS the last few blocks
+its core stored (``keep``), decoded, and ``get_parent_block`` answers
+from them before it reads the store.  The two ancestors of a block are
+the blocks the node processed one and two rounds ago; decoding each
+again out of the store, 43-vote QC and all, was the largest span of a
+64-node round.
 """
 
 from __future__ import annotations
@@ -37,6 +44,26 @@ from .wire import encode_sync_request
 log = logging.getLogger(__name__)
 
 TIMER_ACCURACY_S = 5.0
+
+#: decoded blocks a synchronizer keeps: the 2-chain asks for two, a
+#: commit walk after a view change for a few more
+KEPT_BLOCKS = 8
+
+
+class AncestorCounts:
+    """Parent lookups answered from a synchronizer's kept blocks (hits)
+    and those that went on to the store (misses), over every node of
+    the process since it started: the ``ancestor_hits=`` and
+    ``ancestor_misses=`` of the ``Host stats:`` line
+    (``telemetry/hoststats.py``)."""
+
+    def __init__(self):
+        self.hits = 0
+        self.misses = 0
+
+
+#: the process's one count, as ``store/engine.py`` ``WAL_COUNTS`` is
+ANCESTOR_COUNTS = AncestorCounts()
 
 
 class Synchronizer:
@@ -69,6 +96,13 @@ class Synchronizer:
         # ancestry below it is covered by the snapshot and must never
         # be fetched, whatever floor a caller passes.
         self.join_floor = 0
+        # digest -> the Block object the core stored under it, the
+        # newest KEPT_BLOCKS of them (``keep``).  A subset of the store,
+        # never ahead of it, and owned by this incarnation of the node:
+        # a restart (a new process, or the sim plane's crash/restart)
+        # builds a new Synchronizer, so it starts empty and ancestry is
+        # read from what the store recovered.
+        self._kept: dict[Digest, Block] = {}
         self._waiters: set[asyncio.Task] = set()
         # give-up bookkeeping: which waiters/children each parent pins
         self._by_parent: dict[Digest, list[asyncio.Task]] = {}
@@ -186,11 +220,24 @@ class Synchronizer:
                 )
         self._ensure_retry_task()
 
+    def keep(self, block: Block) -> None:
+        """Keep ``block`` for ``get_parent_block``.  The core calls this
+        once the block's store write has returned, and nothing else
+        does: what is kept is in the log.  Blocks are immutable after
+        construction (messages.py), so the kept object is the one a
+        decode of the stored bytes would rebuild."""
+        kept, digest = self._kept, block.digest()
+        kept.pop(digest, None)  # kept again: the newest now
+        kept[digest] = block
+        if len(kept) > KEPT_BLOCKS:
+            del kept[next(iter(kept))]
+
     async def get_parent_block(
         self, block: Block, floor: int = -1
     ) -> Block | None:
-        """The block certified by ``block.qc``; None if it must be fetched
-        (in which case processing of ``block`` is suspended).
+        """The block certified by ``block.qc``, from the kept blocks or
+        else decoded from the store; None if it must be fetched (in which
+        case processing of ``block`` is suspended).
 
         ``floor`` is the snapshot barrier: a node that adopted a
         QC-anchored state snapshot holds no block history at or below its
@@ -204,6 +251,15 @@ class Synchronizer:
         commit rule can never fire across the cut)."""
         if block.qc.is_genesis():
             return Block.genesis()
+        # a miss opens the span twice, around the lookup and around the
+        # decode: the store read between them is an ``await`` (lint rule
+        # no-await-in-span) and has the ``store.read`` span of its own
+        with _spans.span("core.ancestors", node=self._node):
+            kept = self._kept.get(block.parent)
+            if kept is not None:
+                ANCESTOR_COUNTS.hits += 1
+                return kept
+            ANCESTOR_COUNTS.misses += 1
         data = await self.store.read(block.parent.to_bytes())
         if data is not None:
             with _spans.span("core.ancestors", node=self._node):

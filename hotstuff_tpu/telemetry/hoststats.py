@@ -9,6 +9,7 @@ first over its window)::
     Host stats: elapsed_s=35.004 cpu_user_s=30.21 cpu_sys_s=3.02
       lag_samples=640 lag_mean_ms=1.25 lag_max_ms=41.7 gc2=1 gc2_s=0.038
       store_appends=5210 store_records=38877
+      ancestor_hits=24310 ancestor_misses=0
 
 - ``cpu_user_s`` / ``cpu_sys_s``: the process's CPU seconds, all threads
   (``os.times``).  Over a window's wall time they say whether a long
@@ -25,6 +26,12 @@ first over its window)::
   they carried, every store engine of the process
   (``store/engine.py`` ``WAL_COUNTS``): records over appends is how far
   the write batch engages.
+- ``ancestor_hits`` / ``ancestor_misses``: parent lookups of every
+  node's synchronizer (``consensus/synchronizer.py``
+  ``get_parent_block``) answered from the blocks it keeps, and those
+  that went on to the store; the genesis answer is neither.  Hits over
+  both is how often a node is spared a second decode of a block it has
+  just processed.
 
 A pause in which this process and another both stand still shows as one
 ``lag_max_ms`` the size of the pause with neither ``gc2_s`` nor CPU
@@ -88,6 +95,9 @@ class HostStats:
 
     def line(self) -> str:
         """The counters as ``key=value`` pairs; resets the line's max."""
+        # consensus imports telemetry, so its counter is fetched here
+        from ..consensus.synchronizer import ANCESTOR_COUNTS
+
         cpu = os.times()
         mean = self.lag_total_s / self.lag_samples if self.lag_samples else 0.0
         lag_max, self._lag_max_line_s = self._lag_max_line_s, 0.0
@@ -98,7 +108,9 @@ class HostStats:
             f"lag_max_ms={lag_max * 1e3:.3f} "
             f"gc2={self.gc2} gc2_s={self.gc2_s:.4f} "
             f"store_appends={WAL_COUNTS.appends} "
-            f"store_records={WAL_COUNTS.records}"
+            f"store_records={WAL_COUNTS.records} "
+            f"ancestor_hits={ANCESTOR_COUNTS.hits} "
+            f"ancestor_misses={ANCESTOR_COUNTS.misses}"
         )
 
     async def run(self, logger=None) -> None:

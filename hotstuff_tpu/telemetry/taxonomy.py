@@ -75,7 +75,7 @@ SPAN_HOST_STAGES: tuple = (
     # consensus: consensus/core.py, synchronizer.py, crypto/service.py
     "core.claims",  # burst claim collection / verdict memoization
     "core.proposal",  # leader check, Block.verify, QC processing
-    "core.ancestors",  # parent blocks deserialized from the store
+    "core.ancestors",  # parent lookup among the kept blocks; a miss's decode
     "core.persist",  # ConsensusState / block serialization
     "core.vote.make",  # safety rules, Vote, its frame
     "core.sign",  # the signing call (votes, blocks, timeouts)

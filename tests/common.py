@@ -11,19 +11,33 @@ from __future__ import annotations
 import asyncio
 import functools
 import itertools
+import os
 
 from hotstuff_tpu.consensus import QC, TC, Block, Committee, Timeout, Vote
+from hotstuff_tpu.consensus.synchronizer import ANCESTOR_COUNTS
 from hotstuff_tpu.crypto import Digest, PublicKey, SecretKey, Signature, generate_keypair
 from hotstuff_tpu.network.framing import read_frame, send_frame
 
 SEED = bytes(32)
 
-# unique port ranges per test to avoid clashes (common.rs:39-46)
-_port_counter = itertools.count(26_000, 20)
+# unique port ranges per test to avoid clashes (common.rs:39-46).  An
+# xdist worker is a process with a counter of its own, so each worker
+# has a thousand ports of its own (gw0 from 26,000, gw5 to 31,999, all
+# below the ephemeral range) and goes round inside them: no count of
+# calls walks one worker into another's range.
+_worker = os.environ.get("PYTEST_XDIST_WORKER", "gw0")[2:]
+_first_port = 26_000 + 1_000 * (int(_worker) if _worker.isdigit() else 0)
+_port_counter = itertools.cycle(range(_first_port, _first_port + 1_000, 20))
 
 
 def fresh_base_port() -> int:
     return next(_port_counter)
+
+
+def ancestor_lookups() -> tuple[int, int]:
+    """(hits, misses) of the process's parent lookups so far: a test
+    reads it before and after, since the count is the process's."""
+    return ANCESTOR_COUNTS.hits, ANCESTOR_COUNTS.misses
 
 
 def async_test(fn):

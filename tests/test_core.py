@@ -7,7 +7,9 @@ core_tests.rs:61-183), plus crash-recovery coverage the reference lacks
 import asyncio
 from types import SimpleNamespace
 
-from hotstuff_tpu.consensus import Core, ConsensusState, ProposerMessage, Synchronizer
+import pytest
+
+from hotstuff_tpu.consensus import Block, Core, ConsensusState, ProposerMessage, Synchronizer
 from hotstuff_tpu.consensus.core import CONSENSUS_STATE_KEY, make_event_channels
 from hotstuff_tpu.consensus.leader import LeaderElector
 from hotstuff_tpu.consensus.wire import TAG_PROPOSE, TAG_VOTE, encode_timeout, encode_vote
@@ -16,6 +18,7 @@ from hotstuff_tpu.crypto.service import CpuVerifier
 from hotstuff_tpu.store import Store
 
 from .common import (
+    ancestor_lookups,
     async_test,
     chain,
     committee,
@@ -126,6 +129,55 @@ async def test_commit_chain_head(tmp_path):
 
     committed = await asyncio.wait_for(h.tx_commit.get(), timeout=2.0)
     assert committed.digest() == blocks[0].digest()
+    teardown(h)
+
+
+@async_test
+async def test_process_block_finds_its_ancestors_kept(tmp_path):
+    """``store_block`` hands each block to the synchronizer once it is
+    written, so the third block of a chain finds both ancestors kept
+    (two hits, no store read of a block) and the 2-chain commit fires on
+    the kept ``b0``: the object that was processed, not a decode of it."""
+    h = make_core(tmp_path, fresh_base_port(), name_idx=0)
+    blocks = chain(3)
+    hits, misses = ancestor_lookups()
+    await h.core._process_block(blocks[0])  # its parent is the genesis
+    assert ancestor_lookups() == (hits, misses)
+    await h.core._process_block(blocks[1])  # b1 kept, b0 the genesis
+    assert ancestor_lookups() == (hits + 1, misses)
+    assert h.tx_commit.empty()
+    await h.core._process_block(blocks[2])  # both kept
+    assert ancestor_lookups() == (hits + 3, misses)
+    assert h.tx_commit.get_nowait() is blocks[0]
+    assert list(h.sync._kept.values()) == blocks
+    # what is kept is what the log holds, byte for byte
+    for block in blocks:
+        stored = await h.store.read(block.digest().to_bytes())
+        assert stored == block.serialize()
+        assert Block.deserialize(stored) == block
+    teardown(h)
+
+
+@async_test
+async def test_store_block_keeps_a_block_only_once_written(tmp_path):
+    """The kept blocks are a subset of the log: a block whose append
+    fails is not kept, and one whose append returned is."""
+    h = make_core(tmp_path, fresh_base_port(), name_idx=0)
+    blocks = chain(2)
+    put_many = h.store.engine.put_many
+
+    def torn(pairs):
+        raise OSError("disk full")
+
+    h.store.engine.put_many = torn
+    with pytest.raises(OSError):
+        await h.core.store_block(blocks[0])
+    assert h.sync._kept == {}
+    assert await h.store.read(blocks[0].digest().to_bytes()) is None
+    h.store.engine.put_many = put_many
+    await h.core.store_block(blocks[0])
+    assert list(h.sync._kept.values()) == [blocks[0]]
+    assert await h.sync.get_parent_block(blocks[1]) is blocks[0]
     teardown(h)
 
 

@@ -13,6 +13,7 @@ from __future__ import annotations
 import asyncio
 import json
 import os
+import struct
 import time
 
 import pytest
@@ -25,6 +26,7 @@ from hotstuff_tpu.sim import (
     run_schedule,
     shrink,
 )
+from hotstuff_tpu.sim.harness import SimCluster
 from hotstuff_tpu.sim.schedule import SCHEDULE_VERSION
 
 CORPUS = os.path.join(os.path.dirname(__file__), "data", "sim_seeds.json")
@@ -121,6 +123,85 @@ def test_crash_injection_torn_tail_recovery(tmp_path):
     journal = (tmp_path / "journal.jsonl").read_text()
     assert "node 2 crashed (torn tail 33B)" in journal
     assert "node 2 restarted" in journal
+
+
+def test_crash_with_last_block_append_torn_forgets_the_block(
+    tmp_path, monkeypatch
+):
+    """The blocks a synchronizer keeps die with the node's incarnation.
+    A node crashed INSIDE the append of its last block (the log cut in
+    the middle of that block's record, so nothing after it happened
+    either) and restarted holds no kept block: it does not answer for
+    the torn block from memory, the recovered store does not hold it,
+    and the node asks the committee for it again."""
+    seen: dict = {}
+    crash, restart = SimCluster.crash, SimCluster.restart
+
+    async def crash_inside_last_append(self, i, torn_bytes=0):
+        node = self.nodes[i]
+        seen["sync"] = node.stack.synchronizer
+        seen["torn"] = torn = list(seen["sync"]._kept.values())[-1]
+        await crash(self, i, 0)
+        wal = os.path.join(node.path, "wal.log")
+        with open(wal, "rb") as f:
+            data = f.read()
+        at = off = 0
+        while off + 8 <= len(data):  # the last record under the digest
+            klen, vlen = struct.unpack_from("<II", data, off)
+            if data[off + 8 : off + 8 + klen] == torn.digest().to_bytes():
+                at = off
+            off += 8 + klen + vlen
+        assert at and off == len(data)
+        with open(wal, "r+b") as f:
+            f.truncate(at + 8 + 32 + len(torn.serialize()) // 2)
+
+    async def restart_and_look(self, i):
+        await restart(self, i)
+        node = self.nodes[i]
+        sync = node.stack.synchronizer
+        key = seen["torn"].digest().to_bytes()
+        seen["restarted"] = (
+            sync is not seen["sync"]
+            and seen["torn"].digest() not in sync._kept
+            and await node.store.read(key) is None
+        )
+
+    monkeypatch.setattr(SimCluster, "crash", crash_inside_last_append)
+    monkeypatch.setattr(SimCluster, "restart", restart_and_look)
+    schedule = {
+        "version": SCHEDULE_VERSION,
+        "seed": 12345,
+        "nodes": 4,
+        "duration_s": 9.0,
+        "profile": "honest",
+        "events": [
+            {"kind": "crash", "node": 2, "at": 2.0, "restart_at": 4.0}
+        ],
+    }
+    verdict = run_schedule(schedule, workdir=str(tmp_path))
+    assert verdict.ok, verdict.failures
+    assert seen["restarted"] is True
+    # the restarted incarnation's journal: the torn block is asked for
+    # again, arrives, and the node commits on
+    torn = seen["torn"]
+    events = []
+    journals = tmp_path / "journals"
+    for name in sorted(os.listdir(journals)):
+        if name.startswith(seen["sync"]._node[:4]):
+            for line in (journals / name).read_text().splitlines():
+                events.append(json.loads(line))
+    asked = [
+        e["s"] for e in events
+        if e["e"] == "sync.req" and e["d"] == str(torn.digest())
+    ]
+    assert asked, "the torn block was never requested again"
+    arrived = [
+        e["s"] for e in events
+        if e["e"] == "sync.done" and e["d"] == str(torn.digest())
+        and e["s"] > asked[0]
+    ]
+    assert arrived, "the torn block never arrived again"
+    assert any(e["e"] == "commit" and e["s"] > arrived[0] for e in events)
 
 
 # ---- shrinker ----------------------------------------------------------
