@@ -10,7 +10,7 @@ to be real is the cluster's: BASELINE.json config 3's committee, the
 smallest whose certificates reach the device at all (a 43-vote QC pads
 to the 128-lane tile; a 4-node QC never leaves the CPU).
 
-Three children, each gone — and the chip free — before the next starts;
+Four children, each gone — and the chip free — before the next starts;
 this parent never imports jax:
 
 1. verify     one process: jax's backend must be a TPU, or the smoke
@@ -30,6 +30,15 @@ this parent never imports jax:
               --nodes 64 --rate 200 --tx-size 512 --duration 30` under
               HOTSTUFF_FORCE_DEVICE_ROUTE=1, in a working directory of
               its own; its logs judged here
+4. wan        the wan50 deployment (chipbench/configs/wan50.json, which
+              is its own HOTSTUFF_WAN_SPEC: upstream's 50-node committee
+              over five regions, placed by the leader rotation, every
+              link's delay injected at the senders): the same harness
+              with --nodes 50 at the cell's rate for 20 s, claim dedup
+              off, device route pinned; it commits, the invariants of
+              chipbench/check.py hold over its log, every signature was
+              verified on the device, and the frames were held for their
+              links' matrix entries within 2%
 
 Any child failing makes the exit status non-zero, prints the reason and
 the offending log's traceback, and prints no result line.  On success
@@ -56,7 +65,9 @@ OUT = os.path.join(ROOT, "chiprun_out", "chip_smoke")
 
 #: the whole run must end inside the driver's 1200 s
 DEADLINE_S = 1150.0
-PHASE_CAP_S = {"verify": 600.0, "build": 300.0, "committee": 420.0}
+PHASE_CAP_S = {
+    "verify": 600.0, "build": 300.0, "committee": 420.0, "wan": 300.0,
+}  # fmt: skip
 
 #: the deployment (BASELINE.json config 3) and the offered load: 200 tx/s
 #: because 500 was past the knee on the chip host; the smoke asserts
@@ -73,6 +84,15 @@ DEPLOYMENT = (
     "delay, claim dedup as the harness defaults, device route pinned "
     "(HOTSTUFF_FORCE_DEVICE_ROUTE=1)"
 )
+
+#: the wan50 deployment: its configuration is its own WAN spec, its
+#: traffic file holds the cell's rate
+WAN_CONFIG = os.path.join(ROOT, "chipbench", "configs", "wan50.json")
+WAN_TRAFFIC = os.path.join(ROOT, "chipbench", "traffic", "low-wan50.json")
+WAN_DURATION_S = 20
+#: how far the mean held time may lie from the frames' matrix entries
+#: (the configuration's ``injected_delay`` guarantee)
+WAN_TOLERANCE = 0.02
 
 #: service waves: per bucket, every wave its own digest, each
 #: SPOIL_EVERY-th spoiled
@@ -836,25 +856,34 @@ def judge_committee(
 
 
 def phase_committee(
-    nodes: int = NODES, verifier: str = "tpu", duration: int = DURATION_S
+    nodes: int = NODES,
+    verifier: str = "tpu",
+    duration: int = DURATION_S,
+    rate: int = RATE,
+    phase: str = "committee",
+    env: dict | None = None,
+    wan: bool = False,
 ) -> dict:
     """The committee of the module docstring (the defaults; a CPU dry
     run of this code path passes nodes=4, verifier="cpu"), run from a
     working directory under the smoke's output directory so that it
-    writes nothing into the checkout."""
-    work = os.path.join(OUT, "committee")
+    writes nothing into the checkout.  ``env`` is added to the node
+    process's; the route pin is always there.  ``wan``: the run is
+    judged as the WAN stage's besides (``scrape_wan``, ``judge_wan``)."""
+    work = os.path.join(OUT, phase)
     shutil.rmtree(work, ignore_errors=True)
     os.makedirs(work)
     cmd = [
         sys.executable, "-m", "benchmark", "local", "--in-process",
-        "--verifier", verifier, "--nodes", str(nodes), "--rate", str(RATE),
+        "--verifier", verifier, "--nodes", str(nodes), "--rate", str(rate),
         "--tx-size", str(TX_SIZE), "--duration", str(duration),
         "--timeout-delay", str(TIMEOUT_MS),
     ]  # fmt: skip
     try:
         rc, output = run_child(
-            "committee", cmd, cwd=work, env={"HOTSTUFF_FORCE_DEVICE_ROUTE": "1"}
-        )
+            phase, cmd, cwd=work,
+            env={"HOTSTUFF_FORCE_DEVICE_ROUTE": "1", **(env or {})},
+        )  # fmt: skip
     finally:
         for name in os.listdir(work):  # stores, keys, configs go;
             if name != "logs":  # the logs are kept
@@ -865,14 +894,99 @@ def phase_committee(
                     os.remove(path)
     logs_dir = os.path.join(work, "logs")
     report = scrape_committee(logs_dir)
-    say(f"committee: {json.dumps(report)}")
+    if wan:
+        report.update(scrape_wan(logs_dir, nodes))
+    say(f"{phase}: {json.dumps(report)}")
     bad = judge_committee(report, nodes, rc, output, device=verifier != "cpu")
+    if wan:
+        bad += judge_wan(report, nodes, device=verifier != "cpu")
     if bad:
         culprit = (report["tracebacks"] or [os.path.join(logs_dir, "node-0.log")])[0]
         say(f"---- {culprit}")
         say(log_excerpt(culprit))
-        raise SmokeFailure("committee: " + "; ".join(bad))
+        raise SmokeFailure(f"{phase}: " + "; ".join(bad))
     return report
+
+
+# ---- child 4: the wan50 deployment ------------------------------------------
+
+RE_HOST_STATS = re.compile(r"Host stats: (.*)")
+
+
+def scrape_wan(logs_dir: str, nodes: int) -> dict:
+    """What the WAN stage adds to the committee's report: the
+    benchmark's own invariants over the one log, and the emulation's
+    counters from the last ``Host stats:`` line."""
+    from chipbench import check
+    from chipbench.logs import CommitteeLog
+
+    with open(os.path.join(logs_dir, "node-0.log")) as f:
+        text = f.read()
+    log = CommitteeLog()
+    log.feed(text)
+    last = {}
+    for m in RE_HOST_STATS.finditer(text):
+        last = dict(item.split("=") for item in m.group(1).split())
+    frames = float(last.get("wan_frames", 0))
+    return {
+        "check_violations": check.violations(log, nodes),
+        "wan_nodes_placed": text.count("WAN emulation active: region "),
+        "wan_frames": int(frames),
+        "wan_delay_ms": (
+            round(float(last["wan_delay_ms"]) / frames, 3) if frames else None
+        ),
+        "wan_base_ms": (
+            round(float(last["wan_base_ms"]) / frames, 3) if frames else None
+        ),
+        "sync_requests": int(float(last.get("sync_requests", 0))),
+    }
+
+
+def judge_wan(report: dict, nodes: int, device: bool = True) -> list[str]:
+    """Why the WAN stage does not pass, beyond ``judge_committee``.
+    View-change timeouts are reported and not judged, as in the
+    committee stage: a committee left idle times out every 5 s, and
+    the harness's client has come 24 s after the nodes (one run of
+    three on the chip, PR 32: five rounds timed out before the first
+    payload, none after it)."""
+    bad = []
+    if report["wan_nodes_placed"] != nodes:
+        bad.append(
+            f"{report['wan_nodes_placed']} of {nodes} nodes said where the "
+            "spec placed them"
+        )
+    if report["check_violations"]:
+        bad.append(f"chipbench/check.py: {report['check_violations'][:3]}")
+    held, base = report["wan_delay_ms"], report["wan_base_ms"]
+    if not report["wan_frames"] or not base:
+        bad.append("no frame was held: the emulation was not on")
+    elif abs(held / base - 1.0) > WAN_TOLERANCE:
+        bad.append(
+            f"frames were held {held} ms in the mean where their links' "
+            f"matrix entries come to {base} ms: off by more than "
+            f"{WAN_TOLERANCE:.0%}"
+        )
+    if device and report["device_share"] != 1.0:
+        bad.append(
+            f"device-routed share {report['device_share']} is not 1.0: "
+            "a node's certificates were verified off the chip"
+        )
+    return bad
+
+
+def phase_wan(verifier: str = "tpu", duration: int = WAN_DURATION_S) -> dict:
+    """The wan50 deployment on the harness the committee stage uses."""
+    with open(WAN_CONFIG) as f:
+        config = json.load(f)
+    with open(WAN_TRAFFIC) as f:
+        rate = json.load(f)["rate_tx_s"]
+    # the cell's env, the spec by its absolute path: the harness runs
+    # the committee from a working directory of its own
+    return phase_committee(
+        nodes=config["nodes"], verifier=verifier, duration=duration,
+        rate=rate, phase="wan", wan=True,
+        env={**config["env"], "HOTSTUFF_WAN_SPEC": WAN_CONFIG},
+    )  # fmt: skip
 
 
 # ---- the run ----------------------------------------------------------------
@@ -899,6 +1013,7 @@ def main(argv: list[str]) -> int:
             ("verify", lambda: run_self("verify")),
             ("build", phase_build),
             ("committee", phase_committee),
+            ("wan", phase_wan),
         ):
             say(f"== {phase} ({time.monotonic() - _T0:.0f} s in)")
             t0 = time.monotonic()
@@ -924,6 +1039,16 @@ def main(argv: list[str]) -> int:
         f"{committee['device_sigs']} of "
         f"{committee['device_sigs'] + committee['cpu_sigs']} signatures "
         "device-routed"
+    )
+    wan = summary["wan"]
+    say(
+        f"wan50 on {device['kind']}: {wan['committed_blocks']} blocks "
+        f"committed on {wan['nodes_committing']} nodes, consensus latency "
+        f"{wan['consensus_latency_ms']} ms, end-to-end "
+        f"{wan['e2e_latency_ms']} ms, {wan['view_change_timeouts']} "
+        f"view-change timeouts, {wan['wan_frames']} frames held "
+        f"{wan['wan_delay_ms']} ms in the mean (their links: "
+        f"{wan['wan_base_ms']} ms), {wan['sync_requests']} parent requests"
     )
     os.makedirs(OUT, exist_ok=True)
     with open(os.path.join(OUT, "summary.json"), "w") as f:

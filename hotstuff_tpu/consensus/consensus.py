@@ -652,19 +652,28 @@ class Consensus:
         # WAN emulation (HOTSTUFF_WAN_SPEC, network/wan.py): per-link
         # propagation delay on every node->node sender — the committee
         # experiences the reference's 5-region topology on localhost.
-        # asyncio transport only (the native reactor does its own I/O).
+        # The spec places nodes by address or, as a list of regions, by
+        # their position in the leader rotation, which only this seam
+        # knows.  asyncio senders only: the native reactor does its own
+        # I/O, and a spec it would skip is refused.
         link_delay = None
         wan_spec = os.environ.get("HOTSTUFF_WAN_SPEC")
-        if wan_spec and transport != "native":
-            from ..network.wan import WanModel
+        if wan_spec:
+            from ..network.wan import WanModel, WanSpecError
 
-            model = WanModel.load(wan_spec, address)
+            if transport == "native":
+                raise WanSpecError(
+                    "HOTSTUFF_WAN_SPEC needs the asyncio transport: the "
+                    "native reactor applies no link delays"
+                )
+            model = WanModel.load(wan_spec, address, committee)
             log.info(
-                "WAN emulation active: region %s", model.self_region
+                "WAN emulation active: region %s, position %s of %d",
+                model.self_region,
+                model.position,
+                len(model.regions),
             )
-
-            def link_delay(dst, _model=model):  # noqa: E731 — closure
-                return lambda: _model.delay(dst)
+            link_delay = model.link
 
         # Chaos plane (HOTSTUFF_FAULTS, faults/plane.py): seeded
         # deterministic fault injection, threaded through every sender

@@ -52,14 +52,16 @@ KEPT_BLOCKS = 8
 
 class AncestorCounts:
     """Parent lookups answered from a synchronizer's kept blocks (hits)
-    and those that went on to the store (misses), over every node of
-    the process since it started: the ``ancestor_hits=`` and
-    ``ancestor_misses=`` of the ``Host stats:`` line
-    (``telemetry/hoststats.py``)."""
+    and those that went on to the store (misses), and the parent
+    requests sent to peers (first asks and each peer of a retry
+    broadcast), over every node of the process since it started: the
+    ``ancestor_hits=``, ``ancestor_misses=`` and ``sync_requests=`` of
+    the ``Host stats:`` line (``telemetry/hoststats.py``)."""
 
     def __init__(self):
         self.hits = 0
         self.misses = 0
+        self.sync_requests = 0
 
 
 #: the process's one count, as ``store/engine.py`` ``WAL_COUNTS`` is
@@ -132,6 +134,7 @@ class Synchronizer:
                     self.log.debug("Requesting sync for block %s (retry)", digest)
                     addresses = self._sync_targets(child_round, parent_round)
                     message = encode_sync_request(digest, self.name)
+                    ANCESTOR_COUNTS.sync_requests += len(addresses)
                     await self.network.broadcast(addresses, message)
 
     def _sync_targets(self, child_round: int, parent_round: int) -> list:
@@ -215,6 +218,7 @@ class Synchronizer:
                 )
             address = self.committee.address(block.author)
             if address is not None:
+                ANCESTOR_COUNTS.sync_requests += 1
                 await self.network.send(
                     address, encode_sync_request(parent, self.name)
                 )

@@ -24,6 +24,7 @@ from ..crypto.scheme import (
     make_signing_service,
 )
 from ..crypto.service import CpuVerifier, VerifierBackend
+from ..network.wan import WanSpecError
 from ..store import Store
 from .config import ConfigError, Secret, read_committee, read_parameters
 
@@ -457,18 +458,21 @@ class Node:
             tel.attach_verify_work(work)
 
         self.commit = asyncio.Queue(maxsize=self.CHANNEL_CAPACITY)
-        self.consensus = await Consensus.spawn(
-            secret.name,
-            committee,
-            parameters,
-            signature_service,
-            self.store,
-            self.commit,
-            verifier=verifier,
-            bind_host=bind_host,
-            transport=transport,
-            telemetry=tel,
-        )
+        try:
+            self.consensus = await Consensus.spawn(
+                secret.name,
+                committee,
+                parameters,
+                signature_service,
+                self.store,
+                self.commit,
+                verifier=verifier,
+                bind_host=bind_host,
+                transport=transport,
+                telemetry=tel,
+            )
+        except WanSpecError as e:
+            raise ConfigError(str(e)) from e
         self._snapshot_task = None
         if tel is not None:
             from ..telemetry.exporter import run_snapshot_logger

@@ -9,7 +9,8 @@ first over its window)::
     Host stats: elapsed_s=35.004 cpu_user_s=30.21 cpu_sys_s=3.02
       lag_samples=640 lag_mean_ms=1.25 lag_max_ms=41.7 gc2=1 gc2_s=0.038
       store_appends=5210 store_records=38877
-      ancestor_hits=24310 ancestor_misses=0
+      ancestor_hits=24310 ancestor_misses=0 sync_requests=0
+      wan_frames=0 wan_delay_ms=0.0 wan_base_ms=0.0
 
 - ``cpu_user_s`` / ``cpu_sys_s``: the process's CPU seconds, all threads
   (``os.times``).  Over a window's wall time they say whether a long
@@ -31,7 +32,16 @@ first over its window)::
   ``get_parent_block``) answered from the blocks it keeps, and those
   that went on to the store; the genesis answer is neither.  Hits over
   both is how often a node is spared a second decode of a block it has
-  just processed.
+  just processed.  ``sync_requests``: parent requests those
+  synchronizers sent to peers, first asks and retries: a block that
+  reached a node before its parent.
+- ``wan_frames`` / ``wan_delay_ms`` / ``wan_base_ms``: frames the WAN
+  emulation held at a sender, the sum of what it held them for and the
+  sum of their links' matrix entries (``network/wan.py`` ``WAN_COUNTS``,
+  counted where ``LinkScheduler.deliver_at`` draws); all 0 without
+  ``HOTSTUFF_WAN_SPEC``.  Delay over frames is the mean injected
+  one-way delay; delay over base is held to 1 within 2% (the spec's
+  ``injected_delay`` guarantee).
 
 A pause in which this process and another both stand still shows as one
 ``lag_max_ms`` the size of the pause with neither ``gc2_s`` nor CPU
@@ -47,6 +57,7 @@ import logging
 import os
 import time
 
+from ..network.wan import WAN_COUNTS
 from ..store.engine import WAL_COUNTS
 
 log = logging.getLogger(__name__)
@@ -110,7 +121,11 @@ class HostStats:
             f"store_appends={WAL_COUNTS.appends} "
             f"store_records={WAL_COUNTS.records} "
             f"ancestor_hits={ANCESTOR_COUNTS.hits} "
-            f"ancestor_misses={ANCESTOR_COUNTS.misses}"
+            f"ancestor_misses={ANCESTOR_COUNTS.misses} "
+            f"sync_requests={ANCESTOR_COUNTS.sync_requests} "
+            f"wan_frames={WAN_COUNTS.frames} "
+            f"wan_delay_ms={WAN_COUNTS.delay_s * 1e3:.1f} "
+            f"wan_base_ms={WAN_COUNTS.base_s * 1e3:.1f}"
         )
 
     async def run(self, logger=None) -> None:

@@ -278,8 +278,18 @@ class BatchVerifier:
         # (ISSUE 6): a small wave the cost model routes to the device
         # pads UP to the smallest bucket, so that shape must be warm too
         if getattr(self, "supports_wave_padding", False):
-            from ..crypto.async_service import resolve_wave_buckets
+            from ..crypto.async_service import (
+                make_pad_claim,
+                resolve_wave_buckets,
+            )
 
+            # a padded wave brings the pad claim's key: into the point
+            # cache before any shape compiles, or the first production
+            # wave grows the staged table by its row and the gather
+            # compiles again at every pad shape, inside the first
+            # waves' deadlines (my chip run, PR 32: two waves of every
+            # boot served by the CPU)
+            self._neg_point(make_pad_claim()[2])
             # same resolution the service uses: explicit env ladder
             # wins, else this backend's own advertised shapes (the mesh
             # verifier's mesh-multiple buckets, ISSUE 7)

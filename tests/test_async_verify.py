@@ -4,6 +4,8 @@
 
 import asyncio
 
+import pytest
+
 from hotstuff_tpu.crypto import Digest, Signature, generate_keypair
 from hotstuff_tpu.crypto.async_service import (
     AsyncVerifyService,
@@ -629,14 +631,64 @@ def test_route_under_full_occupancy(monkeypatch):
     monkeypatch.setenv("HOTSTUFF_FORCE_DEVICE_ROUTE", "1")
     assert service._route_device(1) == "wait"
     monkeypatch.delenv("HOTSTUFF_FORCE_DEVICE_ROUTE")
-    # an OVERDUE in-flight wave routes everything to the CPU
+    # an OVERDUE in-flight wave routes everything to the CPU, once it
+    # has had its one yield of the loop to be delivered in
     service._inflight[1] = now - 1.0
     service._device_ewma_s = 0.001
+    assert service._route_device(256) == "wait"
+    assert service._route_device(256) == "cpu"
     assert service._route_device(256) == "cpu"
     service._inflight.clear()
     # below the cap the due probe finally fires on a losing EWMA
     service._device_ewma_s = 10.0
     assert service._route_device(1) == "probe"
+    service.close()
+
+
+@async_test
+@pytest.mark.parametrize("lands", [True, False], ids=["lands", "stalled"])
+async def test_overdue_wave_is_given_one_yield_before_the_cpu(
+    lands, monkeypatch
+):
+    """A wave whose deadline stamp has passed while the process stood
+    still (a pause of the host, a long pass of the loop) has most often
+    landed, its delivery queued behind the dispatcher: the next wave
+    waits 5 ms for that delivery and then takes the device.  A wave
+    that is really stuck sends the traffic round it as before."""
+    msg = b"g" * 32
+    pk, sig = _signed(61, msg)
+    claim = ("one", msg, pk.to_bytes(), sig.to_bytes())
+    # the cells' pin: the gate's wait must not read as a slow device
+    monkeypatch.setenv("HOTSTUFF_FORCE_DEVICE_ROUTE", "1")
+    host = _GatedDeviceHost(f"overdue-grace-{lands}")
+    service = AsyncVerifyService.for_backend(host)
+    first = asyncio.ensure_future(service.verify_claims([claim]))
+    await _until(lambda: len(host.gates) == 1)
+    # the clock ran past the first wave's stamp while nothing else did
+    (serial,) = service._inflight
+    service._inflight[serial] -= 10.0
+    if lands:
+        # the first wave lands inside the second's yield (the test
+        # waits for the delivery itself, not for 5 ms of a loaded host)
+        park = service._wait_for_slot
+
+        async def land_then_park():
+            host.gates[0].set()
+            await _until(lambda: serial not in service._inflight)
+            await park()
+
+        service._wait_for_slot = land_then_park
+    second = asyncio.ensure_future(service.verify_claims([claim]))
+    if lands:
+        await _until(lambda: len(host.gates) == 2)
+        host.gates[1].set()
+    assert await second == [True]
+    host.gates[0].set()
+    assert await first == [True]
+    assert service.pipeline_waits == 1
+    assert service.cpu_dispatches == (0 if lands else 1)
+    assert service.device_dispatches == (2 if lands else 1)
+    assert not service._graced
     service.close()
 
 

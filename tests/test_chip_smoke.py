@@ -146,6 +146,82 @@ def test_committee_judge_sees_conflicting_commits(tmp_path):
     assert any("safety violated" in b for b in bad)
 
 
+# ---- the wan50 stage's own judgement ----------------------------------------
+
+
+def _host_stats(second: int, **counters) -> str:
+    base = dict(
+        elapsed_s=second, ancestor_hits=10, ancestor_misses=0,
+        sync_requests=0, wan_frames=0, wan_delay_ms=0.0, wan_base_ms=0.0,
+    )  # fmt: skip
+    base.update(counters)
+    return (
+        f"{T}{second}.500Z [INFO] hotstuff_tpu.telemetry.hoststats "
+        "Host stats: " + " ".join(f"{k}={v}" for k, v in base.items())
+    )
+
+
+def _judge_wan(tmp_path, lines, placed=2, **kwargs):
+    placed = "\n".join(
+        f"{T}0.200Z [INFO] hotstuff_tpu.consensus.consensus WAN emulation "
+        f"active: region r{i}, position {i} of 2"
+        for i in range(placed)
+    )
+    logs_dir = _logs(tmp_path, extra="\n".join([placed, *lines]), **kwargs)
+    report = chip_smoke.scrape_committee(logs_dir)
+    report.update(chip_smoke.scrape_wan(logs_dir, 2))
+    return report, chip_smoke.judge_wan(report, 2)
+
+
+def test_wan_judge_passes_frames_held_for_their_links(tmp_path):
+    report, bad = _judge_wan(
+        tmp_path,
+        [
+            _host_stats(2, wan_frames=10, wan_delay_ms=700.0, wan_base_ms=1.0),
+            _host_stats(7, wan_frames=4000, wan_delay_ms=254000.0,
+                        wan_base_ms=252000.0, sync_requests=3),
+        ],
+        device_sigs=1000, cpu_sigs=0,
+    )  # fmt: skip
+    assert bad == []
+    # the last line's counters, over its frames
+    assert (report["wan_frames"], report["wan_delay_ms"],
+            report["wan_base_ms"]) == (4000, 63.5, 63.0)  # fmt: skip
+    assert report["sync_requests"] == 3 and report["wan_nodes_placed"] == 2
+    assert report["check_violations"] == []
+
+
+@pytest.mark.parametrize(
+    "lines, kwargs, reason",
+    [
+        # held 3% longer than the frames' links say
+        ([_host_stats(7, wan_frames=100, wan_delay_ms=6489.0,
+                      wan_base_ms=6300.0)], {}, "off by more than 2%"),
+        # the emulation was not on: a parent's line, or no spec
+        ([_host_stats(7)], {}, "no frame was held"),
+        ([], {}, "no frame was held"),
+        # one wave served by the CPU
+        ([_host_stats(7, wan_frames=100, wan_delay_ms=6300.0,
+                      wan_base_ms=6300.0)],
+         dict(device_sigs=999, cpu_sigs=1), "verified off the chip"),
+        # a node that committed nothing: the benchmark's own checker
+        ([_host_stats(7, wan_frames=100, wan_delay_ms=6300.0,
+                      wan_base_ms=6300.0)],
+         dict(nodes=("AAAAAAAA",)), "chipbench/check.py"),
+        # a node the spec did not place
+        ([_host_stats(7, wan_frames=100, wan_delay_ms=6300.0,
+                      wan_base_ms=6300.0)],
+         dict(placed=1), "1 of 2 nodes said where"),
+    ],
+    ids=["held-too-long", "counters-zero", "no-line", "cpu-wave", "checker",
+         "unplaced"],
+)  # fmt: skip
+def test_wan_judge_fails(tmp_path, lines, kwargs, reason):
+    kwargs = {"device_sigs": 1000, "cpu_sigs": 0, **kwargs}
+    _, bad = _judge_wan(tmp_path, lines, **kwargs)
+    assert any(reason in b for b in bad), bad
+
+
 def test_log_excerpt_prints_the_traceback(tmp_path):
     path = tmp_path / "node-0.log"
     path.write_text("a\nb\nTraceback (most recent call last):\n  File x\nErr\n")

@@ -42,7 +42,11 @@ SUBMITTERS = 8
 QC_VOTES = 5
 
 # a range of its own (tests/test_relay.py says why), above that file's
-_ports = itertools.count(34_000, 20)
+# and above the xdist workers' (tests/common.py: up to 31,999), and
+# below the ephemeral range (32,768 up): at 34,000 a listener met the
+# source port of a connection of the 50-node rehearsal running beside
+# it (tests/chipbench/test_wan50_cell.py opens some 5,000)
+_ports = itertools.count(32_000, 20)
 _kinds = itertools.count()
 
 _ref_memo: dict[tuple, bool] = {}

@@ -257,7 +257,8 @@ def test_host_stats_line_is_cumulative_but_for_the_lag_max():
         "elapsed_s", "cpu_user_s", "cpu_sys_s", "lag_samples",
         "lag_mean_ms", "lag_max_ms", "gc2", "gc2_s",
         "store_appends", "store_records",
-        "ancestor_hits", "ancestor_misses",
+        "ancestor_hits", "ancestor_misses", "sync_requests",
+        "wan_frames", "wan_delay_ms", "wan_base_ms",
     }
     assert float(first["lag_max_ms"]) == 10.0
     assert float(first["lag_mean_ms"]) == 6.0

@@ -130,6 +130,30 @@ def test_committee_precompute_cache(verifier):
     assert all(pk in verifier._point_cache for pk in pks)
 
 
+def test_warmup_leaves_the_key_table_at_its_production_size():
+    """A padded wave brings the pad claim's key.  The warm-up puts it in
+    the point cache before it compiles anything, so the staged table,
+    whose row count is a shape of the device-side gather, does not grow
+    under the first production wave and the gather does not compile
+    again at every pad shape inside that wave's deadline."""
+    from hotstuff_tpu.crypto.async_service import make_pad_claim
+    from hotstuff_tpu.tpu import ed25519 as device
+
+    v = BatchVerifier(min_device_batch=0)
+    committee = _sign_many(7, lambda i: b"a proposal")
+    v.precompute([pk for _, pk, _ in committee])
+    v.warmup(batch=8)
+    rows = len(v._tables[1])
+    compiled = device._gather_rows._cache_size()
+    _, digest, pk, sig = make_pad_claim()
+    msgs, pks, sigs = map(list, zip(*committee))
+    # the wave as the service pads it: real claims, then pad claims
+    out = v.verify_device(msgs + [digest] * 9, pks + [pk] * 9, sigs + [sig] * 9)
+    assert out.all()
+    assert len(v._tables[1]) == rows
+    assert device._gather_rows._cache_size() == compiled
+
+
 def test_pallas_dsm_parity_interpret():
     """The Pallas double-scalar-mult kernel (tpu/pallas_dsm.py) must agree
     with the XLA path bit-for-bit.  Runs in interpreter mode so the
