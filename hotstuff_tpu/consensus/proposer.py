@@ -34,25 +34,41 @@ for the home's turn, n/2 rounds on the median (6.3 s of a 6.8 s commit
 latency at 64 nodes).  So the home hands the digest (never the body) to
 the node that makes the next block:
 
-- *When and to whom.*  Once a round, right after the core processed the
-  round's block (a ``Cleanup`` carrying ``block``) or entered the round
-  by a TC (``tc_entered``), the home sends ONE best-effort
-  frame (``wire.encode_relay``) with the digests it admitted that are
-  still in ``pending`` to ``leader(round + 1)``, the node its vote goes
-  to.  Nothing is sent when this node leads one of the next two rounds
-  (it proposes them itself; only digests an orphaned block has carried
-  still go, or a leader whose blocks always orphan, the one before a
-  dead node, would propose and lose them every rotation),
-  relayed-in digests are never relayed on, and a digest goes out again
-  every round until a processed block carries it: a frame that arrives
-  after the target's Make costs one round, not a rotation.  The
-  decision reads the elector and the round alone; there is no option.
+- *When and to whom.*  At admission (ISSUE 34): as soon as a wake-up
+  of the producer queue has been drained into the buffer, the digests
+  it admitted go out in ONE best-effort frame a target
+  (``wire.encode_relay``, at most ``MAX_PRODUCER_BATCH`` digests) to
+  the makers of the next two blocks this node has not seen made:
+  ``leader(r + 1)`` and ``leader(r + 2)`` after it saw block ``r``
+  processed or made it, ``leader(R)`` and ``leader(R + 1)`` after a TC
+  seated round ``R`` (``unmade_round``).  The first copy puts the
+  digest into the very next block when it lands before that leader's
+  Make; the second is there a round ahead of the next one's, however
+  slow the link.  A deferred Make fires on the copy, so an idle
+  committee needs no timeout to commit a lone payload.  *Once a round
+  besides, the backstop:* right after the core processed the round's
+  block (a ``Cleanup`` carrying ``block``) or entered the round by a TC
+  (``tc_entered``), the home sends what it admitted and still holds in
+  ``pending`` to ``leader(round + 1)``, the node its vote goes to,
+  but not a digest that leader has been sent already (``relayed_to``:
+  digest -> the highest round whose leader has it): a digest costs two
+  frames at admission and one more a round only while it is still
+  uncarried after both.  In both cases nothing is sent when this node
+  leads one of the rounds in question (the pair at admission; the next
+  two, and the TC's own, once a round: it proposes them itself; only
+  digests an orphaned block has carried still go, or a leader whose
+  blocks always orphan, the one before a dead node, would propose and
+  lose them every rotation), never to this node itself, and
+  relayed-in digests are never relayed on.  The decision reads the
+  elector and the rounds alone; there is no option.
 - *Exactly once.*  A digest now sits in several buffers, so two rules
   keep it out of two blocks of one chain.  (1) Prune at processing:
   the payloads of every block the core processes, anyone's, leave
   ``pending`` and are tracked in ``inflight`` (round -> digests) until
   the chain commits through that round; a relayed digest that is in a
-  tracked block or recently committed is refused on arrival.  Orphans
+  tracked block or recently committed is refused on arrival (a copy
+  that lands after its leader's Make stays in that leader's buffer
+  until the node processes the block that carries it).  Orphans
   (the chain committed past the round without them) return to the
   FRONT of their HOME's buffer only, which relays them again.  (2) No
   payloads on an unseen parent: a ``Make`` whose ``qc.hash`` names a
@@ -61,7 +77,11 @@ the node that makes the next block:
   that block's message.  ``Make`` and the processed-block message
   travel on one queue, so their order is the core's order.
 - *What it tells.*  Span ``ingest.relay`` (one a frame sent, one a
-  frame received) and one ``Proposer stats:`` line a node every 5 s.
+  frame received) and one ``Proposer stats:`` line a node every 5 s;
+  on it ``early_frames=`` (relay frames sent at admission, of
+  ``relay_frames=``) and ``carried_next=`` (this node's clients'
+  payloads first carried by the block that was the next to be made
+  when they were admitted, of ``wait_n=``).
 """
 
 from __future__ import annotations
@@ -179,12 +199,28 @@ class Proposer:
         # buffer or they are lost for good.  Resolved by commit signals
         # (cleanup messages carrying committed_round).
         self.inflight: dict[Round, tuple] = {}
+        self._tracked_set: set | None = None  # ``_tracked()``, kept
         # Blocks whose processing this proposer has seen (its own count
         # from their making): what a Make may put payloads on.
         self.processed: OrderedDict[Digest, None] = OrderedDict()
-        # newest round relayed for / committed through
+        # newest round the once-a-round relay ran for / committed through
         self.relayed_round: Round = 0
         self.committed_round: Round = 0
+        # The first round whose block this proposer has not seen made:
+        # one past the newest block it made or saw processed, or the
+        # round a TC has just seated.  A digest admitted now goes to
+        # this round's leader and the next one's (module docstring).
+        self.unmade_round: Round = 1
+        # Home digests this wake-up of the producer queue has buffered:
+        # relayed as soon as the queue is drained.
+        self._admitted: list[Digest] = []
+        # Home digest -> the highest round whose leader was sent it (the
+        # round's relay does not send a digest to the same target
+        # twice); dropped where ``home`` drops it.
+        self.relayed_to: dict[Digest, Round] = {}
+        # Home digest -> ``unmade_round`` when it was admitted, until a
+        # block first carries it (``carried_next``).
+        self.admitted_before: dict[Digest, Round] = {}
         # The ``Proposer stats`` line's counters, all cumulative.
         self.relayed_digests = 0  # digests sent in relay frames
         self.relay_frames = 0  # relay frames sent
@@ -192,6 +228,8 @@ class Proposer:
         self.proposed_home = 0  # payloads proposed for our own clients
         self.wait_s_sum = 0.0  # admitted here -> in a processed block
         self.wait_count = 0
+        self.early_frames = 0  # relay frames sent at admission
+        self.carried_next = 0  # first carried by the block made next
         self._next_stats = 0.0
         # Recently COMMITTED digests (bounded LRU): orphan recovery must
         # not re-buffer a payload that committed in an EARLIER walk via
@@ -271,6 +309,8 @@ class Proposer:
         now = default_clock().monotonic()
         if home:
             self.home[digest] = now
+            self.admitted_before[digest] = self.unmade_round
+            self._admitted.append(digest)
         self.pending[digest] = now
 
     def _buffer_item(self, item) -> None:
@@ -342,6 +382,7 @@ class Proposer:
             # are in flight — an empty block advances the 2-chain so they
             # commit now rather than on the producer's next burst.
             self.last_made_round = round_
+            self.unmade_round = max(self.unmade_round, round_ + 1)
             take = min(len(self.pending), MAX_BLOCK_PAYLOADS) if parent_seen else 0
             popped = [self.pending.popitem(last=False) for _ in range(take)]
             payloads = tuple(d for d, _ in popped)
@@ -351,7 +392,7 @@ class Proposer:
                     for _, arrived in popped:
                         if arrived:  # re-buffered orphans may carry None
                             self._payload_wait.observe(now - arrived)
-                ours = sum(self._carried(d, now) for d in payloads)
+                ours = sum(self._carried(d, now, round_) for d in payloads)
                 self.proposed_home += ours
                 self.proposed_relayed += take - ours
 
@@ -492,10 +533,11 @@ class Proposer:
     def _parent_seen(self, qc: QC) -> bool:
         return qc.is_genesis() or qc.hash in self.processed
 
-    def _carried(self, digest: Digest, now: float) -> bool:
-        """A block this proposer made or saw processed carries
-        ``digest``.  True if this node is its home; the first time,
-        that ends its wait (admitted here -> in a processed block)."""
+    def _carried(self, digest: Digest, now: float, round_: Round) -> bool:
+        """Block ``round_``, which this proposer made or saw processed,
+        carries ``digest``.  True if this node is its home; the first
+        time, that ends its wait (admitted here -> in a processed
+        block)."""
         admitted = self.home.get(digest)
         if admitted is None:
             return False
@@ -503,6 +545,8 @@ class Proposer:
             self.wait_s_sum += now - admitted
             self.wait_count += 1
             self.home[digest] = 0.0
+            if self.admitted_before.pop(digest, None) == round_:
+                self.carried_next += 1
         else:
             self.orphans.pop(digest, None)
         return True
@@ -527,15 +571,24 @@ class Proposer:
             self.inflight[block.round] = (
                 self.inflight.get(block.round, ()) + block.payloads
             )
+            self._tracked_set = None
             while len(self.inflight) > MAX_INFLIGHT:
                 self._requeue_oldest_inflight()
         return True
 
     def _tracked(self) -> set:
         """Every digest a tracked block carries: a few rounds' worth,
-        gathered when a relay frame arrives or a block orphans, not
-        kept up on every node for every block."""
-        return set().union(*self.inflight.values())
+        gathered when a relay frame arrives or a block orphans and kept
+        until ``inflight`` changes (a leader takes some seventy frames
+        between two blocks), not kept up on every node for every
+        block."""
+        if self._tracked_set is None:
+            self._tracked_set = set().union(*self.inflight.values())
+        return self._tracked_set
+
+    def _untrack(self, round_: Round) -> tuple:
+        self._tracked_set = None
+        return self.inflight.pop(round_)
 
     def _on_processed(self, block: Block) -> None:
         """Prune at processing: the core processed ``block`` (anyone's),
@@ -555,48 +608,78 @@ class Proposer:
             if ours:
                 now = default_clock().monotonic()
                 for d in ours:
-                    self._carried(d, now)
+                    self._carried(d, now, block.round)
 
     async def _relay(self, round_: Round, made: bool) -> None:
-        """Hand this node's own clients' digests to the node that makes
-        block ``round_ + 1`` (module docstring).  ``made``: block
-        ``round_`` exists already (the call follows its processing);
-        after a TC it does not, and its leader's Make is still behind
-        this message in the queue."""
+        """The round's relay, the backstop of the one at admission: hand
+        this node's own clients' digests that are still buffered to the
+        node that makes block ``round_ + 1`` (module docstring).
+        ``made``: block ``round_`` exists already (the call follows its
+        processing); after a TC it does not, and its leader's Make is
+        still behind this message in the queue."""
+        unmade = round_ + made
+        self.unmade_round = max(self.unmade_round, unmade)
         if self.leader_elector is None or self.relay_network is None:
             return
         if round_ <= self.relayed_round:
             return
         self.relayed_round = round_
-        if not self.home:
-            return
-        leader = self.leader_elector.get_leader
-        ours = self.home
-        for ahead in range(1 if made else 0, 3):
-            if leader(round_ + ahead) == self.name:
-                # we lead soon: these ride in our own block, unless a
-                # block of ours (or anyone's) has orphaned them before
-                ours = self.orphans
-                break
-        pending = self.pending
-        digests = []
-        for d in ours:
-            if d in pending:
-                digests.append(d)
-                if len(digests) == MAX_PRODUCER_BATCH:
-                    break
-        if not digests:
-            return
-        with _spans.span("ingest.relay", node=self._node, round=round_):
-            address = self.committee.for_round(round_ + 1).address(
-                leader(round_ + 1)
+        if self.home:
+            await self._send_relay(
+                self.home, range(unmade, round_ + 3), (round_ + 1,)
             )
-            if address is None:
-                return
-            frame = encode_relay(digests)
-            self.relay_frames += 1
-            self.relayed_digests += len(digests)
-        await self.relay_network.send(address, frame)
+
+    async def _relay_admitted(self) -> None:
+        """The relay at admission: what this wake-up of the producer
+        queue admitted goes at once to the makers of the next two blocks
+        this node has not seen made (module docstring)."""
+        admitted = self._admitted
+        if not admitted:
+            return
+        self._admitted = []
+        if self.leader_elector is None or self.relay_network is None:
+            return
+        pair = range(self.unmade_round, self.unmade_round + 2)
+        await self._send_relay(admitted, pair, pair, early=True)
+
+    async def _send_relay(
+        self, ours, soon: range, targets, early: bool = False
+    ) -> None:
+        """One best-effort frame to the leader of each round in
+        ``targets`` with the digests of ``ours`` that are still buffered
+        and that this leader has not been sent yet.  When this node
+        leads one of the rounds ``soon`` they ride in its own block,
+        and only what an orphaned block has carried goes."""
+        leader = self.leader_elector.get_leader
+        if any(leader(r) == self.name for r in soon):
+            ours = self.orphans
+        if not ours:
+            return
+        pending, relayed_to = self.pending, self.relayed_to
+        for target in targets:
+            if leader(target) == self.name:
+                continue
+            digests = []
+            for d in ours:
+                if d in pending and relayed_to.get(d, 0) < target:
+                    digests.append(d)
+                    if len(digests) == MAX_PRODUCER_BATCH:
+                        break
+            if not digests:
+                continue
+            with _spans.span("ingest.relay", node=self._node, round=target):
+                address = self.committee.for_round(target).address(
+                    leader(target)
+                )
+                if address is None:
+                    continue
+                frame = encode_relay(digests)
+                self.relay_frames += 1
+                self.early_frames += early
+                self.relayed_digests += len(digests)
+                for d in digests:
+                    relayed_to[d] = target
+            await self.relay_network.send(address, frame)
 
     def _log_stats(self) -> None:
         now = default_clock().monotonic()
@@ -608,13 +691,16 @@ class Proposer:
         # reader takes last less first.
         self.log.info(
             "Proposer stats: relayed=%d relay_frames=%d proposed_relayed=%d "
-            "proposed_home=%d wait_ms_sum=%.1f wait_n=%d",
+            "proposed_home=%d wait_ms_sum=%.1f wait_n=%d early_frames=%d "
+            "carried_next=%d",
             self.relayed_digests,
             self.relay_frames,
             self.proposed_relayed,
             self.proposed_home,
             self.wait_s_sum * 1e3,
             self.wait_count,
+            self.early_frames,
+            self.carried_next,
         )
 
     def _requeue_orphans(
@@ -653,9 +739,7 @@ class Proposer:
         payloads survive the stall; the committed_seen/pending filters
         keep the duplicate window bounded (see MAX_INFLIGHT note)."""
         round_ = min(self.inflight)
-        self._requeue_orphans(
-            round_, self.inflight.pop(round_), note="unresolved"
-        )
+        self._requeue_orphans(round_, self._untrack(round_), note="unresolved")
 
     def _resolve_inflight(self, message: ProposerMessage) -> None:
         """Orphan recovery: once the chain is committed through round R,
@@ -672,7 +756,7 @@ class Proposer:
             reverse=True,  # re-insert newest first so oldest ends up in front
         ):
             self._requeue_orphans(
-                round_, self.inflight.pop(round_), committed=message.payloads
+                round_, self._untrack(round_), committed=message.payloads
             )
 
     @staticmethod
@@ -699,6 +783,7 @@ class Proposer:
                         while not self.rx_producer.empty():
                             self._buffer_item(self.rx_producer.get_nowait())
                     prod_task = asyncio.ensure_future(self.rx_producer.get())
+                    await self._relay_admitted()
                     make = self.deferred
                     if (
                         make is not None
@@ -759,6 +844,8 @@ class Proposer:
                                 self.committed_seen[digest] = None
                                 if home and home.pop(digest, None) is not None:
                                     self.orphans.pop(digest, None)
+                                    self.relayed_to.pop(digest, None)
+                                    self.admitted_before.pop(digest, None)
                             while len(self.committed_seen) > SEEN_CAP:
                                 self.committed_seen.popitem(last=False)
                             self._resolve_inflight(message)

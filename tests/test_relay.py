@@ -187,17 +187,23 @@ async def test_a_bare_proposer_relays_nothing():
 
 @async_test
 async def test_relay_follows_the_cores_messages_in_their_order():
-    """Through ``run()``: a round advance by a QC sends nothing (the
-    round's block comes right behind it and is pruned first); the
+    """Through ``run()``: an admission sends at once, one frame a
+    target for the whole burst; a round advance by a QC sends nothing
+    (the round's block comes right behind it and is pruned first); the
     processed block does; an advance by a TC does."""
-    proposer, outbox, _ = bare_proposer(3)
+    proposer, outbox, com = bare_proposer(3)
     mine = digests(3)
     task = proposer.spawn()
     for d in mine:
         await proposer.rx_producer.put(d)
     await proposer.rx_message.put(ProposerMessage.cleanup([4]))
     await asyncio.sleep(0.05)
-    assert outbox.sent == []
+    # no block seen yet: blocks 1 and 2 are the next two to be made
+    assert outbox.sent == [
+        (com.address(keys(N)[r][0]), tuple(mine)) for r in (1, 2)
+    ]
+    assert (proposer.relay_frames, proposer.early_frames) == (2, 2)
+    del outbox.sent[:]
     await proposer.rx_message.put(
         ProposerMessage.cleanup([3, 4, 5], block=block_of(5, mine[:1]))
     )
@@ -213,6 +219,353 @@ async def test_relay_follows_the_cores_messages_in_their_order():
     await asyncio.sleep(0.05)
     assert [sent for _, sent in outbox.sent] == [tuple(mine[1:])] * 2
     assert proposer.relayed_round == 6
+    assert proposer.early_frames == 2
+    task.cancel()
+    proposer.shutdown()
+
+
+# ---- at admission: to the makers of the next two blocks (ISSUE 34) ------
+
+
+async def admit(proposer, items):
+    """One wake-up of the producer queue, as ``run()`` makes it: the
+    burst is buffered, then what it admitted is relayed."""
+    for item in items:
+        proposer._buffer_item(item)
+    await proposer._relay_admitted()
+
+
+def leaders_sent(outbox, com):
+    """The rounds' leaders (their index mod N) each frame went to."""
+    index = {com.address(keys(N)[i][0]): i for i in range(N)}
+    return [index[address] for address, _ in outbox.sent]
+
+
+@pytest.mark.parametrize(
+    "seen, by_tc, targets",
+    [
+        # node 3 of 7 leads rounds 3, 10, 17, ...; ``seen``: the newest
+        # block it saw processed, or the round a TC has seated
+        (5, False, [6, 7]),  # blocks 6 and 7 are the next two to be made
+        (6, False, [7, 8]),
+        (7, False, [8, 9]),  # it leads round 10, three away
+        (8, False, []),  # 9 and 10: it leads the second
+        (9, False, []),  # block 9 is made: it makes the very next block
+        (10, False, [11, 12]),  # its own block is made: what comes now goes on
+        # after a TC block ``seen`` itself is still to be made: the pair
+        # is counted from it
+        (6, True, [6, 7]),
+        (8, True, [8, 9]),
+        (9, True, []),  # 9 and 10: it leads the second
+        (10, True, []),  # its own Make is behind the TC
+        (11, True, [11, 12]),
+    ],
+)
+@async_test
+async def test_an_admitted_digest_goes_at_once_to_the_makers_of_the_next_two_blocks(
+    seen, by_tc, targets
+):
+    proposer, outbox, com = bare_proposer(3)
+    await proposer._relay(seen, made=not by_tc)  # nothing admitted yet
+    assert outbox.sent == []
+    mine = digests(3)
+    await admit(proposer, mine)
+    assert leaders_sent(outbox, com) == [t % N for t in targets]
+    assert all(sent == tuple(mine) for _, sent in outbox.sent)
+    assert proposer.early_frames == proposer.relay_frames == len(targets)
+    assert proposer.relayed_digests == 3 * len(targets)
+    # a wake-up that admits nothing sends nothing
+    await admit(proposer, [])
+    await admit(proposer, [mine[0]])  # a duplicate of a buffered payload
+    assert proposer.relay_frames == len(targets)
+    proposer.shutdown()
+
+
+@async_test
+async def test_only_orphans_go_early_when_this_node_leads_soon():
+    proposer, outbox, com = bare_proposer(3)
+    mine = digests(3)
+    await admit(proposer, mine[:2])
+    assert leaders_sent(outbox, com) == [1, 2]  # no block seen: rounds 1, 2
+    del outbox.sent[:]
+    proposer._on_processed(block_of(2, mine[:1]))  # block 2 carries the first
+    await proposer._relay(8, made=True)
+    # it leads round 10, the second of the pair 9, 10: the other digest
+    # waits for its own block, and nothing is an orphan yet
+    assert outbox.sent == []
+    # the chain commits through round 5 without block 2
+    proposer._resolve_inflight(
+        ProposerMessage.cleanup([], payloads=set(), committed_round=5)
+    )
+    assert list(proposer.pending) == mine[:2]
+    await admit(proposer, mine[2:])
+    # the new digest rides in block 10; what block 2 carried and lost
+    # goes to the other maker of the pair, and never to this node itself
+    assert outbox.sent == [(com.address(keys(N)[9 % N][0]), (mine[0],))]
+    assert proposer.early_frames == 3
+    proposer.shutdown()
+
+
+@async_test
+async def test_a_relayed_in_digest_is_never_relayed_on_at_admission():
+    proposer, outbox, _ = bare_proposer(3)
+    mine, theirs = digests(2), digests(4, salt=1)
+    await admit(proposer, [tuple(theirs)])  # a peer's relay frame alone
+    assert outbox.sent == [] and proposer.early_frames == 0
+    await admit(proposer, [tuple(theirs[:2]), mine[0], tuple(theirs[2:]), mine[1]])
+    assert [sent for _, sent in outbox.sent] == [tuple(mine)] * 2
+    proposer.shutdown()
+
+
+@async_test
+async def test_a_burst_is_one_frame_a_target_and_capped():
+    proposer, outbox, _ = bare_proposer(3)
+    await proposer._relay(4, made=True)
+    mine = digests(MAX_PRODUCER_BATCH + 40)
+    await admit(proposer, mine)
+    assert [sent for _, sent in outbox.sent] == [
+        tuple(mine[:MAX_PRODUCER_BATCH])
+    ] * 2
+    # the rest goes with the round's relay, and the first 512 only to
+    # a leader that has not had them
+    await proposer._relay(5, made=True)  # to leader(6): had the 512
+    assert [sent for _, sent in outbox.sent[2:]] == [
+        tuple(mine[MAX_PRODUCER_BATCH:])
+    ]
+    proposer.shutdown()
+
+
+@async_test
+async def test_the_rounds_relay_skips_what_its_target_has_and_goes_on_to_the_next():
+    proposer, outbox, com = bare_proposer(3)
+    await proposer._relay(4, made=True)
+    early, late = digests(2), digests(2, salt=1)
+    await admit(proposer, early)  # to leader(5) and leader(6)
+    assert leaders_sent(outbox, com) == [5, 6]
+    del outbox.sent[:]
+    # block 5 was made before the copy landed and carries neither
+    proposer._on_processed(block_of(5, []))
+    await proposer._relay(5, made=True)
+    assert outbox.sent == []  # leader(6) has them
+    await admit(proposer, late)  # to leader(6) and leader(7 = 0 mod 7)
+    assert leaders_sent(outbox, com) == [6, 0]
+    del outbox.sent[:]
+    # block 6 carries the early two alone (the late copy missed its make)
+    proposer._on_processed(block_of(6, early))
+    await proposer._relay(6, made=True)
+    assert outbox.sent == []  # leader(7) has the late two, early are carried
+    proposer._on_processed(block_of(7, []))
+    await proposer._relay(7, made=True)
+    # still pending after both copies: on to the next leader, once
+    assert outbox.sent == [(com.address(keys(N)[8 % N][0]), tuple(late))]
+    assert (proposer.early_frames, proposer.relay_frames) == (4, 5)
+    # what committed is forgotten with its home entry
+    task = proposer.spawn()
+    await proposer.rx_message.put(
+        ProposerMessage.cleanup([], payloads=set(early), committed_round=6)
+    )
+    await asyncio.sleep(0.05)
+    assert set(proposer.relayed_to) == set(late) == set(proposer.home)
+    assert not proposer.admitted_before.keys() & set(early)
+    task.cancel()
+    proposer.shutdown()
+
+
+class Acked:
+    """Stands in for the reliable sender: every peer has ACKed at once,
+    so a bare proposer's ``run()`` comes back from a make."""
+
+    async def broadcast(self, addresses, data: bytes):
+        done = asyncio.get_running_loop().create_future()
+        done.set_result(b"Ack")
+        return [done] * len(addresses)
+
+    def close(self) -> None:
+        pass
+
+
+class Wire:
+    """A best-effort network of bare proposers: a relay frame lands in
+    the producer queue of the proposer at its address, as the receiver
+    hands it over, unless the test holds that address's frames back."""
+
+    def __init__(self):
+        self.queues: dict = {}
+        self.held: dict = {}
+
+    def attach(self, proposer, com):
+        proposer.relay_network = self
+        self.queues[com.address(proposer.name)] = proposer.rx_producer
+
+    def hold(self, address):
+        self.held[address] = []
+
+    def release(self, address):
+        for digests_ in self.held.pop(address):
+            self.queues[address].put_nowait(digests_)
+
+    async def send(self, address, data: bytes) -> None:
+        tag, digests_ = decode_message(data)
+        assert tag == TAG_RELAY
+        if address in self.held:
+            self.held[address].append(digests_)
+        elif address in self.queues:
+            self.queues[address].put_nowait(digests_)
+
+
+def wired(*indices):
+    """Bare proposers of one committee of seven on one ``Wire``."""
+    com = committee(fresh_base_port(), N)
+    wire = Wire()
+    out = []
+    for idx in indices:
+        name, secret = keys(N)[idx]
+        proposer = Proposer(
+            name, com, SignatureService(secret),
+            rx_producer=asyncio.Queue(), rx_message=asyncio.Queue(),
+            tx_loopback=asyncio.Queue(),
+            network=Acked(),
+            leader_elector=LeaderElector(com),
+        )
+        wire.attach(proposer, com)
+        out.append(proposer)
+    return wire, com, out
+
+
+async def make_on(proposer, parent: Block | None, round_: int) -> Block:
+    """The core's messages for a leader: ``parent`` processed, then the
+    Make on its QC; returns the block the proposer hands back."""
+    qc = QC.genesis()
+    if parent is not None:
+        qc = QC(hash=parent.digest(), round=parent.round)
+        await proposer.rx_message.put(
+            ProposerMessage.cleanup([parent.round], block=parent)
+        )
+    await proposer.rx_message.put(ProposerMessage.make(round_, qc, None))
+    return await asyncio.wait_for(proposer.tx_loopback.get(), 2.0)
+
+
+@async_test
+async def test_a_digest_admitted_mid_round_rides_in_the_very_next_block():
+    """Node 3 saw block 4 processed; a client's digest comes in while
+    block 5 is still to be made: it is in leader(5)'s buffer before that
+    leader's Make, and block 5 carries it, where the round's relay
+    would have sent it to leader(6) after block 5."""
+    wire, com, (home, five, six) = wired(3, 5, 6)
+    tasks = [p.spawn() for p in (home, five, six)]
+    four = block_of(4, [])
+    await home.rx_message.put(ProposerMessage.cleanup([4], block=four))
+    await asyncio.sleep(0.02)
+    mine = digests(2)
+    for d in mine:
+        await home.rx_producer.put(d)
+    await asyncio.sleep(0.05)
+    assert list(five.pending) == mine and list(six.pending) == mine
+    assert five.home == {} and home.early_frames == 2
+    made = await make_on(five, four, 5)
+    assert (made.round, made.payloads) == (5, tuple(mine))
+    assert five.proposed_relayed == 2
+    # every node processes block 5: the copy at leader(6) is pruned, and
+    # its block on that chain carries nothing twice
+    await home.rx_message.put(ProposerMessage.cleanup([5], block=made))
+    await six.rx_message.put(ProposerMessage.cleanup([5], block=made))
+    await six.rx_message.put(
+        ProposerMessage.make(
+            6, QC(hash=made.digest(), round=5), None, allow_empty=True
+        )
+    )
+    empty = await asyncio.wait_for(six.tx_loopback.get(), 2.0)
+    assert (empty.round, empty.payloads) == (6, ())
+    await asyncio.sleep(0.02)
+    # the first carrying block is the one made right after the admission
+    assert (home.wait_count, home.carried_next) == (2, 2)
+    assert home.relay_frames == 2  # the round's relay had nothing left
+    for t in tasks:
+        t.cancel()
+    for p in (home, five, six):
+        p.shutdown()
+
+
+@async_test
+async def test_a_copy_that_lands_after_the_make_is_pruned_and_never_proposed_twice():
+    """Two leaders hold one digest and both make blocks on one chain:
+    the copy for leader(5) lands after its Make, so block 6 carries the
+    digest; leader(5) prunes its late copy when it processes block 6
+    and does not propose it when it leads again."""
+    wire, com, (home, five, six) = wired(3, 5, 6)
+    tasks = [p.spawn() for p in (home, five, six)]
+    four = block_of(4, [])
+    await home.rx_message.put(ProposerMessage.cleanup([4], block=four))
+    await asyncio.sleep(0.02)
+    wire.hold(com.address(five.name))
+    mine = digests(1)
+    await home.rx_producer.put(mine[0])
+    await asyncio.sleep(0.05)
+    # leader(5) makes its block without it (another payload fires it)
+    other = digests(1, salt=3)
+    await five.rx_producer.put(tuple(other))
+    made5 = await make_on(five, four, 5)
+    assert made5.payloads == tuple(other)
+    wire.release(com.address(five.name))  # the late copy lands
+    await asyncio.sleep(0.05)
+    assert list(five.pending) == mine
+    made6 = await make_on(six, made5, 6)
+    assert made6.payloads == tuple(mine)
+    for p in (home, five):
+        await p.rx_message.put(ProposerMessage.cleanup([5], block=made5))
+        await p.rx_message.put(ProposerMessage.cleanup([6], block=made6))
+    await asyncio.sleep(0.05)
+    assert not five.pending and not home.pending and not six.pending
+    # leader(5) leads round 12 on that chain: nothing of it comes back
+    await five.rx_message.put(
+        ProposerMessage.make(
+            12, QC(hash=made6.digest(), round=6), None, allow_empty=True
+        )
+    )
+    made12 = await asyncio.wait_for(five.tx_loopback.get(), 2.0)
+    assert made12.payloads == ()
+    # a copy of the frame that arrives now is refused: block 6 is tracked
+    await five.rx_producer.put(tuple(mine))
+    await asyncio.sleep(0.05)
+    assert not five.pending
+    # carried by block 6, the second block after its admission: not "next"
+    assert (home.wait_count, home.carried_next) == (1, 0)
+    for t in tasks:
+        t.cancel()
+    for p in (home, five, six):
+        p.shutdown()
+
+
+@async_test
+async def test_carried_next_and_early_frames_count_what_they_say(caplog):
+    proposer, outbox, _ = bare_proposer(3)
+    await proposer._relay(4, made=True)
+    first, second, own = digests(2), digests(2, salt=1), digests(1, salt=2)
+    await admit(proposer, first)  # block 5 is the next to be made
+    proposer._on_processed(block_of(5, first[:1]))  # carries one: next
+    await proposer._relay(5, made=True)
+    await admit(proposer, second)  # block 6 is the next
+    proposer._on_processed(block_of(6, first[1:] + second[:1]))
+    # first[1] was admitted before block 5: 6 is not its next block
+    assert (proposer.wait_count, proposer.carried_next) == (3, 2)
+    proposer._on_processed(block_of(8, second[1:]))  # two blocks late
+    assert (proposer.wait_count, proposer.carried_next) == (4, 2)
+    # what it proposes itself in the block it makes next counts too
+    await proposer._relay(9, made=True)
+    await admit(proposer, own)  # it leads round 10: nothing goes out
+    task = asyncio.ensure_future(proposer._make_block(10, QC.genesis(), None))
+    made = await asyncio.wait_for(proposer.tx_loopback.get(), 2.0)
+    assert made.payloads == tuple(own)
+    assert (proposer.wait_count, proposer.carried_next) == (5, 3)
+    # two admissions with two targets each; the rounds' relays sent the rest
+    assert proposer.early_frames == 4
+    assert proposer.relay_frames == len(outbox.sent)
+    assert not proposer.admitted_before
+    with caplog.at_level(logging.INFO, logger="hotstuff_tpu.consensus.proposer"):
+        proposer._log_stats()
+    line = caplog.messages[-1]
+    assert line.endswith("wait_n=5 early_frames=4 carried_next=3")
+    assert line.startswith("Proposer stats: relayed=")
     task.cancel()
     proposer.shutdown()
 
@@ -598,3 +951,34 @@ async def test_relayed_digests_of_orphaned_blocks_commit_once_afterwards(
     assert relayed_orphans, "no orphaned block carried a relayed digest"
     assert created.rebuffered >= 1
     assert all(times[pid] == 1 for pid in relayed_orphans)
+
+
+@async_test
+async def test_an_idle_committee_commits_a_lone_payload_without_a_timeout(
+    tmp_path,
+):
+    """No block is being made: leader(1) holds a deferred Make.  A
+    payload handed to node 4 reaches that leader at once and fires it;
+    the round's relay would have waited for a processed block or a TC,
+    one view-change timeout (20 s here) away."""
+    nodes = await _committee_of_seven(tmp_path, range(N), timeout_delay=20_000)
+    loop = asyncio.get_running_loop()
+    try:
+        await asyncio.sleep(0.3)  # every node is in round 1, nothing to do
+        digest = Digest.of(b"relay|idle|0")
+        began = loop.time()
+        await nodes[4][0].tx_producer.put(digest)
+        commits = nodes[0][1]
+        while True:
+            block = await asyncio.wait_for(commits.get(), 10.0)
+            if digest in block.payloads:
+                break
+        assert loop.time() - began < 10.0
+        assert block.round == 1 and block.author == keys(N)[1][0]
+        home = nodes[4][0].proposer
+        assert (home.early_frames, home.carried_next) == (2, 1)
+    finally:
+        for stack, _, _ in nodes:
+            await stack.shutdown()
+        for _, _, store in nodes:
+            store.close()

@@ -26,7 +26,9 @@ import pytest
 from benchmark.invariants import check_safety
 from hotstuff_tpu.consensus import Committee
 from hotstuff_tpu.consensus import synchronizer as synchronizer_module
+from hotstuff_tpu.consensus.proposer import Proposer
 from hotstuff_tpu.consensus.synchronizer import ANCESTOR_COUNTS
+from hotstuff_tpu.crypto import Digest
 from hotstuff_tpu.network import ReliableSender, SimpleSender
 from hotstuff_tpu.network.wan import (
     DEFAULT_REGIONS,
@@ -499,3 +501,128 @@ def test_boot_refuses_a_spec_under_the_native_transport(tmp_path):
     assert "Cannot boot: HOTSTUFF_WAN_SPEC needs the asyncio transport" in out
     assert "Traceback" not in out
     assert "WAN emulation active" not in out
+
+
+# ---- (e) the relay at admission over slow links (ISSUE 34) -------------------
+
+RE_CREATED_PAYLOADS = re.compile(r"Created block (\d+) \(payloads (\S*)\)")
+
+
+class _SingleHomed(SimCluster):
+    """The sim cluster with a client's feed: payload ``k`` goes to ONE
+    node, ``7k mod n``, and one probe goes to a eu-north-1 node at the
+    instant an ap-southeast-2 leader makes its block."""
+
+    probe = Digest.of(b"wan|probe")
+
+    def __init__(self, *args):
+        super().__init__(*args)
+        self.probed: tuple[int, int] | None = None  # (round made, home)
+
+    async def _feed(self) -> None:
+        k = 0
+        while True:
+            payload = Digest.of(f"wan|{self.seed}|{k}".encode())
+            self.nodes[(7 * k) % self.n].stack.tx_producer.put_nowait(payload)
+            k += 1
+            await asyncio.sleep(1.0 / self.rate)
+
+    def on_created(self, round_: int) -> None:
+        if self.probed is None and round_ >= 20 and round_ % 5 == 2:
+            # 24 places on in the rotation: region (2 + 24) mod 5 = 1,
+            # eu-north-1, and half a rotation from leading
+            home = (round_ + 24) % self.n
+            self.probed = (round_, home)
+            self.nodes[home].stack.tx_producer.put_nowait(self.probe)
+
+
+def _probe_run(tmp_path, monkeypatch, n=50, duration_s=6.0):
+    """One virtual-time run of the single-homed committee; returns the
+    probe's (round made at its admission, home), every ``Created``
+    block as (virtual time, round, payload ids) and the commits."""
+    tmp_path.mkdir()
+    path = tmp_path / "wan.json"
+    path.write_text(json.dumps(list_spec()))
+    monkeypatch.setenv("HOTSTUFF_WAN_SPEC", str(path))
+    monkeypatch.setenv("HOTSTUFF_SIM_RATE", "100")
+    schedule = {"seed": 34, "nodes": n, "duration_s": duration_s, "events": []}
+    box = {}
+
+    class OnCreated(logging.Handler):
+        def emit(self, record):
+            m = RE_CREATED.match(record.getMessage())
+            if m is not None:
+                box["cluster"].on_created(int(m.group(1)))
+
+    hook = OnCreated(logging.INFO)
+    proposer_log = logging.getLogger("hotstuff_tpu.consensus.proposer")
+
+    async def main(clock, net):
+        box["cluster"] = _SingleHomed(schedule, str(tmp_path), net)
+        proposer_log.addHandler(hook)
+        try:
+            await box["cluster"].run()
+        finally:
+            proposer_log.removeHandler(hook)
+
+    _, records = in_virtual_time(main)
+    created, commits = [], {}
+    for at, name, msg in records:
+        if (m := RE_CREATED_PAYLOADS.match(msg)):
+            ids = m.group(2).split(",") if m.group(2) else []
+            created.append((at, int(m.group(1)), ids))
+        elif (m := RE_COMMITTED.match(msg)) and ".core." in name:
+            commits.setdefault(name.rsplit(".", 1)[1], []).append(
+                (at, int(m.group(1)), m.group(2))
+            )
+    assert not [msg for _, _, msg in records if "Timeout reached" in msg]
+    return box["cluster"].probed, created, commits
+
+
+def test_a_far_homes_digest_rides_the_block_after_next_not_the_one_after(
+    tmp_path, monkeypatch
+):
+    """Fifty nodes, five regions, no jitter.  An ap-southeast-2 leader
+    makes block ``R``; at that instant a eu-north-1 node, 140 ms away,
+    admits a digest.  It holds block ``R`` 140 ms later and its vote and
+    the round's relay reach ``leader(R + 1)`` in us-west-1 80 ms after
+    that: 220 ms after the make, where the 33rd vote takes 130.  So the
+    round's relay alone misses block ``R + 1``; the copy sent at
+    admission is there after 80 ms and rides in it."""
+    spec, n = list_spec(), 50
+    probe = str(_SingleHomed.probe)
+
+    def carrying(created):
+        rounds = [r for _, r, ids in created if probe in ids]
+        assert len(rounds) == 1, rounds
+        return rounds[0]
+
+    probed, created, commits = _probe_run(tmp_path / "a", monkeypatch)
+    made, home = probed
+    assert made % 5 == 2 and home % 5 == 1
+    # the analytic reference: what the matrix gives each path
+    tick_ms = (one_way_s(spec, made % n, home) + one_way_s(spec, home, (made + 1) % n)) * 1e3
+    assert tick_ms == pytest.approx(220.0) and tick_ms > 1e3 * qc_after_proposal_s(
+        spec, n, made % n
+    ) == pytest.approx(130.0)
+    assert one_way_s(spec, home, (made + 1) % n) * 1e3 == pytest.approx(80.0)
+    assert carrying(created) == made + 1
+    # no payload twice, in any block made or on any node's chain
+    proposed = [pid for _, _, ids in created for pid in ids]
+    assert len(proposed) == len(set(proposed)) > 300
+    assert len(commits) == n
+    ok, violations = check_safety(commits)
+    assert ok, violations
+
+    # the same seed makes the same blocks at the same virtual instants
+    again = _probe_run(tmp_path / "b", monkeypatch)
+    assert again[0] == probed and again[1] == created
+
+    # the round's relay alone (ISSUE 27's rule) puts it a block later
+    async def nothing(self):
+        self._admitted.clear()
+
+    monkeypatch.setattr(Proposer, "_relay_admitted", nothing)
+    probed_27, created_27, _ = _probe_run(tmp_path / "c", monkeypatch)
+    assert probed_27[0] % 5 == 2 and probed_27[1] % 5 == 1
+    assert carrying(created_27) >= probed_27[0] + 2
