@@ -31,10 +31,13 @@ def write_json(path: str, data) -> None:
 
 def write_committee(run_dir: str, config: dict, seed: int) -> list[str]:
     """Keys from the seed, committee and parameters as
-    ``benchmark/local.py`` writes them; returns the key files."""
+    ``benchmark/local.py`` writes them, for either scheme: a BLS key
+    carries its proof of possession, in its key file and in the
+    committee (``Consensus.spawn`` refuses a BLS committee without).
+    Returns the key files."""
     from benchmark.local import safe_base_port
     from hotstuff_tpu.consensus import Committee, Parameters
-    from hotstuff_tpu.crypto.scheme import keygen_deterministic
+    from hotstuff_tpu.crypto.scheme import bls_pop, keygen_deterministic
     from hotstuff_tpu.node.config import (
         Secret,
         write_committee as write_committee_file,
@@ -43,10 +46,11 @@ def write_committee(run_dir: str, config: dict, seed: int) -> list[str]:
 
     scheme = config["scheme"]
     key_seed = hashlib.sha256(f"chipbench keys {seed}".encode()).digest()
-    secrets = [
-        Secret(*keygen_deterministic(scheme, key_seed, i), scheme)
-        for i in range(config["nodes"])
-    ]
+    secrets = []
+    for i in range(config["nodes"]):
+        name, secret = keygen_deterministic(scheme, key_seed, i)
+        pop = bls_pop(secret.to_bytes()) if scheme == "bls" else None
+        secrets.append(Secret(name, secret, scheme, pop))
     base_port = safe_base_port()
     committee = Committee.new(
         [
@@ -54,6 +58,7 @@ def write_committee(run_dir: str, config: dict, seed: int) -> list[str]:
             for i, secret in enumerate(secrets)
         ],
         scheme=scheme,
+        pops={s.name: s.pop for s in secrets if s.pop is not None},
     )
     write_committee_file(committee, os.path.join(run_dir, "committee.json"))
     write_parameters(

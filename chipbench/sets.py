@@ -28,7 +28,8 @@ ROOT = os.path.dirname(HERE)
 
 
 def spread(values: list[float]) -> float | None:
-    if len(values) < 2:
+    # a count that reads 0 in every run (view changes) has no share
+    if len(values) < 2 or not statistics.median(values):
         return None
     q1, _, q3 = statistics.quantiles(values, n=4)
     return (q3 - q1) / statistics.median(values)

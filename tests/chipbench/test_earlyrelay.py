@@ -6,6 +6,9 @@ import pytest
 
 from chipbench.readers import earlyrelay, proposerstats
 
+from .test_manifest import load
+from .test_nodedup_cell import entry
+
 HEAD = "2026-10-03T12:00:{s}.000Z [INFO] hotstuff_tpu.consensus.proposer.{node} Proposer stats: relayed={r} relay_frames={r} proposed_relayed=9 proposed_home=1 wait_ms_sum=100.0"
 
 
@@ -91,3 +94,21 @@ def test_a_run_without_a_directory_reads_nothing():
         t0, t1 = 0.0, 1.0
 
     assert earlyrelay.next_block_share(Bare()) is None
+
+
+def test_the_metric_is_in_the_manifest_for_every_cell_that_relays():
+    """Since PR 35: every committee relays at admission, so the three
+    cells report it, and a later cell joins the list."""
+    metric = entry("per_layer", "ingest.next_block_share")
+    assert {"colo64.low", "colo64.nodedup.low", "wan50.low"} <= set(
+        metric["workloads"]
+    )
+    assert (metric["unit"], metric["better"], metric["source"]) == (
+        "%", "higher", "program_counter"
+    )
+    assert (metric["layer"], metric["moves"]) == (
+        "ingest", "commit_latency_p50_ms"
+    )
+    assert load("layers", metric["name"] + ".json")["reader"] == (
+        "earlyrelay:next_block_share"
+    )

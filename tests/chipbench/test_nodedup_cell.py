@@ -23,6 +23,9 @@ NEW_METRICS = (
     "verify.evaluated_share", "verify.lane_fill_share",
     "verify.chunks_per_wave",
 )
+#: the cells whose committee runs the ed25519 verify service, whose
+#: stats line the three read: a later cell that runs it joins the lists
+SERVICE_CELLS = {"colo64.low", CELL, "wan50.low"}
 
 
 def entry(kind: str, name: str) -> dict:
@@ -77,7 +80,7 @@ def test_the_cell_reports_what_colo64_low_reports_and_the_fan_out():
             assert CELL in cells, metric["name"]
     for name in NEW_METRICS:
         metric = entry("per_layer", name)
-        assert metric["workloads"] == ["colo64.low", CELL]
+        assert SERVICE_CELLS <= set(metric["workloads"])
         assert metric["moves"] == "commit_latency_p50_ms"
         assert load("layers", name + ".json")["reader"].startswith("fanout:")
 
