@@ -5,15 +5,18 @@ It names the device, traces it and reads its memory when ``run.py``
 asks (by files in the run directory: only the chip's holder can do
 either), and otherwise only calls
 ``hotstuff_tpu.node.main.main(["run-many", ...])`` with the arguments
-``benchmark/local.py`` gives it.  Without a TPU it fails; ``--dry`` is
-the CPU rehearsal of the tests, prints ``platform: cpu`` and is never a
-cell.
+``benchmark/local.py`` gives it.  Without a TPU it fails (exit 3), and
+so it does, before it imports jax, where the program lacks a part that
+the configuration ``needs`` (exit 4); ``--dry`` is the CPU rehearsal of
+the tests, prints ``platform: cpu`` and is never a cell.  It does not
+outlive ``run.py`` (``ending.die_with_parent``).
 """
 
 from __future__ import annotations
 
 import argparse
 import hashlib
+import importlib
 import json
 import os
 import sys
@@ -83,6 +86,22 @@ def write_committee(run_dir: str, config: dict, seed: int) -> list[str]:
     return key_files
 
 
+def missing_need(needs: list[str]) -> str | None:
+    """The first of a configuration's ``needs``, each a
+    ``"package.module:attribute"`` of the program (``Class.method``
+    for a method), that this program does not have: the name of the
+    thing is the capability."""
+    for need in needs:
+        module, _, attribute = need.partition(":")
+        try:
+            found = importlib.import_module(module)
+            for part in attribute.split("."):
+                found = getattr(found, part)
+        except (ImportError, AttributeError):
+            return need
+    return None
+
+
 def serve_requests(run_dir: str, jax) -> None:
     """Answer ``run.py``: ``trace.request`` (seconds to trace) gets a
     profiler trace under ``trace/`` and ``trace.done``; ``memory.request``
@@ -132,8 +151,21 @@ def main() -> int:
     parser.add_argument("--dry", action="store_true")
     args = parser.parse_args()
     sys.path.insert(0, ROOT)
+    from chipbench import ending
+
+    ending.die_with_parent()
     with open(args.config) as f:
         config = json.load(f)
+    # a tree that cannot run the deployment says so at once: before jax
+    # is imported, the chip opened or a file written
+    lacks = missing_need(config.get("needs", []))
+    if lacks is not None:
+        print(
+            f"chipbench: configuration {config['name']} needs {lacks}, "
+            "which this program does not have",
+            file=sys.stderr,
+        )
+        return 4
 
     import jax
 

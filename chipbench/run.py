@@ -9,6 +9,7 @@ from this process (``gen.py``), reduces the committee's log with the
 metrics' readers, checks the guarantees (``check.py``) and prints the
 result as the last line.  It never imports jax.  ``--dry`` is the CPU
 rehearsal the tests make; it is not a cell and says ``platform: cpu``.
+However it ends, nothing it started is left (``ending.py``).
 """
 
 from __future__ import annotations
@@ -19,7 +20,7 @@ import importlib
 import json
 import os
 import shutil
-import signal
+import socket
 import subprocess
 import sys
 import time
@@ -29,7 +30,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, ROOT)
 
-from chipbench import check, trace  # noqa: E402
+from chipbench import check, ending, trace  # noqa: E402
 from chipbench.gen import Generator, Plan  # noqa: E402
 from chipbench.logs import CommitteeLog  # noqa: E402
 from chipbench.reduce import Run  # noqa: E402
@@ -38,11 +39,14 @@ from chipbench.reduce import Run  # noqa: E402
 POLL_S = 0.25
 #: seconds of the window that a traced run hands to the profiler
 TRACE_S = 7.0
-#: a chip's holder takes ~6 s to leave on SIGTERM (my chip run, PR 22)
-EXIT_GRACE_S = 30.0
-#: the committee binds its ports only after a warm-up that compiles on
-#: a checkout's first run
-BOOT_LIMIT_S = 1000.0
+#: from the start of ``run.py`` to the first commit, or the run is given
+#: up.  The committee binds its ports only after a warm-up that compiles
+#: on a checkout's first run: the slowest first set-up on record is
+#: 105.8 s (ledger, PR 35, ``colo64.low``) and a checkout's first run
+#: with ``native/`` to build read 75.9 s and 94.8 s (my chip runs, PRs 35
+#: and 37), so this is about three times the slowest seen; it was 1,000 s
+#: until PR 37
+BOOT_LIMIT_S = 300.0
 
 
 def load(kind: str, name: str) -> dict:
@@ -84,13 +88,99 @@ def read_json(path: str):
         return None
 
 
-async def wait_for(path: str, limit_s: float, alive) -> bool:
-    deadline = time.time() + limit_s
+async def wait_for(path: str, deadline: float, alive) -> bool:
     while not os.path.exists(path):
         if time.time() > deadline or not alive():
             return False
         await asyncio.sleep(0.05)
     return True
+
+
+def given_up(what: str, child, log_path: str) -> SystemExit:
+    """How a run that gets no committee leaves: what did not come,
+    whether the child had ended or the boot limit had passed, and the
+    end of the child's log (its last words, if it refused to start)."""
+    rc = child.poll()
+    why = (
+        f"{BOOT_LIMIT_S:.0f} s after the start" if rc is None
+        else f"the child had ended, exit {rc}"
+    )
+    try:
+        with open(log_path, errors="replace") as f:
+            f.seek(max(0, os.path.getsize(log_path) - 1500))
+            tail = f.read()
+    except OSError:
+        tail = ""
+    return SystemExit(f"chipbench: {what} ({why})\n{tail}".rstrip())
+
+
+def holder_of(port: int) -> str | None:
+    """The process that listens on a port, from ``/proc/net/tcp`` and
+    ``/proc/*/fd`` as far as this user may read them; None where no
+    process holds the listener."""
+    inodes = set()
+    for table in ("/proc/net/tcp", "/proc/net/tcp6"):
+        try:
+            with open(table) as f:
+                rows = [line.split() for line in f.read().splitlines()[1:]]
+        except OSError:
+            continue
+        inodes |= {
+            row[9] for row in rows
+            if row[3] == "0A" and int(row[1].rsplit(":", 1)[1], 16) == port
+        }
+    for pid in filter(str.isdigit, os.listdir("/proc")):
+        try:
+            for fd in os.listdir(f"/proc/{pid}/fd"):
+                link = os.readlink(f"/proc/{pid}/fd/{fd}")
+                if link.startswith("socket:[") and link[8:-1] in inodes:
+                    return f"pid {pid}: {ending.command_of(pid)}"
+        except OSError:
+            continue
+    return None
+
+
+def committee_ports(nodes: int) -> tuple[int, int]:
+    """The first and the last port the committee binds: every run's
+    are the same (``safe_base_port()`` is one fixed base a host)."""
+    try:
+        from benchmark.local import safe_base_port
+    except ImportError as e:
+        raise SystemExit(f"chipbench: no program to import here: {e}")
+    base = safe_base_port()
+    return base, base + nodes - 1
+
+
+def answering(ports) -> int | None:
+    """The first of ``ports`` on which a listener answers a connect (a
+    socket in TIME_WAIT does not)."""
+    for port in ports:
+        try:
+            socket.create_connection(("127.0.0.1", port), timeout=1.0).close()
+        except OSError:
+            continue
+        return port
+    return None
+
+
+def refuse_held_ports(ports: tuple[int, int]) -> None:
+    """A committee left by another run would take this run's load and
+    answer for it, so a port that a process holds ends the run at once:
+    the holder is named, not killed, since this run did not start it.
+    A listener that no process holds is one the kernel is still
+    closing, for seconds after a chip's holder has gone (my chip run,
+    PR 37): a run killed from outside leaves such, and they are given
+    the grace to go."""
+    port = answering(ports)
+    while port is not None:
+        holder = holder_of(port)
+        if holder or time.time() - STARTED > ending.EXIT_GRACE_S:
+            raise SystemExit(
+                f"chipbench: port {port} of the committee's {ports[0]}-"
+                f"{ports[1]} is held ({holder or 'by no process in /proc'})"
+            )
+        time.sleep(ending.LOOK_S)
+        port = answering(ports)
 
 
 async def drive(args, config, traffic, run_dir, child, log_path) -> Run:
@@ -100,10 +190,13 @@ async def drive(args, config, traffic, run_dir, child, log_path) -> Run:
     plan = Plan(traffic, nodes, args.seed, args.seconds)
     log = CommitteeLog()
     setup = {"child_started_s": child.started - STARTED}
+    deadline = STARTED + BOOT_LIMIT_S
     if not await wait_for(
-        os.path.join(run_dir, "nodes.json"), BOOT_LIMIT_S, alive
+        os.path.join(run_dir, "nodes.json"), deadline, alive
     ):
-        raise SystemExit("chipbench: the child never wrote its committee")
+        raise given_up(
+            "the child never wrote its committee", child, log_path
+        )
     addresses = [tuple(n["address"]) for n in read_json(
         os.path.join(run_dir, "nodes.json")
     )]
@@ -111,12 +204,17 @@ async def drive(args, config, traffic, run_dir, child, log_path) -> Run:
     run = Run(config, traffic, plan, log, gen.sent_at, gen.refused, 0.0)
     run.setup = setup
     try:
-        await gen.connect(time.time() + BOOT_LIMIT_S, alive)
+        try:
+            await gen.connect(deadline, alive)
+        except OSError as e:
+            raise given_up(f"a node never listened: {e}", child, log_path)
         setup["connected_s"] = time.time() - STARTED
         gen.prime()
         while log.first_commit is None:
-            if not alive() or time.time() - STARTED > BOOT_LIMIT_S:
-                raise SystemExit("chipbench: the committee committed nothing")
+            if not alive() or time.time() > deadline:
+                raise given_up(
+                    "the committee committed nothing", child, log_path
+                )
             await asyncio.sleep(0.02)
             log.poll(log_path)
         setup["first_commit_s"] = log.first_commit - STARTED
@@ -159,25 +257,36 @@ async def drive(args, config, traffic, run_dir, child, log_path) -> Run:
     return run
 
 
-def end_child(child, run_dir: str) -> dict:
-    """Ask for the memory reading, end the child and wait for it."""
-    fate = {"rc_before_signal": child.poll(), "sigkill": False}
-    if child.poll() is None:
-        open(os.path.join(run_dir, "memory.request"), "w").close()
-        deadline = time.time() + 5.0
-        while (
-            not os.path.exists(os.path.join(run_dir, "memory.json"))
-            and time.time() < deadline
-        ):
-            time.sleep(0.05)
-        child.send_signal(signal.SIGTERM)
-    try:
-        child.wait(timeout=EXIT_GRACE_S)
-    except subprocess.TimeoutExpired:
-        fate["sigkill"] = True
-        child.kill()
-        child.wait()
-    return fate
+def read_memory(child, run_dir: str) -> None:
+    """Have the child write ``memory.json`` while it still holds the
+    chip."""
+    if child.poll() is not None:
+        return
+    open(os.path.join(run_dir, "memory.request"), "w").close()
+    deadline = time.time() + 5.0
+    while (
+        not os.path.exists(os.path.join(run_dir, "memory.json"))
+        and time.time() < deadline
+    ):
+        time.sleep(0.05)
+
+
+def end_child(child, ports: tuple[int, int]) -> dict:
+    """End the child and whatever it started, its whole session, and
+    wait until no process of it runs and no port of the committee
+    answers: the kernel goes on closing a chip holder's sockets after
+    its last thread has gone, and the next run would meet them."""
+    rc = child.poll()
+    ended = ending.end_group(child.pid, ending.EXIT_GRACE_S, reap=child.poll)
+    began = time.time()
+    left = answering(ports)
+    while left is not None and time.time() - began < ending.EXIT_GRACE_S:
+        time.sleep(ending.LOOK_S)
+        left = answering(ports)
+    return {
+        "rc_before_signal": rc, **ended,
+        "ports_free_s": time.time() - began, "port_left": left,
+    }
 
 
 def read_trace(run_dir: str) -> dict | None:
@@ -229,18 +338,23 @@ def main() -> int:
         "--seed", str(args.seed),
         "--chips", str(cell["chips"]),
     ] + (["--dry"] if args.dry else [])
+    ending.exit_on_signals()
+    ports = committee_ports(config["nodes"])
+    refuse_held_ports(ports)
+    # a session of its own: the child and what it starts are one group
     with open(log_path, "wb") as log_file:
         child = subprocess.Popen(
             command, stdout=log_file, stderr=subprocess.STDOUT,
-            env=env, cwd=ROOT,
+            env=env, cwd=ROOT, start_new_session=True,
         )
-    child.started = time.time()
     try:
+        child.started = time.time()
         run = asyncio.run(
             drive(args, config, traffic, run_dir, child, log_path)
         )
+        read_memory(child, run_dir)
     finally:
-        fate = end_child(child, run_dir)
+        fate = end_child(child, ports)
     run.log.poll(log_path)
     device = read_json(os.path.join(run_dir, "device.json"))
     if device is None:
@@ -259,7 +373,11 @@ def main() -> int:
     if fate["rc_before_signal"] is not None:
         why_not.append(f"the child died: exit {fate['rc_before_signal']}")
     if fate["sigkill"]:
-        why_not.append("the child had to be SIGKILLed")
+        why_not.append(
+            f"the child's group had to be SIGKILLed: {fate['killed']}"
+        )
+    if fate["port_left"] is not None:
+        why_not.append(f"port {fate['port_left']} still answers")
     if run.log.tracebacks:
         why_not.append(f"{run.log.tracebacks} traceback(s) in the log")
 

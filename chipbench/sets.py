@@ -25,6 +25,9 @@ import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
+sys.path.insert(0, ROOT)
+
+from chipbench import ending  # noqa: E402
 
 
 def spread(values: list[float]) -> float | None:
@@ -43,16 +46,28 @@ def main() -> int:
     parser.add_argument("--seconds", type=float, required=True)
     parser.add_argument("--trace", type=int, default=0)
     args = parser.parse_args()
+    ending.exit_on_signals()
     out_dir = os.path.join(ROOT, "chiprun_out", "sets")
     os.makedirs(out_dir, exist_ok=True)
     values: dict[str, list[float]] = {}
     for seed in args.seeds.split(","):
         began = time.time()
-        done = subprocess.run(
+        run = subprocess.Popen(
             [sys.executable, os.path.join(HERE, "run.py"),
              "--workload", args.workload, "--seed", seed,
              "--seconds", str(args.seconds), "--trace", str(args.trace)],
-            capture_output=True, text=True, cwd=ROOT,
+            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
+            cwd=ROOT,
+        )
+        try:
+            stdout, stderr = run.communicate()
+        finally:
+            # ending a set ends its run, which ends its committee
+            if run.poll() is None:
+                run.terminate()
+                run.wait()
+        done = subprocess.CompletedProcess(
+            run.args, run.returncode, stdout, stderr
         )
         lines = done.stdout.strip().splitlines()
         record = {

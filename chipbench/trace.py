@@ -102,4 +102,8 @@ def summarise_devices(devices: list[list], window_s: float) -> dict | None:
 
 
 if __name__ == "__main__":
+    import ending  # beside this file: a program here, not the package's
+
+    # ``run.py`` waits for this reader; killed meanwhile, it is missed
+    ending.die_with_parent()
     json.dump(device_events(sys.argv[1]), sys.stdout)

@@ -125,3 +125,27 @@ def test_files_under_paths_keep_to_the_allowed_characters():
     for kind in ("traffic", "layers", "configs"):
         for name in os.listdir(os.path.join(ROOT, "chipbench", kind)):
             assert name.endswith(".json"), name
+
+
+#: what a configuration may name under ``needs``: a module of the
+#: program and an attribute of it, or of a class of it
+NEED = re.compile(
+    r"^[A-Za-z_]\w*(\.[A-Za-z_]\w*)*:[A-Za-z_]\w*(\.[A-Za-z_]\w*)*$", re.ASCII
+)
+
+
+@pytest.mark.parametrize(
+    "entry", BENCH["configs"], ids=[c["name"] for c in BENCH["configs"]]
+)
+def test_what_a_configuration_needs_is_named_and_is_there(entry):
+    """``needs`` is optional: a list of ``"package.module:attribute"``
+    (``Class.method`` for a method), each a part of the program that ``child.py`` looks up before it
+    opens the chip.  A cell of this tree's manifest names only what this
+    tree's program has."""
+    from chipbench.child import missing_need
+
+    needs = load("configs", entry["name"] + ".json").get("needs", [])
+    assert isinstance(needs, list) and len(needs) <= 16
+    for need in needs:
+        assert isinstance(need, str) and NEED.match(need), need
+    assert missing_need(needs) is None
