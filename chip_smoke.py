@@ -323,19 +323,15 @@ def _check_lanes(device, shapes) -> dict:
     if sorted(np.flatnonzero(~want)) != sorted(planted):
         raise SmokeFailure("ed25519_ref did not refuse exactly the planted lanes")
 
-    kernel = (
-        dev._verify_kernel_pallas_donated
-        if device.donate_buffers
-        else dev._verify_kernel_pallas
-    )
+    kernel = dev._wave_entry(True, device.donate_buffers)
     steady = {}
     for shape in shapes:
         batch = (msgs[:shape], pks[:shape], sigs[:shape])
         # the computation production dispatches at this shape (already
         # traced and lowered by the warm-up) holds a Mosaic custom call:
         # not the XLA kernel, not interpret mode
-        _, arrays = device.prepare(*batch)
-        lowered = kernel.lower(*(jnp.asarray(a) for a in arrays))
+        _, (tables, buf) = device.prepare(*batch)
+        lowered = kernel.lower(tables, jnp.asarray(buf))
         if "tpu_custom_call" not in lowered.as_text():
             raise SmokeFailure(f"shape {shape}: no tpu_custom_call lowered")
         got = np.asarray(device.verify_device(*batch))

@@ -1742,7 +1742,7 @@ class AsyncVerifyService:
                 "cpu=%d probe=%d device_sigs=%d cpu_sigs=%d "
                 "deadline_misses=%d waits=%d depth=%d mesh=%d "
                 "agg=%d agg_sigs=%d ewma_ms=%.1f zc=%d fb=%d "
-                "submitted_sigs=%d lanes=%d chunks=%d",
+                "submitted_sigs=%d lanes=%d chunks=%d h2d=%d calls=%d",
                 self._stats_tag,
                 self.dispatches,
                 self.device_dispatches,
@@ -1762,6 +1762,9 @@ class AsyncVerifyService:
                 self.submitted_sigs,
                 self.lanes,
                 self.chunks,
+                # the backend's own: host arrays handed to jax and jitted
+                # calls, one of each a chunk (tpu/ed25519.py)
+                *getattr(self.backend, "device_counters", lambda: (0, 0))(),
             )
 
 

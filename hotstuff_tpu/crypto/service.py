@@ -40,9 +40,12 @@ class VerifierBackend(Protocol):
       never routes to a backend that would cold-compile mid-consensus;
     - ``dispatch_deadline_s = 0.1`` — floor for the per-dispatch
       deadline (raised adaptively from the dispatch EWMA);
-    - ``device_key_cache = False`` — committee key tables are staged
-      device-resident once per rebuild and gathered by row id per wave
-      (tpu/ed25519.BatchVerifier, parallel/mesh.ShardedBatchVerifier);
+    - ``device_counters()`` (unset: zeros) — cumulative ``(h2d,
+      calls)``: host arrays handed to the device and jitted calls made,
+      printed on the service's stats line after ``chunks=``
+      (tpu/ed25519.BatchVerifier and parallel/mesh.ShardedBatchVerifier
+      count one of each a wave: the staging buffer, the entry that
+      decomposes, gathers and verifies);
     - ``supports_wave_padding = False`` — device-routed waves may be
       pre-padded to fixed bucket shapes (``HOTSTUFF_WAVE_BUCKETS``)
       with always-valid filler claims so every dispatch hits a warm

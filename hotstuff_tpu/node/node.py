@@ -130,6 +130,12 @@ class LazyDeviceVerifier:
     def _device(self) -> VerifierBackend | None:
         return self._shared_device.get(self._kind)
 
+    def device_counters(self) -> tuple[int, int]:
+        """The device verifier's ``(h2d, calls)`` for the verify
+        service's stats line; zeros until the device materializes."""
+        device = self._device
+        return (0, 0) if device is None else device.device_counters()
+
     @property
     def wave_bucket_shapes(self) -> tuple | None:
         """The device verifier's advertised wave bucket ladder (the mesh

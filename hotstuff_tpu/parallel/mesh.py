@@ -24,8 +24,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..tpu import curve
-from ..tpu.ed25519 import BatchVerifier
+from ..tpu.ed25519 import BatchVerifier, wave_fn
 from ..telemetry import spans as _spans
 
 DP_AXIS = "dp"
@@ -55,42 +54,15 @@ def default_mesh(n_devices: int | None = None) -> Mesh:
     return Mesh(np.array(devices), (DP_AXIS,))
 
 
-# in_specs for (ax, ay, az, at, s_bits, k_bits, r_y, r_sign): batch axis is
-# axis 0 everywhere except the bit-planes, where it is axis 1.
-_IN_SPECS = (
-    P(DP_AXIS),
-    P(DP_AXIS),
-    P(DP_AXIS),
-    P(DP_AXIS),
-    P(None, DP_AXIS),
-    P(None, DP_AXIS),
-    P(DP_AXIS),
-    P(DP_AXIS),
-)
+# in_specs for (tables, buffer): the committee tables replicated, the
+# wave's staging buffer sharded by rows, so each device decomposes and
+# gathers exactly its own slice (tpu/ed25519.py ``unpack_wave``).
+_IN_SPECS = (P(), P(DP_AXIS))
 
 
-def _local_verify(ax, ay, az, at, s_bits, k_bits, r_y, r_sign):
-    p = curve.dual_scalar_mult(s_bits, k_bits, (ax, ay, az, at))
-    return curve.compressed_equals(p, r_y, r_sign)
-
-
-def _make_local_verify_pallas(interpret: bool = False):
-    """Per-shard dispatch of the fully fused Pallas verify (scan +
-    in-VMEM compressed-equality epilogue) — each device runs it on its
-    slice; per-shard batch must be a multiple of pallas_dsm.LANE_TILE
-    (the verifier's pad grid guarantees it).  ``interpret=True`` runs
-    the SAME kernel through the Pallas interpreter so the exact
-    production route (shard_map + Pallas + psum) gets multi-device
-    parity coverage on the CPU test mesh (VERDICT r2 item 7)."""
-    from ..tpu import pallas_dsm
-
-    def local(ax, ay, az, at, s_bits, k_bits, r_y, r_sign):
-        return pallas_dsm.verify_compressed(
-            s_bits, k_bits, (ax, ay, az, at), r_y, r_sign,
-            interpret=interpret,
-        )
-
-    return local
+def _bad_count(ok):
+    """The invalid lanes over the whole mesh: the one word crossing ICI."""
+    return jax.lax.psum(jnp.sum(jnp.logical_not(ok).astype(jnp.int32)), DP_AXIS)
 
 
 def make_sharded_verify(
@@ -100,18 +72,21 @@ def make_sharded_verify(
     donate: bool = False,
     psum_word: bool = False,
 ):
-    """jitted [batch]-bool verification with the batch sharded over the
-    mesh. Batch size must be a multiple of the mesh size (the driver pads).
+    """jitted ``(tables, buffer) -> bool[rows]`` verification with the
+    wave's rows sharded over the mesh.  Rows must be a multiple of the
+    mesh size (the driver pads).  Per shard it is the base verifier's
+    ``wave_fn``: decomposition, key gather, kernel.
 
-    ``pallas=True`` runs the Pallas kernel per shard (TPU meshes; the
-    XLA kernel remains the portable path for the CPU-mesh tests and
-    dryrun).  ``interpret=True`` (tests only) drives the pallas branch
-    through the interpreter on CPU meshes.
+    ``pallas=True`` runs the fully fused Pallas kernel per shard (TPU
+    meshes; per-shard rows must be a multiple of pallas_dsm.LANE_TILE,
+    which the verifier's pad grid guarantees; the XLA kernel remains the
+    portable path for the CPU-mesh tests and dryrun).  ``interpret=True``
+    (tests only) drives the pallas branch through the interpreter so the
+    exact production route (shard_map + Pallas + psum) gets multi-device
+    parity coverage on the CPU test mesh (VERDICT r2 item 7).
 
-    ``donate=True`` donates the per-wave staging temporaries (args 4-7:
-    s_bits, k_bits, r_y, r_sign) to the kernel, mirroring the base
-    verifier's ``_verify_kernel_donated`` — the committee point rows
-    (args 0-3) alias the sharded device key gather and must NOT be
+    ``donate=True`` donates the wave's buffer, mirroring the base
+    verifier's entry — the replicated committee tables are never
     donated.
 
     ``psum_word=True`` additionally returns the replicated invalid-count
@@ -119,16 +94,13 @@ def make_sharded_verify(
     story hinges on.  The production mesh readback fetches THAT word
     first and skips the multi-shard lane gather entirely when the whole
     wave is valid (the common case)."""
-    local = _make_local_verify_pallas(interpret) if pallas else _local_verify
+    local = wave_fn(pallas, interpret)
     if psum_word:
         inner = local
 
-        def local(ax, ay, az, at, s_bits, k_bits, r_y, r_sign):
-            ok = inner(ax, ay, az, at, s_bits, k_bits, r_y, r_sign)
-            bad = jax.lax.psum(
-                jnp.sum(jnp.logical_not(ok).astype(jnp.int32)), DP_AXIS
-            )
-            return ok, bad
+        def local(tables, buf):
+            ok = inner(tables, buf)
+            return ok, _bad_count(ok)
 
         out_specs = (P(DP_AXIS), P())
     else:
@@ -142,20 +114,19 @@ def make_sharded_verify(
         # so the vma consistency check cannot apply to the pallas branch
         check_vma=not pallas,
     )
-    return jax.jit(fn, donate_argnums=(4, 5, 6, 7) if donate else ())
+    return jax.jit(fn, donate_argnums=(1,) if donate else ())
 
 
 def make_sharded_qc_check(mesh: Mesh):
     """jitted scalar-bool "is every signature in this QC valid" with the
-    batch sharded over the mesh and a single psum word crossing ICI."""
-
-    def local_all(ax, ay, az, at, s_bits, k_bits, r_y, r_sign):
-        ok = _local_verify(ax, ay, az, at, s_bits, k_bits, r_y, r_sign)
-        bad = jax.lax.psum(jnp.sum(jnp.logical_not(ok).astype(jnp.int32)), DP_AXIS)
-        return bad == 0
-
+    wave's rows sharded over the mesh and a single psum word crossing
+    ICI."""
+    local = wave_fn(pallas=False)
     fn = shard_map(
-        local_all, mesh=mesh, in_specs=_IN_SPECS, out_specs=P()
+        lambda tables, buf: _bad_count(local(tables, buf)) == 0,
+        mesh=mesh,
+        in_specs=_IN_SPECS,
+        out_specs=P(),
     )
     return jax.jit(fn)
 
@@ -185,17 +156,20 @@ class ShardedBatchVerifier(BatchVerifier):
         self._shard_pallas = (
             self.mesh.devices.flat[0].platform == "tpu"
         )
-        mk = lambda **kw: make_sharded_verify(  # noqa: E731
-            self.mesh, pallas=self._shard_pallas, **kw
-        )
-        # four compiled entry points, each compiled lazily per shape:
-        # the plain per-item kernel keeps stage()/bench signature parity
-        # with the base class; production verify_device dispatches the
+        # the compiled entry points by (psum_word, donate), each compiled
+        # lazily per shape: stage() hands out the plain per-item kernel
+        # like the base class; production verify_device dispatches the
         # psum-word variants (per-item lanes + the one ICI word).
-        self._kernel = mk()
-        self._kernel_donated = mk(donate=True)
-        self._kernel_psum = mk(psum_word=True)
-        self._kernel_psum_donated = mk(psum_word=True, donate=True)
+        self._kernels = {
+            (psum_word, donate): make_sharded_verify(
+                self.mesh,
+                pallas=self._shard_pallas,
+                psum_word=psum_word,
+                donate=donate,
+            )
+            for psum_word in (False, True)
+            for donate in (False, True)
+        }
         self.name = f"tpu-sharded-{m}"
         if self._shard_pallas:
             from ..tpu import pallas_dsm
@@ -235,65 +209,25 @@ class ShardedBatchVerifier(BatchVerifier):
         )
         self.wave_bucket_shapes = tuple(sorted(set(snapped)))
         # Per-shard device key table (ISSUE 6): the stacked committee
-        # tables replicate across the mesh once per rebuild, each wave
-        # ships only its [padded] row indices sharded over dp, and the
-        # gather runs device-side producing rows already laid out for
-        # the shard_map in_specs — the sharded backend stops restaging
-        # 4x[padded,20] coordinate rows every wave.
+        # tables replicate across the mesh once per rebuild (the base
+        # class's _device_build places them by _table_sharding), each
+        # wave ships only its staging buffer, rows sharded over dp, and
+        # every device gathers its own slice's rows inside the call.
         self._row_sharding = NamedSharding(self.mesh, P(DP_AXIS))
         self._table_sharding = NamedSharding(self.mesh, P())
-        self._sharded_gather = jax.jit(
-            lambda tables, idxs: tuple(t[idxs] for t in tables),
-            out_shardings=(self._row_sharding,) * 4,
-        )
 
     @property
     def kernel_name(self) -> str:
         return "pallas" if self._shard_pallas else "xla"
 
-    # per-shard key table: the staged gather emits rows sharded to
-    # match the shard_map in_specs (see _gather_device_rows), so the
-    # PR 5 device key cache now applies to the mesh backend too
-    device_key_cache = True
-
-    def _device_build(self, build):
-        """Replicate the stacked committee tables across the mesh once
-        per rebuild (committee keys are epoch-static)."""
-        if self._device_src is not build:
-            tables, _ = build
-            self._device_tables = tuple(
-                jax.device_put(t, self._table_sharding) for t in tables
-            )
-            self._device_src = build
-        return self._device_tables
-
-    def _gather_device_rows(self, build, idxs):
-        """Shard-aligned committee gather: [padded] indices sharded
-        over dp index the replicated tables, so each device produces
-        exactly its own slice of the coordinate rows."""
-        tables = self._device_build(build)
-        return self._sharded_gather(
-            tables, jax.device_put(idxs, self._row_sharding)
-        )
-
-    def _run_kernel(
-        self, ax, ay, az, at, s_bits, k_bits, r_y, r_sign, donate=False
-    ):
-        # donation wired through the shard_map jit (ISSUE 7): the
-        # donated compilation hands the four per-wave staging
-        # temporaries (bit-planes + R rows) back to XLA, exactly like
-        # the base class's _verify_kernel_donated — the point rows stay
-        # un-donated because they alias the sharded committee gather.
-        kernel = self._kernel_donated if donate else self._kernel
-        return kernel(
-            jnp.asarray(ax),
-            jnp.asarray(ay),
-            jnp.asarray(az),
-            jnp.asarray(at),
-            jnp.asarray(s_bits),
-            jnp.asarray(k_bits),
-            jnp.asarray(r_y),
-            jnp.asarray(r_sign),
+    def _run_wave(self, tables, buf, donate=False, psum_word=False):
+        """The base class's step with the placement changed: the
+        buffer's rows land sharded over dp, so each device holds exactly
+        its slice.  Donation hands the buffer back to XLA as the base
+        entry does (ISSUE 7)."""
+        self._count(h2d=1, calls=1)
+        return self._kernels[psum_word, donate](
+            tables, jax.device_put(buf, self._row_sharding)
         )
 
     def verify_device(self, messages, pubkeys, signatures):
@@ -313,27 +247,19 @@ class ShardedBatchVerifier(BatchVerifier):
             # oversized batches chunk through the base class, which
             # recurses back here per max-shape chunk
             return super().verify_device(messages, pubkeys, signatures)
-        donate = self.donate_buffers
-        kernel = self._kernel_psum_donated if donate else self._kernel_psum
-        rec = _spans.recorder()
-        if rec is None:
-            valid_host, arrays = self.prepare(messages, pubkeys, signatures)
-            ok, bad = kernel(*(jnp.asarray(a) for a in arrays))
+        with _spans.span("prepare"):
+            valid_host, args = self.prepare(messages, pubkeys, signatures)
+        with _spans.span("dispatch"):
+            ok, bad = self._run_wave(
+                *args, donate=self.donate_buffers, psum_word=True
+            )
+        with _spans.span("device.execute"):
             ok = jax.block_until_ready(ok)
-            if int(np.asarray(bad)) == 0:
-                # every lane valid => host validity was all-True too
-                # (host-invalid rows are zeroed into failing lanes)
-                return np.ones(n, bool)
-            return np.asarray(ok)[:n] & valid_host
-        with rec.span("prepare"):
-            valid_host, arrays = self.prepare(messages, pubkeys, signatures)
-        with rec.span("dispatch"):
-            ok, bad = kernel(*(jnp.asarray(a) for a in arrays))
-        with rec.span("device.execute"):
-            ok = jax.block_until_ready(ok)
-        with rec.span("mesh.psum"):
+        with _spans.span("mesh.psum"):
             bad_count = int(np.asarray(bad))
         if bad_count == 0:
-            return np.ones(n, bool)
-        with rec.span("readback"):
+            # every lane passed, and a row the host refused rides as a
+            # passing pad row: the host's verdicts are the wave's
+            return valid_host
+        with _spans.span("readback"):
             return np.asarray(ok)[:n] & valid_host

@@ -28,7 +28,7 @@ shapes to bound XLA recompilation.
 
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache
 
 import numpy as np
 
@@ -39,10 +39,14 @@ from ..crypto import ed25519_ref as ref
 from ..telemetry import spans as _spans
 from . import curve, field as F
 
-MASK255 = (1 << 255) - 1
-
 # Padded batch shapes (powers of 4) to bound compilation count.
 PAD_SIZES = (1, 4, 16, 64, 256, 1024, 4096)
+
+# Pallas pad shapes: lane-aligned, capped at 1024 per dispatch (larger
+# batches chunk).  Each shape is traced, lowered and compiled on its
+# own; only the compile is a load from the persistent cache after the
+# first process (seconds per shape on a v5e: CHANGES.md, ISSUE 22).
+PALLAS_PAD_SIZES = (128, 256, 1024)
 
 
 def _verify_impl(ax, ay, az, at, s_bits, k_bits, r_y, r_sign):
@@ -57,56 +61,78 @@ def _verify_impl(ax, ay, az, at, s_bits, k_bits, r_y, r_sign):
     return curve.compressed_equals(p, r_y, r_sign)
 
 
-def _verify_impl_pallas(ax, ay, az, at, s_bits, k_bits, r_y, r_sign):
-    """Same contract as _verify_impl, with the WHOLE verification —
-    double-scalar multiplication AND the compressed-equality epilogue —
-    fused into one VMEM-resident Pallas dispatch (tpu/pallas_dsm.py;
-    the XLA epilogue was ~2 ms of sequential HBM round-trips).  TPU
-    backend only; batch must be a multiple of pallas_dsm.LANE_TILE (the
-    pad sizes guarantee it)."""
-    from . import pallas_dsm
+#: columns of a wave's staging buffer, one ``uint8`` row a signature:
+#: the signature (R, then s), the challenge scalar k, and the key's row
+#: in the committee tables as four little-endian bytes
+SIG_COLS, K_COLS, ROW_COLS = slice(0, 64), slice(64, 96), slice(96, 100)
+WAVE_COLS = 100
 
-    return pallas_dsm.verify_compressed(
-        s_bits, k_bits, (ax, ay, az, at), r_y, r_sign
+# MSB-first shifts of the windows of one byte, and for each 13-bit limb
+# of R's low 255 bits the byte its lowest bit lies in and where
+_WIN_SHIFTS = np.arange(8 - curve.WINDOW, -1, -curve.WINDOW)
+_LIMB_BYTE, _LIMB_SHIFT = np.divmod(F.LIMB_BITS * np.arange(F.NLIMBS), 8)
+
+
+def _windows_msb(scalars):
+    """uint8 [n, 32] little-endian scalars -> int32 [NWIN, n] MSB-first
+    4-bit windows: ``_bytes_to_windows_msb`` transposed, by shifts."""
+    b = scalars[:, ::-1].astype(jnp.int32)
+    win = (b[:, :, None] >> _WIN_SHIFTS) & ((1 << curve.WINDOW) - 1)
+    return win.reshape(b.shape[0], curve.NWIN).T
+
+
+def unpack_wave(tables, buf):
+    """The kernel's eight operands from a staged wave, on the device:
+    ``buf`` is uint8 [n, WAVE_COLS], ``tables`` the four device-resident
+    coordinate tables of the negated committee points.  Bit for bit what
+    ``_bytes_to_windows_msb`` / ``_bytes_rows_to_limbs`` and a host
+    gather give (tests/test_tpu_ed25519.py holds them to each other)."""
+    r = buf[:, :32].astype(jnp.int32)
+    r_sign = r[:, 31] >> 7
+    # a limb's 13 bits lie in at most three bytes; bit 255 is the sign
+    r = jnp.pad(r.at[:, 31].set(r[:, 31] & 0x7F), ((0, 0), (0, 1)))
+    word = (
+        r[:, _LIMB_BYTE]
+        | r[:, _LIMB_BYTE + 1] << 8
+        | r[:, _LIMB_BYTE + 2] << 16
     )
+    r_y = (word >> _LIMB_SHIFT) & F.MASK
+    row = buf[:, ROW_COLS].astype(jnp.int32)
+    idx = row[:, 0] | row[:, 1] << 8 | row[:, 2] << 16 | row[:, 3] << 24
+    ax, ay, az, at = (t[idx] for t in tables)
+    s_win, k_win = _windows_msb(buf[:, 32:64]), _windows_msb(buf[:, K_COLS])
+    return ax, ay, az, at, s_win, k_win, r_y, r_sign
 
 
-_verify_kernel = partial(jax.jit, static_argnames=())(_verify_impl)
-_verify_kernel_pallas = partial(jax.jit, static_argnames=())(_verify_impl_pallas)
+def wave_fn(pallas: bool, interpret: bool = False):
+    """``(tables, buffer) -> bool[rows]``: the decomposition and the key
+    gather of ``unpack_wave``, then the kernel.  ``pallas`` selects the
+    fused VMEM-resident Pallas dispatch (tpu/pallas_dsm.py: TPU only,
+    rows a multiple of its LANE_TILE, which the pad sizes guarantee;
+    the XLA epilogue was ~2 ms of sequential HBM round-trips), else the
+    portable XLA kernel.  One function for the single-device entry below
+    and, per shard, for the mesh verifier (parallel/mesh.py)."""
 
-# Donated variants (ISSUE 6): the scalar windows, R limbs and sign bits
-# are per-wave staging temporaries — donating them lets XLA reuse their
-# device allocations across waves instead of re-allocating per dispatch.
-# The point coordinates (args 0-3) stay un-donated: with the device key
-# cache they alias the epoch-static gather source.
-_verify_kernel_donated = jax.jit(_verify_impl, donate_argnums=(4, 5, 6, 7))
-_verify_kernel_pallas_donated = jax.jit(
-    _verify_impl_pallas, donate_argnums=(4, 5, 6, 7)
-)
+    def verify_wave(tables, buf):
+        ax, ay, az, at, s_win, k_win, r_y, r_sign = unpack_wave(tables, buf)
+        if not pallas:
+            return _verify_impl(ax, ay, az, at, s_win, k_win, r_y, r_sign)
+        from . import pallas_dsm
 
+        return pallas_dsm.verify_compressed(
+            s_win, k_win, (ax, ay, az, at), r_y, r_sign, interpret=interpret
+        )
 
-# Pallas pad shapes: lane-aligned, capped at 1024 per dispatch (larger
-# batches chunk).  Each shape is traced, lowered and compiled on its
-# own; only the compile is a load from the persistent cache after the
-# first process (seconds per shape on a v5e: CHANGES.md, ISSUE 22).
-PALLAS_PAD_SIZES = (128, 256, 1024)
-
-
-@jax.jit
-def _gather_rows(tables, idxs):
-    """Device-side committee-key gather (ISSUE 5): index the
-    device-resident stacked point tables by row id, so a wave transfers
-    [n] int64 indices instead of 4x[n,20] int32 coordinate rows."""
-    return tuple(t[idxs] for t in tables)
+    return verify_wave
 
 
-def _bytes_to_limbs(b: bytes, lo_bits: int = 255) -> np.ndarray:
-    v = int.from_bytes(b, "little") & ((1 << lo_bits) - 1)
-    out = np.zeros(F.NLIMBS, np.int32)
-    for i in range(F.NLIMBS):
-        out[i] = v & F.MASK
-        v >>= F.LIMB_BITS
-    return out
+@lru_cache(maxsize=None)
+def _wave_entry(pallas: bool, donate: bool):
+    """The one jitted entry a wave is dispatched through, compiled once
+    a pad shape.  ``donate`` (ISSUE 6) hands the wave's buffer, a
+    per-wave temporary, back to XLA; the tables are the epoch-static
+    gather source and never donated."""
+    return jax.jit(wave_fn(pallas), donate_argnums=(1,) if donate else ())
 
 
 _LIMB_WEIGHTS = (1 << np.arange(F.LIMB_BITS, dtype=np.int32)).astype(np.int32)
@@ -120,7 +146,10 @@ _WIN_WEIGHTS = (1 << np.arange(curve.WINDOW - 1, -1, -1)).astype(np.int32)
 
 def _bytes_to_windows_msb(rows: np.ndarray) -> np.ndarray:
     """[n, W] little-endian scalar bytes -> [n, 2W] MSB-first 4-bit
-    windows (W = 32 for full scalars < L < 2^253)."""
+    windows (W = 32 for full scalars < L < 2^253).  With
+    ``_bytes_rows_to_limbs`` the plain numpy reference of
+    ``unpack_wave``: off the production path since a wave is decomposed
+    on the device."""
     bits = np.unpackbits(rows[:, ::-1], axis=1, bitorder="big").astype(np.int32)
     nwin = rows.shape[1] * 8 // curve.WINDOW
     groups = bits.reshape(rows.shape[0], nwin, curve.WINDOW)
@@ -172,10 +201,10 @@ class BatchVerifier:
         self._tables: tuple | None = None
         # Device-resident committee key cache (ISSUE 5): the stacked
         # coordinate tables staged on device ONCE per rebuild (committee
-        # keys are static per epoch), so each wave ships only the [n]
-        # row indices and gathers coordinates device-side instead of
-        # re-transferring 4x[n,20] int32 every dispatch.  _device_src
-        # identifies the host build the staged copy mirrors.
+        # keys are static per epoch), so each wave ships only its rows'
+        # indices, in its one buffer, and gathers coordinates inside the
+        # jitted call.  _device_src identifies the host build the staged
+        # copy mirrors.
         self._device_tables: tuple | None = None
         self._device_src: tuple | None = None
         # Per-thread staging scratch, keyed by padded size: the pipeline
@@ -185,6 +214,13 @@ class BatchVerifier:
         # long-lived (ISSUE 6), so these pools ARE the preallocated
         # staging-buffer ring: one persistent set per slot.
         self._scratch = threading.local()
+        # Host arrays handed to jax and jitted calls made, cumulative:
+        # a wave is one of each (the verify service prints both beside
+        # ``chunks=``), a table rebuild four arrays more.  The slot
+        # threads share them, hence the lock.
+        self.h2d = 0
+        self.calls = 0
+        self._count_lock = threading.Lock()
         # Challenge-hash memo: k = H(R||A||M) is a pure function of the
         # claim bytes, and fixed-shape padding re-stages the SAME pad
         # claim every wave — memoizing makes pad lanes (and re-verified
@@ -285,10 +321,10 @@ class BatchVerifier:
 
             # a padded wave brings the pad claim's key: into the point
             # cache before any shape compiles, or the first production
-            # wave grows the staged table by its row and the gather
-            # compiles again at every pad shape, inside the first
-            # waves' deadlines (my chip run, PR 32: two waves of every
-            # boot served by the CPU)
+            # wave could grow the staged table (a shape of the jitted
+            # entry's argument) and compile again at every pad shape,
+            # inside the first waves' deadlines (my chip run, PR 32: two
+            # waves of every boot served by the CPU)
             self._neg_point(make_pad_claim()[2])
             # same resolution the service uses: explicit env ladder
             # wins, else this backend's own advertised shapes (the mesh
@@ -318,14 +354,10 @@ class BatchVerifier:
                 self._tables = None  # stacked table is stale
         return hit
 
-    # staged device-side committee gather; the mesh-sharded subclass
-    # overrides the gather so rows land shard-aligned
-    device_key_cache = True
-
     @property
     def donate_buffers(self) -> bool:
-        """Donate the per-wave staging arrays to the kernel (ISSUE 6)
-        so XLA recycles their device allocations across waves.  On by
+        """Donate the wave's device buffer to the jitted call (ISSUE 6)
+        so XLA recycles its allocation across waves.  On by
         default on accelerator backends; ``HOTSTUFF_DONATE=1/0``
         forces either way (CPU jax has no donation support and warns
         once per shape, so it stays off there unless forced)."""
@@ -339,6 +371,20 @@ class BatchVerifier:
                 self._donate = jax.default_backend() in ("tpu", "gpu")
         return self._donate
 
+    #: where the committee tables are placed (None: the default device);
+    #: the mesh-sharded verifier replicates them over its mesh
+    _table_sharding = None
+
+    def _count(self, h2d: int, calls: int = 0) -> None:
+        with self._count_lock:
+            self.h2d += h2d
+            self.calls += calls
+
+    def device_counters(self) -> tuple[int, int]:
+        """``(h2d, calls)``, as the verify service's stats line prints
+        them (an optional capability: crypto/service.py)."""
+        return self.h2d, self.calls
+
     def _device_build(self, build):
         """The device-resident copy of ``build``'s stacked tables,
         staged on first use after each rebuild.  Idempotent and safe
@@ -346,29 +392,31 @@ class BatchVerifier:
         the same immutable build and last-write-wins."""
         if self._device_src is not build:
             tables, _ = build
-            self._device_tables = tuple(jnp.asarray(t) for t in tables)
+            self._count(h2d=len(tables))
+            self._device_tables = tuple(
+                jax.device_put(t, self._table_sharding) for t in tables
+            )
             self._device_src = build
         return self._device_tables
 
-    def _scratch_for(self, padded: int) -> dict:
-        """Preallocated per-thread staging buffers for this pad shape,
-        zeroed for reuse (one memset replaces the per-item Python
-        writes the old prepare loop did)."""
+    def _scratch_for(self, rows: int) -> np.ndarray:
+        """This thread's preallocated staging buffer for the pad shape
+        ``rows`` rows land on, uint8 [padded, WAVE_COLS], every row reset
+        to what a pad row carries: zero scalars, table row 0 (the zero
+        dummy) and R = the identity's encoding (y = 1).  The kernel
+        computes the identity on such a row and it passes, so the call
+        needs no row count."""
+        padded = next(p for p in self._padded_sizes() if p >= rows)
         pool = getattr(self._scratch, "pool", None)
         if pool is None:
             pool = self._scratch.pool = {}
-        bufs = pool.get(padded)
-        if bufs is None:
-            bufs = pool[padded] = {
-                "sig": np.zeros((padded, 64), np.uint8),
-                "k": np.zeros((padded, 32), np.uint8),
-                "r_sign": np.zeros(padded, np.int32),
-                "idxs": np.zeros(padded, np.int64),
-            }
+        buf = pool.get(padded)
+        if buf is None:
+            buf = pool[padded] = np.zeros((padded, WAVE_COLS), np.uint8)
         else:
-            for a in bufs.values():
-                a.fill(0)
-        return bufs
+            buf.fill(0)
+        buf[:, 0] = 1
+        return buf
 
     def _rebuild_tables(self):
         """Build (tables, row_index) FULLY in locals, then publish with
@@ -384,7 +432,11 @@ class BatchVerifier:
                 for pk, pt in self._point_cache.items()
                 if pt is not None
             ]
-            k = len(valid) + 1
+            # row 0 and the keys, up to a power of two from 128: the row
+            # count is a shape of the jitted entry's argument, and a key
+            # more (a stranger's signature) must not compile the kernel
+            # again inside a wave's deadline
+            k = max(128, 1 << len(valid).bit_length())
             tables = tuple(
                 np.zeros((k, F.NLIMBS), np.int32) for _ in range(4)
             )
@@ -446,25 +498,27 @@ class BatchVerifier:
                 ]
             )
 
-        # the internal dispatch donates its staging arrays when enabled
-        # (they are per-wave temporaries); external stage() users call
-        # the kernel with donate's default False and may reuse arrays
-        donate = self.donate_buffers
-        # the dispatch split into its waterfall stages; the fence is
-        # part of the production path (ISSUE 5): overlap happens at the
-        # WAVE level — the dispatch pipeline parks this worker thread
-        # in device.execute (GIL released) while the next wave stages
-        # on another thread
         with _spans.span("prepare"):
-            kernel, arrays, valid_host = self.stage(
-                messages, pubkeys, signatures
-            )
+            _, args, valid_host = self.stage(messages, pubkeys, signatures)
+        return self._dispatch(args, valid_host)
+
+    def _dispatch(self, args, valid_host) -> np.ndarray:
+        """A staged wave through the device, in the waterfall's stages.
+        The fence is part of the production path (ISSUE 5): overlap
+        happens at the WAVE level — the dispatch pipeline parks this
+        worker thread in device.execute (GIL released) while the next
+        wave stages on another thread.  The internal dispatch donates
+        the wave's buffer when enabled (a per-wave temporary); external
+        stage() users call the kernel with donate's default False."""
         with _spans.span("dispatch"):
-            ok = kernel(*arrays, donate=donate)
+            ok = self._run_wave(*args, donate=self.donate_buffers)
+            # the verdicts follow the kernel to the host by themselves,
+            # not on a round trip of their own after the fence
+            ok.copy_to_host_async()
         with _spans.span("device.execute"):
             ok = jax.block_until_ready(ok)
         with _spans.span("readback"):
-            return np.asarray(ok)[:n] & valid_host
+            return np.asarray(ok)[: len(valid_host)] & valid_host
 
     def verify_packed(self, dig_buf, pk_buf, sig_buf, rows: int) -> np.ndarray:
         """Zero-copy verify over adopted native ingest-arena columns
@@ -488,93 +542,32 @@ class BatchVerifier:
                 [r.tobytes() for r in pk_v],
                 [r.tobytes() for r in sig_v],
             )
-        donate = self.donate_buffers
         with _spans.span("prepare"):
-            valid_host, arrays = self.prepare_packed(dig_v, pk_v, sig_v)
-        with _spans.span("dispatch"):
-            ok = self._run_kernel(*arrays, donate=donate)
-        with _spans.span("device.execute"):
-            ok = jax.block_until_ready(ok)
-        with _spans.span("readback"):
-            return np.asarray(ok)[:rows] & valid_host
+            valid_host, args = self.prepare_packed(dig_v, pk_v, sig_v)
+        return self._dispatch(args, valid_host)
 
     def prepare_packed(self, dig_v, pk_v, sig_v) -> tuple[np.ndarray, tuple]:
         """``prepare`` over arena column views: signature staging is ONE
-        block copy off the column (the wire parser already validated
-        lengths, so the malformed-length scan is gone), and pad rows
-        (the same claim every wave) hit the challenge memo.  The
-        remaining per-row Python — point-cache lookups and the SHA-512
-        challenge — needs hashable bytes keys; a native challenge-hash
-        column (SHA-512 mod L in wave_pack.cpp) is the noted follow-up
-        that would erase it."""
+        block copy off the column, and the wire parser already validated
+        lengths, so the malformed-length scan is gone.  The per-row
+        Python of ``_fill_rows`` needs hashable bytes keys; a native
+        challenge-hash column (SHA-512 mod L in wave_pack.cpp) is the
+        noted follow-up that would erase it."""
         n = dig_v.shape[0]
-        padded = next(p for p in self._padded_sizes() if p >= n)
-        bufs = self._scratch_for(padded)
-        sig_rows = bufs["sig"]
-        k_rows = bufs["k"]
-        r_sign = bufs["r_sign"]
-        idxs = bufs["idxs"]
-
-        sig_rows[:n] = sig_v  # one vectorized copy straight off the arena
-        valid_host = np.ones(n, dtype=bool)
-
-        # s >= L rejection, vectorized — same compare as prepare()
-        s_be = sig_rows[:n, :31:-1]
-        diff = s_be != _L_BE
-        any_diff = diff.any(axis=1)
-        first = np.where(any_diff, diff.argmax(axis=1), 0)
-        valid_host &= (s_be[np.arange(n), first] < _L_BE[first]) & any_diff
-
-        pk_b = [r.tobytes() for r in pk_v]
-        for i in np.flatnonzero(valid_host):
-            if pk_b[i] not in self._point_cache:
-                self._neg_point(pk_b[i])
-        build = self._tables
-        if build is None:
-            build = self._rebuild_tables()
-        tables, row_of = build
-        for i in np.flatnonzero(valid_host):
-            row = row_of.get(pk_b[i], 0)
-            if row:
-                idxs[i] = row
-            else:
-                valid_host[i] = False  # key decompresses to no point
-
-        memo = self._challenge_memo
-        for i in np.flatnonzero(valid_host):
-            key = (sig_v[i].tobytes(), pk_b[i], dig_v[i].tobytes())
-            kb = memo.get(key)
-            if kb is None:
-                k = ref.verify_challenge(key[0], key[1], key[2])
-                kb = k.to_bytes(32, "little")
-                if len(memo) >= 8192:
-                    memo.clear()
-                memo[key] = kb
-            k_rows[i] = np.frombuffer(kb, np.uint8)
-        bad = ~valid_host
-        if bad.any():
-            sig_rows[:n][bad] = 0  # zero scalars -> identity lanes
-        r_sign[:n] = sig_rows[:n, 31] >> 7
-
-        s_bits = _bytes_to_windows_msb(sig_rows[:, 32:])
-        k_bits = _bytes_to_windows_msb(k_rows)
-        r_y = _bytes_rows_to_limbs(sig_rows[:, :32])
-        if padded > n:
-            r_y[n:, 0] = 1
-
-        if self.device_key_cache:
-            ax, ay, az, at = self._gather_device_rows(build, idxs)
-        else:
-            ax, ay, az, at = (t[idxs] for t in tables)
-
-        return valid_host, (
-            ax, ay, az, at, s_bits.T, k_bits.T, r_y, r_sign.copy(),
+        buf = self._scratch_for(n)
+        buf[:n, SIG_COLS] = sig_v  # one vectorized copy straight off the arena
+        return self._fill_rows(
+            buf,
+            np.ones(n, dtype=bool),
+            [r.tobytes() for r in dig_v],
+            [r.tobytes() for r in pk_v],
+            [r.tobytes() for r in sig_v],
         )
 
     def stage(self, messages, pubkeys, signatures):
-        """(kernel_fn, kernel arrays, host_validity) for this batch —
+        """(kernel_fn, its arguments, host_validity) for this batch —
         the production dispatch point (the mesh-sharded subclass
-        overrides ``_run_kernel``).
+        overrides ``_run_wave``).
 
         NOTE (round 3): a split-scalar kernel variant (each signature as
         two 128-bit half rows, 16 macro steps) lived here through round
@@ -585,8 +578,8 @@ class BatchVerifier:
         so with the 128-lane tile a 64-vote QC at 32 steps x 128 lanes
         costs the same as 16 steps x 256 lanes, without ~600 lines of
         machinery."""
-        valid_host, arrays = self.prepare(messages, pubkeys, signatures)
-        return self._run_kernel, arrays, valid_host
+        valid_host, args = self.prepare(messages, pubkeys, signatures)
+        return self._run_wave, args, valid_host
 
     def prepare(
         self,
@@ -594,25 +587,15 @@ class BatchVerifier:
         pubkeys: list[bytes],
         signatures: list[bytes],
     ) -> tuple[np.ndarray, tuple]:
-        """Host-side batch preparation: decompressed-point lookups,
-        challenge hashing, limb/bit decomposition, shape padding —
-        vectorized with numpy so prep never outruns the device kernel.
-        Returns (host_validity[n], kernel_arrays) where kernel_arrays feed
-        ``_run_kernel`` directly.
-
-        Vectorized staging (ISSUE 5): buffers are preallocated at the
-        PADDED shape per worker thread and reused across waves, so a
-        wave costs one memset + block numpy ops; the only remaining
-        per-item Python is key decompression (cached, epoch-static) and
-        the SHA-512 challenge hash (no batch API on the host)."""
+        """Host-side batch preparation: the wave's one staging buffer,
+        preallocated at the PADDED shape per worker thread and reused
+        across waves (ISSUE 5), filled by one memset + block numpy ops
+        and the per-row work of ``_fill_rows``.  Returns
+        (host_validity[n], (device tables, buffer)): the arguments of
+        ``_run_wave``.  Windows, limbs and the key gather are the jitted
+        call's (``unpack_wave``)."""
         n = len(messages)
-        padded = next(p for p in self._padded_sizes() if p >= n)
-        bufs = self._scratch_for(padded)
-        sig_rows = bufs["sig"]
-        k_rows = bufs["k"]
-        r_sign = bufs["r_sign"]
-        idxs = bufs["idxs"]
-
+        buf = self._scratch_for(n)
         # malformed-length rejections (rare; everything else vectorizes)
         valid_host = np.array(
             [
@@ -622,17 +605,26 @@ class BatchVerifier:
             dtype=bool,
         )
         if valid_host.all():
-            sig_rows[:n] = np.frombuffer(
+            buf[:n, SIG_COLS] = np.frombuffer(
                 b"".join(signatures), np.uint8
             ).reshape(n, 64)
         else:
             for i in np.flatnonzero(valid_host):
-                sig_rows[i] = np.frombuffer(signatures[i], np.uint8)
+                buf[i, SIG_COLS] = np.frombuffer(signatures[i], np.uint8)
+        return self._fill_rows(buf, valid_host, messages, pubkeys, signatures)
 
+    def _fill_rows(self, buf, valid_host, messages, pubkeys, signatures):
+        """The tail ``prepare`` and ``prepare_packed`` share, over a
+        buffer that holds the signatures of the rows ``valid_host`` has
+        not refused yet: the exact host-side checks (s < L, a key that
+        decompresses to a point), each row's key index and challenge
+        scalar.  The only per-item Python left is the cached key lookup
+        and the SHA-512 challenge hash (no batch API on the host)."""
+        n = len(valid_host)
         # s >= L rejection, vectorized: lexicographic compare of each
         # scalar (big-endian view of sig[32:]) against L; rows equal to
         # L have no differing byte and are rejected too
-        s_be = sig_rows[:n, :31:-1]
+        s_be = buf[:n, 63:31:-1]
         diff = s_be != _L_BE
         any_diff = diff.any(axis=1)
         first = np.where(any_diff, diff.argmax(axis=1), 0)
@@ -642,20 +634,19 @@ class BatchVerifier:
         # insert marks the stacked build stale), THEN snapshot one
         # build — it post-dates this batch's inserts, so row_of covers
         # every valid pk here even if another thread rebuilds
-        # concurrently.  Index 0 is the zero dummy row: invalid items
-        # keep it, their scalars are zeroed below, and the kernel
-        # computes the identity while valid_host masks the lane out.
+        # concurrently.  Row 0 is the zero dummy: refused items keep it.
         for i in np.flatnonzero(valid_host):
             if pubkeys[i] not in self._point_cache:
                 self._neg_point(pubkeys[i])
         build = self._tables
         if build is None:
             build = self._rebuild_tables()
-        tables, row_of = build
+        row_of = build[1]
+        row_col = buf[:, ROW_COLS].view("<u4")[:, 0]
         for i in np.flatnonzero(valid_host):
             row = row_of.get(pubkeys[i], 0)
             if row:
-                idxs[i] = row
+                row_col[i] = row
             else:
                 valid_host[i] = False  # key decompresses to no point
 
@@ -667,72 +658,27 @@ class BatchVerifier:
             key = (signatures[i], pubkeys[i], messages[i])
             kb = memo.get(key)
             if kb is None:
-                k = ref.verify_challenge(
-                    signatures[i], pubkeys[i], messages[i]
-                )
-                kb = k.to_bytes(32, "little")
+                kb = ref.verify_challenge(*key).to_bytes(32, "little")
                 if len(memo) >= 8192:
                     memo.clear()
                 memo[key] = kb
-            k_rows[i] = np.frombuffer(kb, np.uint8)
-        bad = ~valid_host
-        if bad.any():
-            sig_rows[:n][bad] = 0  # zero scalars -> identity lanes
-        r_sign[:n] = sig_rows[:n, 31] >> 7
+            buf[i, K_COLS] = np.frombuffer(kb, np.uint8)
+        bad = np.flatnonzero(~valid_host)
+        if len(bad):
+            # a refused row rides as a pad row: the lane passes on the
+            # device and valid_host masks it out
+            buf[bad] = 0
+            buf[bad, 0] = 1
+        return valid_host, (self._device_build(build), buf)
 
-        # decompositions run at the padded shape directly — pad lanes
-        # are all-zero rows (s=0,k=0 -> P=identity, which compresses to
-        # y=1,sign=0; r_y gets the matching 'one' rows so pads pass)
-        s_bits = _bytes_to_windows_msb(sig_rows[:, 32:])
-        k_bits = _bytes_to_windows_msb(k_rows)
-        r_y = _bytes_rows_to_limbs(sig_rows[:, :32])
-        if padded > n:
-            r_y[n:, 0] = 1
-
-        # point rows by row id: device-resident gather when the staged
-        # committee table is usable (one [padded] index transfer instead
-        # of 4x[padded,20] coordinate rows), host fancy-index otherwise
-        if self.device_key_cache:
-            ax, ay, az, at = self._gather_device_rows(build, idxs)
-        else:
-            ax, ay, az, at = (t[idxs] for t in tables)
-
-        return valid_host, (
-            ax, ay, az, at, s_bits.T, k_bits.T, r_y, r_sign.copy(),
-        )
-
-    def _gather_device_rows(self, build, idxs):
-        """Device-side committee-key gather from the staged tables —
-        the mesh-sharded verifier overrides this so the gathered rows
-        land shard-aligned instead of on one device."""
-        return _gather_rows(self._device_build(build), idxs)
-
-    def _run_kernel(
-        self, ax, ay, az, at, s_bits, k_bits, r_y, r_sign, donate=False
-    ):
-        """Device dispatch — overridden by the mesh-sharded verifier.
-        ``donate=True`` selects the buffer-donating compilation of the
-        same kernel (callers must not reuse the staging arrays after);
-        the default keeps external stage() users, which may re-dispatch
-        the same staged arrays, on the non-consuming variant."""
-        if self.use_pallas:
-            kernel = (
-                _verify_kernel_pallas_donated
-                if donate
-                else _verify_kernel_pallas
-            )
-        else:
-            kernel = _verify_kernel_donated if donate else _verify_kernel
-        return kernel(
-            jnp.asarray(ax),
-            jnp.asarray(ay),
-            jnp.asarray(az),
-            jnp.asarray(at),
-            jnp.asarray(s_bits),
-            jnp.asarray(k_bits),
-            jnp.asarray(r_y),
-            jnp.asarray(r_sign),
-        )
+    def _run_wave(self, tables, buf, donate=False):
+        """Buffer on the host -> verdicts on the device: one transfer,
+        one jitted call.  The step the mesh-sharded verifier overrides
+        (rows placed shard-aligned).  ``donate=True`` selects the
+        buffer-donating compilation of the same entry; the host buffer
+        is this thread's to refill once the verdicts are back."""
+        self._count(h2d=1, calls=1)
+        return _wave_entry(self.use_pallas, donate)(tables, jax.device_put(buf))
 
     # -- VerifierBackend protocol (hotstuff_tpu.crypto.service) --------------
 

@@ -142,10 +142,13 @@ async def test_padded_wave_verdict_parity_across_mesh_sizes(m, monkeypatch):
 
 
 def test_sharded_gather_matches_in_specs_after_rebuild():
-    """After a committee REBUILD the staged gather still produces
-    coordinate rows sharded to match the shard_map in_specs (P('dp') on
-    the batch axis) and numerically identical to the single-device
-    verifier's rows for the new committee."""
+    """After a committee REBUILD the staged tables are still replicated
+    over the mesh and the wave's buffer still lands with its rows
+    sharded to match the shard_map in_specs (P('dp') on the batch axis),
+    so each device gathers its own slice; the gathered coordinate rows
+    are numerically identical to the single-device verifier's rows for
+    the new committee."""
+    import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from hotstuff_tpu.tpu.ed25519 import BatchVerifier
@@ -163,22 +166,33 @@ def test_sharded_gather_matches_in_specs_after_rebuild():
     v = ShardedBatchVerifier(mesh=default_mesh(4), min_device_batch=0)
     msgs_a, pks_a, sigs_a = batch(0x61)
     v.precompute(pks_a)
-    v.prepare(msgs_a, pks_a, sigs_a)  # stage committee A's tables
+    _, (tables_a, _) = v.prepare(msgs_a, pks_a, sigs_a)  # committee A's tables
 
     # rebuild: a NEW committee replaces the device-resident tables
     msgs_b, pks_b, sigs_b = batch(0x62)
     v.precompute(pks_b)
-    valid_host, arrays = v.prepare(msgs_b, pks_b, sigs_b)
-    assert valid_host.all()
+    valid_host, (tables, buf) = v.prepare(msgs_b, pks_b, sigs_b)
+    assert valid_host.all() and tables is not tables_a
 
+    replicated = NamedSharding(v.mesh, P())
+    for table in tables:
+        assert table.sharding.is_equivalent_to(replicated, table.ndim)
+    # the production entry (the psum word beside the lanes)
+    ok, bad = v._run_wave(tables, buf, psum_word=True)
     want = NamedSharding(v.mesh, P("dp"))
-    for row in arrays[:4]:  # ax, ay, az, at — the gathered point rows
-        assert row.sharding.is_equivalent_to(want, row.ndim)
+    assert ok.sharding.is_equivalent_to(want, ok.ndim)
+    assert np.asarray(ok).all() and int(bad) == 0
 
-    # numeric parity with the single-device verifier's prepare for the
-    # same committee/batch (same 16-entry padded shape on both grids)
+    # numeric parity with the single-device verifier for the same
+    # committee/batch (same 16-entry padded shape on both grids): the
+    # staged buffers alike, and the operands unpacked from them
+    from hotstuff_tpu.tpu.ed25519 import unpack_wave
+
     base = BatchVerifier(min_device_batch=0, use_pallas=False)
-    base.precompute(pks_b)
-    _, base_arrays = base.prepare(msgs_b, pks_b, sigs_b)
-    for got, ref in zip(arrays, base_arrays):
+    base.precompute(pks_a + pks_b)
+    _, (base_tables, base_buf) = base.prepare(msgs_b, pks_b, sigs_b)
+    np.testing.assert_array_equal(buf, base_buf)
+    unpack = jax.jit(unpack_wave)
+    arrays = unpack(tables, jax.device_put(buf, want))
+    for got, ref in zip(arrays, unpack(base_tables, base_buf)):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))

@@ -97,9 +97,9 @@ def test_mesh_pallas_interpret_256_votes():
     # host prep via the plain verifier, padded to 8 x 128 lanes
     prep = BatchVerifier(min_device_batch=0, use_pallas=False)
     prep.pad_sizes = (1024,)  # 128 lanes per device
-    valid_host, arrays = prep.prepare(msgs, pks, sigs)
+    valid_host, (tables, buf) = prep.prepare(msgs, pks, sigs)
     kernel = make_sharded_verify(default_mesh(), pallas=True, interpret=True)
-    out = np.asarray(kernel(*(jnp.asarray(a) for a in arrays)))[:n]
+    out = np.asarray(kernel(tables, jnp.asarray(buf)))[:n]
     out = out & valid_host
     expected = np.array([i not in {7, 130, 255} for i in range(n)])
     assert (out == expected).all()
