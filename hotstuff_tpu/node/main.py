@@ -35,8 +35,7 @@ LEVELS = [logging.ERROR, logging.WARNING, logging.INFO, logging.DEBUG]
 class _IdleSpanSelector(selectors.DefaultSelector):
     """The event loop's selector with its waiting named: a ``select``
     that may block is the ``loop.idle`` span, so in a profiler trace
-    the loop thread's busy time is the window less ``loop.idle``, and
-    what no other span covers of it is known (telemetry/spans.py)."""
+    the loop thread's busy time is the window less ``loop.idle``."""
 
     def select(self, timeout=None):
         if timeout is not None and timeout <= 0:
@@ -45,8 +44,36 @@ class _IdleSpanSelector(selectors.DefaultSelector):
             return super().select(timeout)
 
 
+class _SpannedEventLoop(asyncio.SelectorEventLoop):
+    """The loop ``run`` and ``run-many`` serve on, named in a profiler
+    trace: its waiting is ``loop.idle`` (the selector above), and while
+    a session is active every handle it runs is one ``cb`` span
+    (``spans.trace_callbacks``), so the busy time no layer span covers
+    is split into the callbacks that held it and the loop's own
+    machinery between them.  Once a pass it asks the profiler's switch
+    (the one ``spans.span`` reads) and swaps ``Handle._run`` only when
+    the switch has flipped: with no session the stdlib's ``_run`` runs
+    every callback."""
+
+    def __init__(self):
+        super().__init__(_IdleSpanSelector())
+        self._traced = False  # what this loop last gave trace_callbacks
+
+    def _process_events(self, event_list):
+        if _spans._tracing() != self._traced:
+            self._traced = not self._traced
+            _spans.trace_callbacks(self._traced)
+        super()._process_events(event_list)
+
+    def close(self):
+        if self._traced:
+            self._traced = False
+            _spans.trace_callbacks(False)
+        super().close()
+
+
 def _new_event_loop() -> asyncio.AbstractEventLoop:
-    return asyncio.SelectorEventLoop(_IdleSpanSelector())
+    return _SpannedEventLoop()
 
 
 async def _with_host_stats(serving) -> None:

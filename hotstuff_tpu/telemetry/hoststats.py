@@ -7,6 +7,7 @@ cumulative like ``Verify service stats`` (a reader takes last less
 first over its window)::
 
     Host stats: elapsed_s=35.004 cpu_user_s=30.21 cpu_sys_s=3.02
+      loop_cpu_s=24.80
       lag_samples=640 lag_mean_ms=1.25 lag_max_ms=41.7 gc2=1 gc2_s=0.038
       store_appends=5210 store_records=38877
       ancestor_hits=24310 ancestor_misses=0 sync_requests=0
@@ -15,6 +16,10 @@ first over its window)::
 - ``cpu_user_s`` / ``cpu_sys_s``: the process's CPU seconds, all threads
   (``os.times``).  Over a window's wall time they say whether a long
   round is the CPU's or a wait's.
+- ``loop_cpu_s``: the event-loop thread's own CPU seconds (its
+  ``pthread_getcpuclockid`` clock, taken when the probe starts on the
+  loop).  The loop's busy wall time less these is time it was runnable
+  but off a core: waiting for the interpreter's lock, or not scheduled.
 - ``lag_*``: the event-loop lag probe, the one probe the process has: a
   ``LAG_INTERVAL`` sleep wakes late by the time the loop was busy or
   the process was not scheduled.  ``lag_samples`` and ``lag_mean_ms``
@@ -55,6 +60,7 @@ import asyncio
 import gc
 import logging
 import os
+import threading
 import time
 
 from ..network.wan import WAN_COUNTS
@@ -79,6 +85,7 @@ class HostStats:
         self.gc2 = 0
         self.gc2_s = 0.0
         self._gc2_t0: float | None = None
+        self._loop_clock: int | None = None  # set by ``run`` on the loop
 
     def _on_gc(self, phase: str, info: dict) -> None:
         if info.get("generation") != 2:
@@ -112,9 +119,14 @@ class HostStats:
         cpu = os.times()
         mean = self.lag_total_s / self.lag_samples if self.lag_samples else 0.0
         lag_max, self._lag_max_line_s = self._lag_max_line_s, 0.0
+        loop_cpu = (
+            "" if self._loop_clock is None
+            else f"loop_cpu_s={time.clock_gettime(self._loop_clock):.3f} "
+        )
         return (
             f"elapsed_s={time.monotonic() - self.started:.3f} "
             f"cpu_user_s={cpu.user:.3f} cpu_sys_s={cpu.system:.3f} "
+            f"{loop_cpu}"
             f"lag_samples={self.lag_samples} lag_mean_ms={mean * 1e3:.3f} "
             f"lag_max_ms={lag_max * 1e3:.3f} "
             f"gc2={self.gc2} gc2_s={self.gc2_s:.4f} "
@@ -133,6 +145,7 @@ class HostStats:
         line every ``LOG_INTERVAL``; cancelled at shutdown."""
         logger = logger or log
         loop = asyncio.get_running_loop()
+        self._loop_clock = time.pthread_getcpuclockid(threading.get_ident())
         next_log = loop.time() + LOG_INTERVAL
         gc.callbacks.append(self._on_gc)
         try:

@@ -74,10 +74,21 @@ registry, and — when a journal is attached — an ``{"e":"span"}`` record
 whose ``u`` field carries the duration, rendered by
 ``benchmark/traces.py`` as a per-node "verify pipeline" Perfetto track
 aligned with the consensus rounds.
+
+The event loop's own callbacks (``trace_callbacks``): while a session is
+active the node's loop (``node/main.py``) has every handle it runs
+entered as one ``cb`` annotation carrying ``kind`` (``task`` / ``io`` /
+``timer`` / ``call``, read as ``cb.task`` ... ``cb.call``) and
+``name=<qualname>``, both set once the annotation has started, so the
+layer spans nest inside the callback that ran them, what lies between
+the spans has a name, and the classifying is the callback's time.
+With no session ``asyncio.events.Handle._run`` is the stdlib's own
+function: nothing is paid per callback.
 """
 
 from __future__ import annotations
 
+import asyncio
 import os
 import sys
 import threading
@@ -201,6 +212,55 @@ def attach_journal(journal) -> None:
         _SINK = lambda stage, dur_ns: journal.record(
             "span", 0, None, stage, dur_ns=dur_ns
         )
+
+
+# ---- the event loop's callbacks ------------------------------------------
+
+#: the stdlib's own ``Handle._run`` (``TimerHandle`` inherits it): what
+#: every callback runs through while no profiler session is active
+_HANDLE_RUN = asyncio.events.Handle._run
+
+
+#: the one annotation a callback is entered as; its kind (``task``,
+#: ``io``, ``timer``, ``call``) and name are set inside it, so that the
+#: probe's own work lies inside the span it measures, not between spans
+CALLBACK = "cb"
+_TRANSPORT = asyncio.selector_events._SelectorTransport
+
+
+def _qualname(obj) -> str:
+    return getattr(obj, "__qualname__", None) or type(obj).__qualname__
+
+
+def _callback_kind(handle) -> tuple[str, str]:
+    """``(kind, name)`` of a handle's run: a Task's step or wake-up (its
+    coroutine's qualname), a timer, a selector transport's or the loop's
+    own reader or writer (``_read_ready``, ``_write_ready``, the
+    self-pipe's ``_read_from_self``, ``_accept_connection``), or any
+    other ready handle."""
+    callback = handle._callback
+    owner = getattr(callback, "__self__", None)
+    if isinstance(owner, asyncio.Task):
+        return "task", _qualname(owner.get_coro())
+    if isinstance(handle, asyncio.TimerHandle):
+        return "timer", _qualname(callback)
+    if owner is handle._loop or isinstance(owner, _TRANSPORT):
+        return "io", _qualname(callback)
+    return "call", _qualname(callback)
+
+
+def _traced_handle_run(handle) -> None:
+    with _ANNOTATION(CALLBACK) as note:
+        kind, name = _callback_kind(handle)
+        note.set_metadata(kind=kind, name=name)
+        _HANDLE_RUN(handle)
+
+
+def trace_callbacks(on: bool) -> None:
+    """Enter every handle any loop of the process runs as a ``cb.*``
+    span (``on``), or give ``Handle._run`` back to the stdlib.  The
+    node's loop calls this when the profiler's switch flips."""
+    asyncio.events.Handle._run = _traced_handle_run if on else _HANDLE_RUN
 
 
 class _Span:
@@ -339,4 +399,5 @@ __all__ = [
     "enable",
     "disable",
     "attach_journal",
+    "trace_callbacks",
 ]

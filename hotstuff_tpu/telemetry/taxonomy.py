@@ -106,6 +106,19 @@ SPAN_HOST_STAGES: tuple = (
     "loop.idle",
 )
 
+#: the event loop's callbacks, one ``cb`` annotation a handle it runs
+#: while a profiler session is active (``telemetry/spans.py``
+#: trace_callbacks, the loop of ``node/main.py``), its ``kind`` and
+#: ``name=<qualname>`` set inside it; ``chipbench/loopcalls.py`` reads
+#: it under these names.  No layer's: the layer spans nest inside them,
+#: and ``chipbench/hostspans.py`` drops them (no ``cb`` prefix there).
+SPAN_LOOP_CALLBACKS: tuple = (
+    "cb.task",  # a Task's step or wake-up: name is the coroutine's
+    "cb.io",  # a reader / writer callback the selector reported ready
+    "cb.timer",  # a TimerHandle: call_later / call_at, asyncio.sleep
+    "cb.call",  # any other ready handle: done-callbacks, threadsafe calls
+)
+
 #: every registered span stage name (what ``span("...")`` /
 #: ``rec.add("...")`` call sites are checked against)
 SPAN_STAGES: frozenset = frozenset(
@@ -114,6 +127,7 @@ SPAN_STAGES: frozenset = frozenset(
     + SPAN_ANNOTATION_STAGES
     + SPAN_AGG_STAGES
     + SPAN_HOST_STAGES
+    + SPAN_LOOP_CALLBACKS
 )
 
 # ---- journal edges (telemetry/journal.py records) --------------------------
@@ -276,6 +290,7 @@ __all__ = [
     "SPAN_ANNOTATION_STAGES",
     "SPAN_AGG_STAGES",
     "SPAN_HOST_STAGES",
+    "SPAN_LOOP_CALLBACKS",
     "SPAN_STAGES",
     "BLOCK_EDGES",
     "CONTROL_EDGES",
