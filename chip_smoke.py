@@ -327,8 +327,9 @@ def _check_lanes(device, shapes) -> dict:
     steady = {}
     for shape in shapes:
         batch = (msgs[:shape], pks[:shape], sigs[:shape])
-        # the computation production dispatches at this shape (already
-        # traced and lowered by the warm-up) holds a Mosaic custom call:
+        # the computation production dispatches at this shape (the
+        # program the warm-up built or loaded from the executable store
+        # compiles exactly this lowering) holds a Mosaic custom call:
         # not the XLA kernel, not interpret mode
         _, (tables, buf) = device.prepare(*batch)
         lowered = kernel.lower(tables, jnp.asarray(buf))

@@ -37,15 +37,14 @@ import jax.numpy as jnp
 
 from ..crypto import ed25519_ref as ref
 from ..telemetry import spans as _spans
-from . import curve, field as F
+from . import curve, exe_store as _exe_store, field as F
 
 # Padded batch shapes (powers of 4) to bound compilation count.
 PAD_SIZES = (1, 4, 16, 64, 256, 1024, 4096)
 
 # Pallas pad shapes: lane-aligned, capped at 1024 per dispatch (larger
-# batches chunk).  Each shape is traced, lowered and compiled on its
-# own; only the compile is a load from the persistent cache after the
-# first process (seconds per shape on a v5e: CHANGES.md, ISSUE 22).
+# batches chunk).  Each shape's program is built once and then loaded
+# from the executable store by every later process (tpu/exe_store.py).
 PALLAS_PAD_SIZES = (128, 256, 1024)
 
 
@@ -177,7 +176,12 @@ class BatchVerifier:
     ``min_device_batch=0`` to force everything onto the device (tests
     do, so the kernel path is what's exercised)."""
 
-    def __init__(self, min_device_batch: int = 64, use_pallas: bool | None = None):
+    def __init__(
+        self,
+        min_device_batch: int = 64,
+        use_pallas: bool | None = None,
+        exe_dir: str | None = None,
+    ):
         # pk bytes -> (ax, ay, az, at) limb rows of the negated point, or None
         self._point_cache: dict[bytes, tuple | None] = {}
         # Vectorized prepare path: pk bytes -> row index into the stacked
@@ -247,6 +251,15 @@ class BatchVerifier:
         self._cpu = None  # lazy CpuVerifier for small batches
         # pad shape -> FirstCallTimer.take() of its warmup call
         self.warm_report: dict[int, dict] = {}
+        # (donate, buffer shape, table shape) -> the program a wave of
+        # those shapes runs; exe_dir names the executable store's
+        # directory (None: beside the compile cache, Pallas only), and
+        # _exe_report is what the store said of the last program it gave
+        self._programs: dict[tuple, object] = {}
+        self._program_lock = threading.Lock()
+        self._exe_dir = exe_dir
+        self._exe_store = None
+        self._exe_report: dict | None = None
 
     @property
     def use_pallas(self) -> bool:
@@ -281,12 +294,13 @@ class BatchVerifier:
             self._neg_point(pk)
 
     def warmup(self, batch: int | None = None) -> None:
-        """Compile (or cache-load) the device kernel BEFORE entering the
+        """Compile (or load) the device kernel BEFORE entering the
         consensus hot path.  The first call at each pad shape costs
-        seconds to tens of seconds even on a cache hit — paid here,
-        once, at node boot, instead of on the first QC verify where it
-        would blow through the round timeout.  ``warm_report`` keeps
-        where each shape's seconds went.
+        seconds to tens of seconds unless the executable store holds
+        its program — paid here, once, at node boot, instead of on the
+        first QC verify where it would blow through the round timeout.
+        ``warm_report`` keeps where each shape's seconds went, and
+        whether its program was loaded or built (``exe``).
 
         ``batch`` is the largest batch the caller expects (the committee
         size: QC/TC verification batches never exceed it) — warming the
@@ -335,14 +349,30 @@ class BatchVerifier:
         sizes = [p for p in grid if floor <= p <= ceiling] or [n]
         from . import FirstCallTimer
 
+        # one lane's R with a bit flipped: the host takes it, only the
+        # kernel can refuse it, so a program that answers "all valid"
+        # (or "all invalid") stops the boot here
+        forged = bytes([sig[0] ^ 1]) + sig[1:]
         with FirstCallTimer() as timer:
             for size in sizes:
-                out = self.verify([msg] * size, [pk] * size, [sig] * size)
-                if not out.all():
+                want = np.arange(size) != size // 2
+                sigs = [sig if ok else forged for ok in want]
+                self._exe_report = None
+                out = self.verify([msg] * size, [pk] * size, sigs)
+                wrong = np.flatnonzero(out != want)
+                if len(wrong):
                     raise RuntimeError(
-                        "verifier warmup produced invalid result"
+                        f"verifier warmup at {size} lanes: wrong verdicts "
+                        f"at lanes {wrong[:8].tolist()} (forged: {size // 2})"
                     )
-                self.warm_report[size] = timer.take()
+                report = timer.take()
+                if self._exe_report is not None:
+                    report.update(self._exe_report)
+                    if self._exe_report["exe"] == "loaded":
+                        # out of the cache directory with no backend
+                        # compile: what cache_hits exists to say
+                        report.update(cache_hits=1, cache_misses=0)
+                self.warm_report[size] = report
 
     def _neg_point(self, pk: bytes):
         hit = self._point_cache.get(pk)
@@ -673,12 +703,55 @@ class BatchVerifier:
 
     def _run_wave(self, tables, buf, donate=False):
         """Buffer on the host -> verdicts on the device: one transfer,
-        one jitted call.  The step the mesh-sharded verifier overrides
+        one compiled call.  The step the mesh-sharded verifier overrides
         (rows placed shard-aligned).  ``donate=True`` selects the
         buffer-donating compilation of the same entry; the host buffer
         is this thread's to refill once the verdicts are back."""
         self._count(h2d=1, calls=1)
-        return _wave_entry(self.use_pallas, donate)(tables, jax.device_put(buf))
+        buf = jax.device_put(buf)
+        return self._wave_program(tables, buf, donate)(tables, buf)
+
+    def _wave_program(self, tables, buf, donate):
+        """The program a wave of these shapes runs: the jitted entry
+        itself, or where the executable store is engaged
+        (``exe_store``) its compiled program for these shapes, loaded
+        from disk or built once and kept there."""
+        key = (donate, buf.shape, tables[0].shape)
+        program = self._programs.get(key)
+        if program is None:
+            with self._program_lock:
+                program = self._programs.get(key)
+                if program is None:
+                    program = self._programs[key] = self._load_or_build(
+                        tables, buf, donate
+                    )
+        return program
+
+    def _load_or_build(self, tables, buf, donate):
+        entry = _wave_entry(self.use_pallas, donate)
+        store = self.exe_store
+        if store is None:
+            return entry
+        key = _exe_store.key(
+            "ed25519.wave", (tables, buf), pallas=self.use_pallas, donate=donate
+        )
+        program, self._exe_report = store.get(
+            key, lambda: entry.lower(tables, buf).compile()
+        )
+        return program
+
+    @property
+    def exe_store(self) -> "_exe_store.ExecutableStore | None":
+        """Where compiled wave programs are kept across processes: the
+        directory given to the constructor, else beside the compile
+        cache on the Pallas entry (the TPU backend) only; None keeps the
+        plain jitted entry."""
+        if self._exe_store is None:
+            root = self._exe_dir
+            if root is None and self.use_pallas:
+                root = _exe_store.default_dir()
+            self._exe_store = _exe_store.ExecutableStore(root) if root else False
+        return self._exe_store or None
 
     # -- VerifierBackend protocol (hotstuff_tpu.crypto.service) --------------
 
