@@ -30,10 +30,11 @@ from __future__ import annotations
 
 import logging
 import os
-import sys
 
 from ..crypto import Digest, PublicKey, Signature
 from ..crypto.service import VerifierBackend
+from ..telemetry import spans as _spans
+from ..telemetry.blsstats import BLS_COUNTS
 from .config import Committee
 from .errors import AuthorityReuse, ConsensusError, InvalidSignature, UnknownAuthority
 from .messages import QC, TC, Round, Timeout, Vote, make_signer_bitmap
@@ -64,23 +65,25 @@ class _SigAccumulator:
     lands — O(1) marginal work per vote instead of an O(n) sum at QC
     formation.
 
-    The sum runs on DEVICE (``tpu.bls.TpuG1RunningSum``, one fixed-shape
-    ``point_add`` dispatch per vote) when an accelerator backend is live
-    or HOTSTUFF_AGG_DEVICE_SUM=1 forces it; otherwise an incremental
-    host Jacobian add.  Per-signature decompress skips the r-torsion
-    ladder — the emitted aggregate is subgroup-checked by every verifier
-    (the same soundness argument as ``BlsVerifier.verify_shared_msg``).
+    Where the sum runs follows the verifier the node was given: on
+    DEVICE (``tpu.bls.TpuG1RunningSum``, one fixed-shape ``point_add``
+    dispatch per vote) under the device aggregator (``--verifier tpu``,
+    ``BlsVerifier.sums_on_device``), else an incremental host Jacobian
+    add.  HOTSTUFF_AGG_DEVICE_SUM=1/0 forces either.  Per-signature
+    decompress skips the r-torsion ladder — the emitted aggregate is
+    subgroup-checked by every verifier (the same soundness argument as
+    ``BlsVerifier.verify_shared_msg``).
 
     ``count`` mirrors the number of accumulated signatures; the owning
     cell compares it against its vote list to detect evict/replace
     divergence and rebuilds from the surviving votes (rare, adversarial
     path)."""
 
-    def __init__(self):
+    def __init__(self, verifier: VerifierBackend | None = None):
         self.count = 0
         self._device = None
         self._host = None
-        if "jax" in sys.modules and self._want_device():
+        if _sum_on_device(verifier):
             try:
                 from ..tpu.bls import TpuG1RunningSum
 
@@ -92,43 +95,42 @@ class _SigAccumulator:
 
             self._host = G1Point.identity()
 
-    @staticmethod
-    def _want_device() -> bool:
-        env = os.environ.get("HOTSTUFF_AGG_DEVICE_SUM", "").strip().lower()
-        if env:
-            return env not in ("0", "off", "no", "false")
-        try:
-            import jax
-
-            return jax.default_backend() in ("tpu", "gpu")
-        except Exception:  # noqa: BLE001
-            return False
-
     def add(self, sig: Signature) -> bool:
         """Accumulate one signature; False when it doesn't decompress
         (a spoofed blob — the cell falls back to rebuild-at-quorum)."""
         from ..crypto.bls.curve import G1Point
 
-        pt = G1Point.from_bytes(sig.to_bytes(), subgroup_check=False)
+        with _spans.span("bls.decode"):
+            pt = G1Point.from_bytes(sig.to_bytes(), subgroup_check=False)
         if pt is None:
             return False
         if self._device is not None:
             self._device.add(pt)
+            BLS_COUNTS.add("device_adds")
         else:
             self._host = self._host + pt
+            BLS_COUNTS.add("host_adds")
         self.count += 1
         return True
 
     def aggregate(self) -> bytes | None:
         """The compressed 48-byte aggregate, or None for the empty sum."""
-        pt = (
-            self._device.snapshot()
-            if self._device is not None
-            else self._host
-        )
+        if self._device is not None:
+            pt = self._device.snapshot()
+            BLS_COUNTS.add("snapshots")
+        else:
+            pt = self._host
         if pt.inf:
             return None
         return pt.to_bytes()
+
+
+def _sum_on_device(verifier) -> bool:
+    """HOTSTUFF_AGG_DEVICE_SUM when set, else what the verifier says."""
+    env = os.environ.get("HOTSTUFF_AGG_DEVICE_SUM", "").strip().lower()
+    if env:
+        return env not in ("0", "off", "no", "false")
+    return bool(getattr(verifier, "sums_on_device", False))
 
 
 class AggregationBounds(ConsensusError):
@@ -208,7 +210,7 @@ class QCMaker:
             # O(1) marginal work per vote: the aggregate signature is
             # ready the moment quorum lands (ISSUE 9)
             if self._acc is None:
-                self._acc = _SigAccumulator()
+                self._acc = _SigAccumulator(verifier)
             self._acc.add(vote.signature)  # failure -> count diverges,
             # _compact_qc rebuilds from the (verified) survivors
         self.weight += stake
@@ -227,13 +229,16 @@ class QCMaker:
 
         self.verified = True
         self.weight = 0  # a QC is made at most once
+        BLS_COUNTS.add("qcs")
         if _compact_enabled(committee):
-            qc = self._compact_qc(vote, committee)
+            qc = self._compact_qc(vote, committee, verifier)
             if qc is not None:
                 return qc
         return QC(hash=vote.hash, round=vote.round, votes=list(self.votes))
 
-    def _compact_qc(self, vote: Vote, committee: Committee) -> QC | None:
+    def _compact_qc(
+        self, vote: Vote, committee: Committee, verifier: VerifierBackend
+    ) -> QC | None:
         """Emit the constant-size form: one aggregate signature + signer
         bitmap.  None (vote-list fallback) when the signer set doesn't
         map onto the committee bitmap or no aggregate can be formed —
@@ -248,7 +253,7 @@ class QCMaker:
             # evict/replace (or a non-decompressing spoof) diverged the
             # running sum from the vote list: rebuild from the survivors
             # — all of them just passed verification
-            acc = _SigAccumulator()
+            acc = _SigAccumulator(verifier)
             if not all(acc.add(sig) for _, sig in self.votes):
                 return None
             self._acc = acc
@@ -257,6 +262,16 @@ class QCMaker:
             return None
         if self.owner is not None:
             self.owner.compact_qcs += 1
+        BLS_COUNTS.add("compact_qcs")
+        # NOTE: scraped (chipbench/readers/bls.py checks every aggregate
+        # against its own sum of the signers' vote signatures)
+        log.info(
+            "Compact QC round %d signers %s agg %s sigs %s",
+            vote.round,
+            bitmap.hex(),
+            agg.hex(),
+            ",".join(sig.to_bytes().hex() for _, sig in self.votes),
+        )
         return QC(
             hash=vote.hash,
             round=vote.round,

@@ -21,6 +21,8 @@ import ctypes
 import os
 import subprocess
 
+from ...telemetry.blsstats import BLS_COUNTS
+
 _LIB_NAME = "libhs_bls.so"
 
 
@@ -131,6 +133,7 @@ def verify_one(
     individually checked committee keys)."""
     if len(pk96) != 96 or len(sig48) != 48:
         return False
+    BLS_COUNTS.add("pairings")
     return bool(
         _lib.hs_bls_verify_one_ex(
             message, len(message), pk96, sig48, 1 if check_pk_subgroup else 0
@@ -174,6 +177,7 @@ def verify_batch(
     weights = b"".join(
         (secrets.randbits(128) | 1).to_bytes(16, "little") for _ in range(n)
     )
+    BLS_COUNTS.add("pairings")
     return bool(
         _lib.hs_bls_verify_batch(
             b"".join(digests32),
@@ -226,6 +230,7 @@ def verify_batch_points(
         return False
     if any(len(p) != 96 for p in pks96):
         return False
+    BLS_COUNTS.add("pairings")
     return bool(
         _lib.hs_bls_verify_batch_points(
             whm96, b"".join(pks96), n, agg96, 1 if check_pk_subgroup else 0

@@ -41,6 +41,7 @@ verify path of crypto/src/lib.rs:186-257 (BASELINE config 5).
 
 from __future__ import annotations
 
+from ...telemetry.blsstats import BLS_COUNTS
 from .curve import G1Point, G2Point
 from .fields import P, R, X, Fq2, Fq6, Fq12
 
@@ -192,6 +193,7 @@ def pairings_equal(
     """e(P1, Q1) == e(P2, Q2) via one product: e(P1,Q1)·e(-P2,Q2) == 1 —
     shares the final exponentiation between the two Miller loops (the
     fixed cube preserves the equality: g³ = 1 ⇔ g = 1 in the r-group)."""
+    BLS_COUNTS.add("pairings")
     f = miller_loop(p1, q1) * miller_loop(-p2, q2)
     return final_exponentiation(f) == Fq12.ONE
 

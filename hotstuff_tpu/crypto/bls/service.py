@@ -15,18 +15,87 @@ crypto/src/lib.rs:232-257; BASELINE config 5's threshold variant uses
 
 from __future__ import annotations
 
+import json
+import logging
+import time
+
 from ...telemetry import spans as _spans
+from ...telemetry.blsstats import BLS_COUNTS
 from . import (
+    _POP_DST,
     BlsPublicKey,
     BlsSecretKey,
     BlsSignature,
     aggregate_public_keys,
     keygen,
+    verify_possession,
 )
+
+log = logging.getLogger(__name__)
+
+#: the process's decoded committee keys: 96 key bytes -> the decoded
+#: point, or None for bytes that do not decode.  Every ``BlsVerifier``
+#: of the process reads it, so a co-located committee decodes each key
+#: once and not once a node (a G2 decode is ~13 ms of pure Python).
+#: Bounded like a verifier's ``_agg_pk_cache``: emptied when full.
+_PK_CACHE: dict[bytes, BlsPublicKey | None] = {}
+_PK_CACHE_MAX = 4096
+
+#: ``(public key, proof)`` byte pairs whose proof of possession verified
+#: in this process, keyed by their exact bytes: a co-located committee
+#: checks each member's proof once and not once a node.  Only passes are
+#: kept, so a pair that failed is checked again, and fails again, every
+#: time it is asked about.
+_POP_PASSED: set[tuple[bytes, bytes]] = set()
+_POP_PASSED_MAX = 4096
+
+
+def decoded_key(pk_bytes: bytes) -> BlsPublicKey | None:
+    """The committee key ``pk_bytes`` decoded (subgroup-checked), from
+    the process's cache."""
+    if pk_bytes not in _PK_CACHE:
+        if len(_PK_CACHE) >= _PK_CACHE_MAX:
+            _PK_CACHE.clear()
+        _PK_CACHE[pk_bytes] = BlsPublicKey.from_bytes(pk_bytes)
+    return _PK_CACHE[pk_bytes]
+
+
+def possession_holds(pk: bytes, pop: bytes, native=None) -> bool:
+    """One check of a proof of possession, no memo: through the native
+    verifier when ``native`` is its module (the proof's message is
+    ``_POP_DST + pk`` under the same ``hash_to_g1``; key and proof
+    subgroup-checked, the identity refused), else pure Python
+    (``verify_possession``)."""
+    if native is not None:
+        return native.verify_one(_POP_DST + pk, pk, pop)
+    pub = decoded_key(pk)
+    proof = BlsSignature.from_bytes(pop)
+    return pub is not None and proof is not None and verify_possession(pub, proof)
+
+
+def check_possession(pk: bytes, pop: bytes) -> bool:
+    """Whether ``pop`` proves possession of the secret of the 96-byte
+    key ``pk``, checked once a process for each pair of exact bytes
+    (``consensus/config.py`` ``Committee.verify_pops`` asks for every
+    member at every ``Consensus.spawn``)."""
+    key = (bytes(pk), bytes(pop))
+    if key in _POP_PASSED:
+        return True
+    try:
+        from . import native
+    except ImportError:
+        native = None
+    if not possession_holds(*key, native=native):
+        return False
+    if len(_POP_PASSED) >= _POP_PASSED_MAX:
+        _POP_PASSED.clear()
+    _POP_PASSED.add(key)
+    return True
 
 
 class BlsVerifier:
-    """VerifierBackend over BLS bytes; caches decoded public keys.
+    """VerifierBackend over BLS bytes; decoded keys come from the
+    process's cache (``decoded_key``).
 
     ``aggregator="tpu"`` runs the G1 signature sum on device
     (hotstuff_tpu/tpu/bls.py — the psum-shaped reduction of
@@ -49,8 +118,14 @@ class BlsVerifier:
     name = "bls-cpu"
     prefers_aggregate = True
 
+    #: verifier names whose G1 programs ``warmup`` has compiled or loaded
+    #: in this process: jax keeps one compiled program a shape for the
+    #: whole process, however many nodes build a verifier
+    _warm: set[str] = set()
+
     def __init__(self, aggregator: str = "cpu"):
-        self._pk_cache: dict[bytes, BlsPublicKey | None] = {}
+        BLS_COUNTS.active = True
+        self._committee_size = 0
         # signer-set digest -> aggregated G2 key (compact-QC verify);
         # bounded in verify_aggregate_msg
         self._agg_pk_cache: dict[bytes, BlsPublicKey] = {}
@@ -170,14 +245,51 @@ class BlsVerifier:
             b"".join(ser(p) for p in whm), pb, ser(agg)
         )
 
-    def _pk(self, pk_bytes: bytes) -> BlsPublicKey | None:
-        if pk_bytes not in self._pk_cache:
-            self._pk_cache[pk_bytes] = BlsPublicKey.from_bytes(pk_bytes)
-        return self._pk_cache[pk_bytes]
-
     def precompute(self, pubkeys: list[bytes]) -> None:
+        self._committee_size = len(pubkeys)
         for pk in pubkeys:
-            self._pk(pk)
+            decoded_key(pk)
+
+    @property
+    def sums_on_device(self) -> bool:
+        """Whether a QC maker's running sum of vote signatures belongs
+        on the device: with the device aggregator, the verifier a node
+        runs under ``--verifier tpu`` (``consensus/aggregator.py``)."""
+        return self._tpu_agg is not None
+
+    def warmup(self, batch: int | None = None) -> None:
+        """Compile or load, before the node binds its port, every G1
+        program a committee of this size can dispatch: the running-sum
+        add of one vote, and the aggregation tree at each pad bucket up
+        to the committee's size (``batch``, or the keys ``precompute``
+        was given, whichever is smaller).  Once a process; the CPU
+        verifier has none.  Each program's result is checked against
+        the host's sum, and the ``Device verifier [...] warm in`` line
+        says where each shape's seconds went."""
+        if self._tpu_agg is None or self.name in self._warm:
+            return
+        from ...tpu import device_info
+        from ...tpu.bls import warm_g1_programs
+
+        sizes = [n for n in (batch, self._committee_size) if n]
+        t0 = time.perf_counter()
+        report = warm_g1_programs(self._tpu_agg, min(sizes, default=1))
+        self._warm.add(self.name)
+        # NOTE: this log entry is part of the benchmark log-scrape
+        # contract (chipbench/readers/verifier.py, as for ed25519)
+        log.info(
+            "Device verifier [%s] warm in %.1f s: %s",
+            self.name,
+            time.perf_counter() - t0,
+            json.dumps(
+                {
+                    **device_info(),
+                    "kernel": "g1-xla",
+                    "pad_shapes": [int(k) for k in report if k.isdigit()],
+                    "warm": report,
+                }
+            ),
+        )
 
     def verify_one(self, digest, pk, sig) -> bool:
         pk_b = pk if isinstance(pk, bytes) else pk.to_bytes()
@@ -185,7 +297,7 @@ class BlsVerifier:
         msg = digest if isinstance(digest, bytes) else digest.to_bytes()
         if self._native_verify is not None:
             return self._native_verify(msg, pk_b, sig_b)
-        pub = self._pk(pk_b)
+        pub = decoded_key(pk_b)
         s = BlsSignature.from_bytes(sig_b)
         return pub is not None and s is not None and pub.verify(msg, s)
 
@@ -213,7 +325,7 @@ class BlsVerifier:
             # the cache already paid once per epoch)
             pubs, sig_bytes = [], []
             for pk, sig in votes:
-                pub = self._pk(pk if isinstance(pk, bytes) else pk.to_bytes())
+                pub = decoded_key(pk if isinstance(pk, bytes) else pk.to_bytes())
                 if pub is None:
                     return False
                 pubs.append(pub)
@@ -230,7 +342,7 @@ class BlsVerifier:
                 )
         pks, sig_points = [], []
         for pk, sig in votes:
-            pub = self._pk(pk if isinstance(pk, bytes) else pk.to_bytes())
+            pub = decoded_key(pk if isinstance(pk, bytes) else pk.to_bytes())
             s = G1Point.from_bytes(
                 sig if isinstance(sig, bytes) else sig.to_bytes(),
                 subgroup_check=False,
@@ -268,7 +380,8 @@ class BlsVerifier:
         aggregated wire form): the signers' public keys — gathered from
         the signer bitmap by the caller — are summed once, then ONE
         pairing equality checks the pre-aggregated 48-byte signature,
-        regardless of committee size.
+        regardless of committee size.  Counted as ``agg_verifies``, and
+        as ``agg_failures`` where it does not verify.
 
         Unlike ``verify_shared_msg`` the aggregate signature arrives
         off the WIRE (adversary-controlled), so it is subgroup-checked
@@ -277,6 +390,13 @@ class BlsVerifier:
         key SUM is memoized by signer-set digest — under steady state
         every QC carries the same (or one of a few) quorum bitmaps, so
         repeat certificates skip the G2 sum and pay only the pairing."""
+        ok = self._verify_aggregate_msg(digest, pks, agg_sig)
+        BLS_COUNTS.add("agg_verifies")
+        if not ok:
+            BLS_COUNTS.add("agg_failures")
+        return ok
+
+    def _verify_aggregate_msg(self, digest, pks, agg_sig) -> bool:
         msg = digest if isinstance(digest, bytes) else digest.to_bytes()
         sig_b = (
             agg_sig if isinstance(agg_sig, bytes) else agg_sig.to_bytes()
@@ -296,7 +416,7 @@ class BlsVerifier:
             with _spans.span("agg.gather"):
                 pubs = []
                 for pb in pk_bytes:
-                    pub = self._pk(pb)
+                    pub = decoded_key(pb)
                     if pub is None:
                         return False
                     pubs.append(pub)
@@ -336,7 +456,7 @@ class BlsVerifier:
         for d, idxs in groups.items():
             pubs = []
             for i in idxs:
-                pub = self._pk(pb[i])
+                pub = decoded_key(pb[i])
                 if pub is None:
                     return None
                 pubs.append(pub)
@@ -437,7 +557,7 @@ class BlsVerifier:
             ]
         entries = []
         for d, p, s in zip(digests, pks, sigs):
-            pub = self._pk(p if isinstance(p, bytes) else p.to_bytes())
+            pub = decoded_key(p if isinstance(p, bytes) else p.to_bytes())
             sig = BlsSignature.from_bytes(
                 s if isinstance(s, bytes) else s.to_bytes()
             )
@@ -455,6 +575,7 @@ class BlsVerifier:
             for (msg, pk_pt, _), r in zip(entries, weights):
                 f = f * miller_loop(hash_to_g1(msg)._mul_raw(r), pk_pt)
             f = f * miller_loop(-agg, G2Point.generator())
+            BLS_COUNTS.add("pairings")
             if final_exponentiation(f) == Fq12.ONE:
                 return [True] * n
         return [
@@ -472,6 +593,7 @@ class BlsSigningService:
     BLS material through the identical protocol types."""
 
     def __init__(self, secret: BlsSecretKey | bytes):
+        BLS_COUNTS.active = True
         if isinstance(secret, (bytes, bytearray)):
             secret = BlsSecretKey(int.from_bytes(bytes(secret), "big"))
         self._sk: BlsSecretKey | None = secret
@@ -486,11 +608,23 @@ class BlsSigningService:
         if self._closed or self._sk is None:
             raise RuntimeError("BlsSigningService is shut down")
         msg = digest if isinstance(digest, bytes) else digest.to_bytes()
-        return Signature(self._sk.sign(msg).to_bytes())
+        # core.sign as the ed25519 service's, so the consensus layer
+        # holds the signing of either scheme; bls.sign inside it for
+        # the BLS reader
+        with _spans.span("core.sign"), _spans.span("bls.sign"):
+            sig = self._sk.sign(msg).to_bytes()
+        BLS_COUNTS.add("signs")
+        return Signature(sig)
 
     def shutdown(self) -> None:
         self._closed = True
         self._sk = None
 
 
-__all__ = ["BlsVerifier", "BlsSigningService", "keygen"]
+__all__ = [
+    "BlsVerifier",
+    "BlsSigningService",
+    "check_possession",
+    "decoded_key",
+    "keygen",
+]

@@ -50,8 +50,10 @@ first over its window)::
 
 A pause in which this process and another both stand still shows as one
 ``lag_max_ms`` the size of the pause with neither ``gc2_s`` nor CPU
-seconds behind it.  ``telemetry/__init__.py`` reads the lag keys of its
-snapshot from here; ``chipbench/readers/hoststats.py`` reads the line.
+seconds behind it.  A process that runs BLS nodes prints its ``BLS
+stats:`` line (``telemetry/blsstats.py``) right after this one.
+``telemetry/__init__.py`` reads the lag keys of its snapshot from here;
+``chipbench/readers/hoststats.py`` reads the line.
 """
 
 from __future__ import annotations
@@ -65,6 +67,7 @@ import time
 
 from ..network.wan import WAN_COUNTS
 from ..store.engine import WAL_COUNTS
+from .blsstats import BLS_COUNTS
 
 log = logging.getLogger(__name__)
 
@@ -159,6 +162,9 @@ class HostStats:
                     # NOTE: this log entry is scraped (benchmark/scaling.py,
                     # chipbench/readers/hoststats.py)
                     logger.info("Host stats: %s", self.line())
+                    if BLS_COUNTS.active:
+                        # NOTE: scraped (chipbench/readers/bls.py)
+                        logger.info("BLS stats: %s", BLS_COUNTS.line())
         finally:
             gc.callbacks.remove(self._on_gc)
 

@@ -57,12 +57,16 @@ SPAN_ANNOTATION_STAGES: tuple = ("pipeline.occupancy",)
 #: sub-phases of the ``agg.verify`` parent frame — recorded and
 #: histogrammed, never waterfall rows.  Surfaced as unregistered drift
 #: by the taxonomy-registry lint the day it landed (ISSUE 12).
+#: The ``bls.*`` stages are the loop's own BLS work around them:
+#: ``chipbench/readers/bls.py`` reads their self time on the loop thread
 SPAN_AGG_STAGES: tuple = (
     "agg.gather",
     "agg.keysum",
     "agg.pairing",
-    "agg.accumulate",
-    "agg.snapshot",
+    "agg.accumulate",  # one vote's device add (tpu/bls.py)
+    "agg.snapshot",  # the running sum's fence and readback at quorum
+    "bls.sign",  # BlsSigningService.sign_sync: hash to G1, scalar multiply
+    "bls.decode",  # a vote signature decompressed for the running sum
 )
 
 #: host stages outside the verify waterfall, one prefix a layer of

@@ -26,6 +26,7 @@ the kernel's own source line whoever calls it.
 """
 
 import os as _os
+import threading as _threading
 import time as _time
 
 import jax as _jax
@@ -72,7 +73,9 @@ class FirstCallTimer:
     events: tracing to a jaxpr and lowering to a module are host work
     every process pays; only the backend compile is what the persistent
     cache replaces with a load.  Use as a context manager around the
-    calls, ``take()`` after each."""
+    calls, ``take()`` after each.  It counts the events of the thread
+    that entered it, so programs warmed in threads of their own are
+    timed each by its own."""
 
     _DURATIONS = {
         "/jax/core/compile/jaxpr_trace_duration": "trace_s",
@@ -85,6 +88,7 @@ class FirstCallTimer:
     }
 
     def __enter__(self) -> "FirstCallTimer":
+        self._thread = _threading.get_ident()
         self._acc: dict = {}
         self._t0 = _time.perf_counter()
         _monitoring.register_event_duration_secs_listener(self._on_duration)
@@ -96,6 +100,8 @@ class FirstCallTimer:
         _monitoring.unregister_event_listener(self._on_event)
 
     def _on_duration(self, event: str, duration: float, **_kw) -> None:
+        if _threading.get_ident() != self._thread:
+            return
         key = self._DURATIONS.get(event)
         if key == "trace_s":
             # jits nest and the outer trace's time holds the inner's
@@ -104,6 +110,8 @@ class FirstCallTimer:
             self._acc[key] = self._acc.get(key, 0.0) + duration
 
     def _on_event(self, event: str, **_kw) -> None:
+        if _threading.get_ident() != self._thread:
+            return
         key = self._COUNTS.get(event)
         if key:
             self._acc[key] = self._acc.get(key, 0) + 1

@@ -94,7 +94,6 @@ def limbs_to_int(limbs) -> int:
 
 
 Q_LIMBS = _int_to_limbs(Q)
-Q_EXT = np.concatenate([Q_LIMBS, np.zeros(NCOLS - NLIMBS, np.int32)])
 
 # Fold vectors for the two overflow columns of the CIOS accumulator:
 # parallel carry passes move carries UP into columns 30/31 and never
@@ -151,31 +150,57 @@ def _pass(t):
     return r + jnp.pad(c, pad_cfg + [(1, 0)])[..., : t.shape[-1]]
 
 
+def _window_pass(t, lo: int):
+    """``_pass`` over the NCOLS columns of ``t`` from ``lo`` on, the
+    columns outside left as they are."""
+    top = lo + NCOLS - 1
+    window = t[..., lo:top]
+    pad_cfg = [(0, 0)] * (t.ndim - 1)
+    carried = jnp.concatenate(
+        [window & MASK, t[..., top : top + 1]], axis=-1
+    ) + jnp.pad(window >> LIMB_BITS, pad_cfg + [(1, 0)])
+    return jnp.concatenate([t[..., :lo], carried, t[..., top + 1 :]], axis=-1)
+
+
 def mont_mul(a, b):
     """Batched Montgomery product of signed-loose inputs (|value| < ~60q,
     |limb| < 2^13.1 — see the module docstring's magnitude audit).
-    Output magnitude < 3.2q, loose limbs.  a, b: int32 [..., NLIMBS]."""
-    pad_cfg = [(0, 0)] * (a.ndim - 1)
-    b_ext = jnp.pad(b, pad_cfg + [(0, NCOLS - NLIMBS)])
-    q_ext = jnp.asarray(Q_EXT)
-    mu = jnp.int32(MU)
-    t = jnp.zeros(jnp.broadcast_shapes(a.shape[:-1], b.shape[:-1]) + (NCOLS,), jnp.int32)
+    Output magnitude < 3.2q, loose limbs.  a, b: int32 [..., NLIMBS].
 
+    The CIOS accumulator does not shift: step i works on the NCOLS
+    columns from i on of a 62-column array (b, q and the carry padded
+    to that place), so a step is two fused operations on the TPU, not
+    five, and the result is the last NCOLS columns.  The values are
+    those of the shifting form, bit for bit."""
+    width = NLIMBS + NCOLS
+    shape = jnp.broadcast_shapes(a.shape, b.shape)
+    a = jnp.broadcast_to(a, shape).reshape(-1, NLIMBS)
+    b = jnp.broadcast_to(b, shape).reshape(-1, NLIMBS)
+    pad_cfg = [(0, 0)]
+    # a barrier, so that the compiler does not fold the 30 placements of
+    # q, or of a constant b, into 30 constants, each copied to the core
+    # before its step
+    b, q = jax.lax.optimization_barrier((b, jnp.asarray(Q_LIMBS)))
+    q0 = int(Q_LIMBS[0])
+    t = jnp.zeros((a.shape[0], width), jnp.int32)
     for i in range(NLIMBS):
-        t = t + a[..., i : i + 1] * b_ext
-        m = ((t[..., :1] & MASK) * mu) & MASK
-        t = t + m * q_ext
-        # t0 is now ≡ 0 mod 2^13; propagate its exact carry and shift
-        # the limb window down one position
-        carry0 = t[..., :1] >> LIMB_BITS
-        t = jnp.concatenate(
-            [t[..., 1:2] + carry0, t[..., 2:], jnp.zeros_like(t[..., :1])],
-            axis=-1,
+        ai = a[..., i : i + 1]
+        low = t[..., i : i + 1] + ai * b[..., :1]
+        m = ((low & MASK) * MU) & MASK
+        # column i is now ≡ 0 mod 2^13: its exact carry goes to column
+        # i + 1, and the column is not read again
+        carry = (low + m * q0) >> LIMB_BITS
+        place = (i, width - NLIMBS - i)
+        t = (
+            t
+            + ai * jnp.pad(b, pad_cfg + [place])
+            + m * jnp.pad(q, [place])
+            + jnp.pad(carry, pad_cfg + [(i + 1, width - i - 2)])
         )
         if (i % _CARRY_EVERY) == _CARRY_EVERY - 1:
-            t = _pass(t)
+            t = _window_pass(t, i + 1)
 
-    t = _pass(_pass(t))
+    t = _pass(_pass(t[..., NLIMBS:]))
     # fold the overflow columns (carry residue parked above limb 29 by
     # the upward-only passes) back into the 30-limb window mod q —
     # dropping them loses k*2^390 ≡ k*R, i.e. an off-by-k in the value
@@ -190,7 +215,7 @@ def mont_mul(a, b):
         + (hi30 + lo31) * jnp.asarray(_C403)
         + hi31 * jnp.asarray(_C416)
     )
-    return _pass(_pass(head))
+    return _pass(_pass(head)).reshape(shape)
 
 
 def madd(a, b):
@@ -202,46 +227,52 @@ def msub(a, b):
     return _pass(a - b)
 
 
-def mul_small(a, k: int):
-    """Multiply by a small non-negative integer constant (k <= 16:
-    loose limbs * 16 < 2^18, one pass restores looseness).  Montgomery
-    form is linear, so plain integer scaling stays in-form."""
-    return _pass(a * jnp.int32(k))
-
-
 # ---- complete projective G1 (Renes-Costello-Batina 2015, Alg. 7) -----------
 # Point = (X, Y, Z) loose Montgomery limb arrays; identity = (0 : 1 : 0).
 
 
+def mont_mul_many(a_list, b_list):
+    """The Montgomery products of several pairs of equal shape, as one
+    ``mont_mul`` over the pairs stacked on a new leading axis.  The
+    values are those of one ``mont_mul`` a pair, bit for bit (the
+    arithmetic is elementwise over the leading axes); the program runs
+    the 30 CIOS steps once, not once a pair, which divides the
+    operations the device runs, and the profiler records, by the number
+    of pairs."""
+    return tuple(mont_mul(jnp.stack(a_list), jnp.stack(b_list)))
+
+
 def point_add(p, q):
     """Complete addition: valid for every pair of subgroup points,
-    including P == Q, P == -Q, and either operand at infinity."""
+    including P == Q, P == -Q, and either operand at infinity.  The 12
+    multiplications fall in two groups of six that depend only on what
+    came before the group, so each group is one ``mont_mul_many``; the
+    additions between them are stacked likewise, three at a time, each
+    row the same ``madd``/``msub`` as written beside it, or a multiple by
+    B3 and one pass (Montgomery form is linear, so integer scaling stays
+    in form; loose limbs * 12 < 2^18, and one pass restores looseness)."""
     x1, y1, z1 = p
     x2, y2, z2 = q
-    t0 = mont_mul(x1, x2)
-    t1 = mont_mul(y1, y2)
-    t2 = mont_mul(z1, z2)
-    t3 = mont_mul(madd(x1, y1), madd(x2, y2))
-    t3 = msub(t3, madd(t0, t1))
-    t4 = mont_mul(madd(y1, z1), madd(y2, z2))
-    t4 = msub(t4, madd(t1, t2))
-    x3 = mont_mul(madd(x1, z1), madd(x2, z2))
-    y3 = msub(x3, madd(t0, t2))
-    x3 = madd(t0, t0)
-    t0 = madd(x3, t0)
-    t2 = mul_small(t2, B3)
-    z3 = madd(t1, t2)
-    t1 = msub(t1, t2)
-    y3 = mul_small(y3, B3)
-    x3 = mont_mul(t4, y3)
-    t2 = mont_mul(t3, t1)
-    x3 = msub(t2, x3)
-    y3 = mont_mul(y3, t0)
-    t1 = mont_mul(t1, z3)
-    y3 = madd(t1, y3)
-    t0 = mont_mul(t0, t3)
-    z3 = mont_mul(z3, t4)
-    z3 = madd(z3, t0)
+    sums = [
+        _pass(jnp.stack([u + v for u, v in ((x, y), (y, z), (x, z))]))
+        for x, y, z in (p, q)
+    ]
+    t0, t1, t2, t3, t4, x3 = mont_mul_many(
+        [x1, y1, z1, *sums[0]], [x2, y2, z2, *sums[1]]
+    )
+    # t3 = msub(t3, madd(t0, t1)); t4 = msub(t4, madd(t1, t2));
+    # y3 = msub(x3, madd(t0, t2))
+    inner = _pass(jnp.stack([t0 + t1, t1 + t2, t0 + t2]))
+    t3, t4, y3 = _pass(jnp.stack([t3, t4, x3]) - inner)
+    # x3 = madd(t0, t0); t2 = _pass(t2 * B3); y3 = _pass(y3 * B3)
+    x3, t2, y3 = _pass(jnp.stack([t0 + t0, t2 * B3, y3 * B3]))
+    # t0 = madd(x3, t0); z3 = madd(t1, t2); t1 = msub(t1, t2)
+    t0, z3, t1 = _pass(jnp.stack([x3 + t0, t1 + t2, t1 - t2]))
+    x3, t2, y3, t1, t0, z3 = mont_mul_many(
+        [t4, t3, y3, t1, t0, z3], [y3, t1, t0, z3, t3, t4]
+    )
+    # x3 = msub(t2, x3); y3 = madd(t1, y3); z3 = madd(z3, t0)
+    x3, y3, z3 = _pass(jnp.stack([t2 - x3, t1 + y3, z3 + t0]))
     return (x3, y3, z3)
 
 
@@ -268,7 +299,7 @@ def _aggregate_plain_impl(xs, ys, zs):
     r2 = jnp.broadcast_to(jnp.asarray(R2_LIMBS), xs.shape)
     return tuple(
         c[0]
-        for c in _tree_reduce(tuple(mont_mul(c, r2) for c in (xs, ys, zs)))
+        for c in _tree_reduce(mont_mul_many([xs, ys, zs], [r2] * 3))
     )
 
 
@@ -472,15 +503,20 @@ def _running_add_impl(ax, ay, az, px, py, pz):
     PLAIN [1, NLIMBS] limb rows (byte-split on host, no bignum work),
     Montgomery-converts in-kernel (one R^2 multiply per coordinate,
     same trick as ``_aggregate_plain_impl``), then ``point_add``s into
-    the Montgomery-form accumulator.  The result is ``_freshen``ed:
-    unlike the log-depth aggregation tree, this chain is as deep as the
-    committee (up to 512 sequential adds), and unfreshened point_add
-    outputs compound ~x2.5 per round until the CIOS columns overflow
-    int32 (see ``_freshen``'s magnitude audit)."""
+    the Montgomery-form accumulator.  The accumulator is ``_freshen``ed
+    as it comes in: unlike the log-depth aggregation tree, this chain is
+    as deep as the committee (up to 512 sequential adds), and
+    unfreshened point_add outputs compound ~x2.5 per round until the
+    CIOS columns overflow int32 (see ``_freshen``'s magnitude audit).
+    Freshening the input, not the output, lets the one multiply by 1 and
+    the three by R^2 run as one ``mont_mul_many``; what is stored is one
+    point_add away from fresh values, as the tree's first level is."""
     r2 = jnp.broadcast_to(jnp.asarray(R2_LIMBS), px.shape)
-    p = tuple(mont_mul(c, r2) for c in (px, py, pz))
-    out = point_add((ax, ay, az), p)
-    return tuple(_freshen(c) for c in out)
+    one = jnp.broadcast_to(jnp.asarray(_ONE_MONT), ax.shape).astype(jnp.int32)
+    ax, ay, az, px, py, pz = mont_mul_many(
+        [ax, ay, az, px, py, pz], [one] * 3 + [r2] * 3
+    )
+    return point_add((ax, ay, az), (px, py, pz))
 
 
 _running_add_kernel = jax.jit(_running_add_impl)
@@ -549,6 +585,56 @@ class TpuG1RunningSum:
             )
 
 
+def warm_g1_programs(aggregator: TpuG1Aggregator, committee: int) -> dict:
+    """Compile or load every G1 program a committee of ``committee``
+    nodes can dispatch, before the consensus hot path: the running-sum
+    add of one vote (the donated variant where ``_donate_buffers()``
+    picks it) and the aggregation tree at every pad shape a batch of 1
+    to ``committee`` points lands on (8, 32 and 128 for 64 nodes).  Each
+    program's first call runs in a thread of its own, so that the
+    compiles, which hold no interpreter lock, overlap: a cold warm-up
+    takes about its largest tree's compile, not the sum of all.  Each
+    sums the first multiples of the generator, ``G, 2G, ...``, and its
+    result is checked against the host's, so a wrong program stops the
+    boot.  The points are distinct, as a QC's signatures are: copies of
+    one point make every level of the tree a doubling, and 128 of them
+    overflow its unfreshened limbs (``_freshen``).  Returns, by program
+    (``running_add``, then each pad shape), where its first call's
+    seconds went and its compile-cache hits (``FirstCallTimer``)."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    from . import FirstCallTimer
+
+    g = G1Point.generator()
+    shapes = sorted({aggregator._padded_size(k) for k in range(1, committee + 1)})
+    multiples = [g]
+    while len(multiples) < max(shapes[-1], 2):
+        multiples.append(multiples[-1] + g)
+
+    def running_add() -> G1Point:
+        acc = TpuG1RunningSum()
+        acc.add(multiples[0])
+        acc.add(multiples[1])
+        return acc.snapshot()
+
+    programs = {"running_add": (running_add, 3)}
+    for shape in shapes:
+        programs[str(shape)] = (
+            partial(aggregator.aggregate, multiples[:shape]),
+            shape * (shape + 1) // 2,
+        )
+
+    def first_call(name: str) -> dict:
+        run, times = programs[name]
+        with FirstCallTimer() as timer:
+            if run() != g.mul(times):
+                raise RuntimeError(f"G1 warmup: the {name} program is wrong")
+            return timer.take()
+
+    with ThreadPoolExecutor(len(programs)) as pool:
+        return dict(zip(programs, pool.map(first_call, programs)))
+
+
 # ---- batched variable-base scalar multiplication ----------------------------
 # The per-entry G1 work of distinct-digest TC verification (VERDICT r5
 # item 8): r_i·H(m_i) for every entry plus the Σ r_i·sig_i aggregate.
@@ -598,7 +684,7 @@ def _scalar_mult_kernel(bits, xs, ys, zs, nbits: int = SCALAR_BITS):
         acc = tuple(
             jnp.where(take, ad, ac) for ac, ad in zip(acc, added)
         )
-        return tuple(_freshen(c) for c in acc)
+        return tuple(_freshen(jnp.stack(acc)))
 
     return jax.lax.fori_loop(0, nbits, body, acc)
 
