@@ -9,7 +9,9 @@ Measured: one signature verification ~6 ms native vs ~53 ms pure
 Python.  The per-certificate aggregate checks were already one pairing
 equality; this path matters for PER-MESSAGE authentication (timeout
 floods — the view-change-storm bench showed ~45 ms/timeout on the
-Python backend).
+Python backend).  Signing (``sign``: hash to G1, the scalar multiply and
+compression in one call) takes ~0.75 ms against ~6 ms in Python on an
+x86 core, and every vote and block a BLS node makes is one.
 
 Set ``HOTSTUFF_BLS_NATIVE=0`` to force the Python pairing.  The library
 runs a bilinearity selftest at load; any failure falls back to Python.
@@ -80,6 +82,13 @@ def _load_lib() -> ctypes.CDLL:
             ctypes.c_char_p,
             ctypes.c_int,
         ]
+        lib.hs_bls_sign.restype = ctypes.c_int
+        lib.hs_bls_sign.argtypes = [
+            ctypes.c_char_p,
+            ctypes.c_size_t,
+            ctypes.c_char_p,
+            ctypes.c_char_p,
+        ]
         lib.hs_bls_selftest.restype = ctypes.c_int
         lib.hs_bls_aggregate_sigs.restype = ctypes.c_int
         lib.hs_bls_aggregate_sigs.argtypes = [
@@ -139,6 +148,19 @@ def verify_one(
             message, len(message), pk96, sig48, 1 if check_pk_subgroup else 0
         )
     )
+
+
+def sign(message: bytes, scalar_le32: bytes) -> bytes | None:
+    """Native signing: the compressed x*H(message) that
+    ``BlsSecretKey(x).sign(message).to_bytes()`` gives, for the secret
+    scalar x as 32 little-endian bytes.  None for a key of another
+    length, or a scalar that is zero or not below r."""
+    if len(scalar_le32) != 32:
+        return None
+    out = ctypes.create_string_buffer(48)
+    if not _lib.hs_bls_sign(message, len(message), scalar_le32, out):
+        return None
+    return out.raw
 
 
 def aggregate_sigs(sigs48: list[bytes]) -> bytes | None:

@@ -587,16 +587,26 @@ class BlsSigningService:
     """The BLS signing service behind the SignatureService API surface
     (reference crypto/src/lib.rs:232-257).  Signing is inline — the
     single-threaded loop already serializes access to the key, the same
-    argument as the Ed25519 service — ~6 ms per sign (hash-to-G1 + one
-    G1 scalar multiply).  Returns the scheme-agnostic consensus
-    ``Signature`` wrapper (48-byte compressed G1) so votes/blocks carry
-    BLS material through the identical protocol types."""
+    argument as the Ed25519 service.  A sign is hash-to-G1 and one G1
+    scalar multiply: one call into the native library where it loads
+    (``native.sign``, byte for byte the same signature), else
+    ``BlsSecretKey.sign`` in pure Python (~6 ms).  Returns the
+    scheme-agnostic consensus ``Signature`` wrapper (48-byte compressed
+    G1) so votes/blocks carry BLS material through the identical
+    protocol types."""
 
     def __init__(self, secret: BlsSecretKey | bytes):
         BLS_COUNTS.active = True
         if isinstance(secret, (bytes, bytearray)):
             secret = BlsSecretKey(int.from_bytes(bytes(secret), "big"))
         self._sk: BlsSecretKey | None = secret
+        self._sk_le32: bytes | None = secret.scalar.to_bytes(32, "little")
+        try:
+            from . import native as _native
+
+            self._native_sign = _native.sign
+        except ImportError:
+            self._native_sign = None
         self._closed = False
 
     async def request_signature(self, digest) -> "Signature":
@@ -612,13 +622,18 @@ class BlsSigningService:
         # holds the signing of either scheme; bls.sign inside it for
         # the BLS reader
         with _spans.span("core.sign"), _spans.span("bls.sign"):
-            sig = self._sk.sign(msg).to_bytes()
+            sig = self._native_sign and self._native_sign(msg, self._sk_le32)
+            if sig is None:
+                sig = self._sk.sign(msg).to_bytes()
+            else:
+                BLS_COUNTS.add("native_signs")
         BLS_COUNTS.add("signs")
         return Signature(sig)
 
     def shutdown(self) -> None:
         self._closed = True
         self._sk = None
+        self._sk_le32 = None
 
 
 __all__ = [

@@ -8,9 +8,13 @@ or before its window's end less the last at or before its start::
 
     BLS stats: signs=4096 device_adds=2752 host_adds=0 snapshots=64
       qcs=64 compact_qcs=64 agg_verifies=64 agg_failures=0 pairings=130
+      native_signs=4096
 
 - ``signs``: signatures made (``BlsSigningService.sign_sync``, all
   nodes): votes, blocks and timeouts.
+- ``native_signs``: of those, the ones the native library made in one
+  call (``crypto/bls/native.py`` ``sign``); the rest were made in pure
+  Python (``HOTSTUFF_BLS_NATIVE=0``, or no library).
 - ``device_adds`` / ``host_adds``: vote signatures a QC maker added to
   its running sum on the device (``tpu/bls.py`` ``TpuG1RunningSum``)
   and on the host (a Jacobian add); the verifier the node was given
@@ -40,6 +44,7 @@ FIELDS = (
     "agg_verifies",
     "agg_failures",
     "pairings",
+    "native_signs",
 )
 
 

@@ -60,6 +60,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..crypto.bls.curve import G1Point
 from ..crypto.bls.fields import P as Q
@@ -498,7 +500,7 @@ class TpuG1Aggregator:
         return G1Point(xi * z_inv % Q, yi * z_inv % Q)
 
 
-def _running_add_impl(ax, ay, az, px, py, pz):
+def _running_add_xla(ax, ay, az, px, py, pz):
     """One incremental accumulate (ISSUE 9): the new point arrives as
     PLAIN [1, NLIMBS] limb rows (byte-split on host, no bignum work),
     Montgomery-converts in-kernel (one R^2 multiply per coordinate,
@@ -517,6 +519,163 @@ def _running_add_impl(ax, ay, az, px, py, pz):
         [ax, ay, az, px, py, pz], [one] * 3 + [r2] * 3
     )
     return point_add((ax, ay, az), (px, py, pz))
+
+
+# ---- the running add as one Pallas kernel ----------------------------------
+# ``_running_add_xla`` is ~200 device operations an add (three stacked
+# Montgomery products of 30 CIOS steps, two fusions a step), and a QC
+# is 43 adds: on the chip that is one profiler event an operation, and
+# a committee whose rounds are short enough fills a traced window with
+# more events than the profiler writes out in time.  The kernel below
+# runs the same int32 operations, bit for bit, on one vector register
+# an operand, VMEM-resident: the stacked operands of a ``mont_mul_many``
+# ride the sublanes (one row a product, 8 rows), the limb columns the
+# lanes (a CIOS column shift is a lane rotate of zero-padded rows), so
+# an add is one custom call and a few copies.
+
+_ROWS, _LANES = 8, 128
+
+
+def _g1_const_rows() -> np.ndarray:
+    """q, the three overflow-column fold vectors, Montgomery 1 and R^2,
+    one a row: kernel inputs, as Pallas kernels capture no constants."""
+    rows = np.zeros((_ROWS, _LANES), np.int32)
+    for r, limbs in enumerate(
+        (Q_LIMBS, _C390, _C403, _C416, to_mont_limbs(1), R2_LIMBS)
+    ):
+        rows[r, :NLIMBS] = limbs
+    return rows
+
+
+_G1_CONST_ROWS = _g1_const_rows()
+
+
+def _lanes():
+    return jax.lax.broadcasted_iota(jnp.int32, (_ROWS, _LANES), 1)
+
+
+def _pass_v(t, n: int = NLIMBS):
+    """``_pass`` over the first ``n`` lanes of every row (lanes from
+    ``n`` on are zero and stay zero)."""
+    lane = _lanes()
+    carry = pltpu.roll(t >> LIMB_BITS, 1, 1)
+    return jnp.where(lane < n - 1, t & MASK, t) + jnp.where(
+        (lane > 0) & (lane < n), carry, 0
+    )
+
+
+def _window_pass_v(t, lo: int):
+    """``_window_pass`` of every row."""
+    lane = _lanes()
+    top = lo + NCOLS - 1
+    carry = pltpu.roll(t >> LIMB_BITS, 1, 1)
+    return jnp.where((lane >= lo) & (lane < top), t & MASK, t) + jnp.where(
+        (lane > lo) & (lane <= top), carry, 0
+    )
+
+
+def _mont_mul_v(a, b, const):
+    """``mont_mul`` of each row of ``a`` by the same row of ``b``, limbs
+    in lanes 0..NLIMBS-1: the 62-column CIOS accumulator is one row of
+    lanes, and step i's placement of b and q at column i a rotate by i
+    of rows whose lanes past NLIMBS are zero."""
+    lane = _lanes()
+    q = jnp.broadcast_to(const[0:1, :], (_ROWS, _LANES))
+    q0 = int(Q_LIMBS[0])
+    b0 = b[:, 0:1]
+    t = jnp.zeros((_ROWS, _LANES), jnp.int32)
+    for i in range(NLIMBS):
+        ai = a[:, i : i + 1]
+        low = t[:, i : i + 1] + ai * b0
+        m = ((low & MASK) * MU) & MASK
+        carry = (low + m * q0) >> LIMB_BITS
+        t = (
+            t
+            + ai * pltpu.roll(b, i, 1)
+            + m * pltpu.roll(q, i, 1)
+            + jnp.where(lane == i + 1, carry, 0)
+        )
+        if (i % _CARRY_EVERY) == _CARRY_EVERY - 1:
+            t = _window_pass_v(t, i + 1)
+    # the last NCOLS columns, moved to lanes 0..NCOLS-1
+    t = jnp.where(lane < NCOLS, pltpu.roll(t, _LANES - NLIMBS, 1), 0)
+    t = _pass_v(_pass_v(t, NCOLS), NCOLS)
+    c30, c31 = t[:, NLIMBS : NLIMBS + 1], t[:, NLIMBS + 1 : NLIMBS + 2]
+    lo30, hi30 = c30 & MASK, c30 >> LIMB_BITS
+    lo31, hi31 = c31 & MASK, c31 >> LIMB_BITS
+    head = (
+        jnp.where(lane < NLIMBS, t, 0)
+        + lo30 * const[1:2, :]
+        + (hi30 + lo31) * const[2:3, :]
+        + hi31 * const[3:4, :]
+    )
+    return _pass_v(_pass_v(head))
+
+
+def _stack_v(rows):
+    """Rows (each [1, _LANES]) stacked into one [_ROWS, _LANES] value."""
+    r = jax.lax.broadcasted_iota(jnp.int32, (_ROWS, _LANES), 0)
+    out = jnp.zeros((_ROWS, _LANES), jnp.int32)
+    for k, v in enumerate(rows):
+        out = jnp.where(r == k, jnp.broadcast_to(v, (_ROWS, _LANES)), out)
+    return out
+
+
+def _rows_v(m, n: int):
+    return [m[k : k + 1, :] for k in range(n)]
+
+
+def _running_add_kernel_body(const_ref, in_ref, out_ref):
+    """``_running_add_xla`` on rows: ``in_ref`` holds the accumulator's
+    and the new point's six coordinates, ``out_ref`` gets the sum's
+    three; each stacked step below is the line of ``point_add`` it
+    names."""
+    const = const_ref[...]
+    mont = _stack_v([const[4:5, :]] * 3 + [const[5:6, :]] * 3)
+    x1, y1, z1, x2, y2, z2 = _rows_v(_mont_mul_v(in_ref[...], mont, const), 6)
+    sp = _rows_v(_pass_v(_stack_v([x1 + y1, y1 + z1, x1 + z1])), 3)
+    sq = _rows_v(_pass_v(_stack_v([x2 + y2, y2 + z2, x2 + z2])), 3)
+    t0, t1, t2, t3, t4, x3 = _rows_v(
+        _mont_mul_v(_stack_v([x1, y1, z1, *sp]), _stack_v([x2, y2, z2, *sq]), const),
+        6,
+    )
+    inner = _pass_v(_stack_v([t0 + t1, t1 + t2, t0 + t2]))
+    t3, t4, y3 = _rows_v(_pass_v(_stack_v([t3, t4, x3]) - inner), 3)
+    x3, t2, y3 = _rows_v(_pass_v(_stack_v([t0 + t0, t2 * B3, y3 * B3])), 3)
+    t0, z3, t1 = _rows_v(_pass_v(_stack_v([x3 + t0, t1 + t2, t1 - t2])), 3)
+    x3, t2, y3, t1, t0, z3 = _rows_v(
+        _mont_mul_v(
+            _stack_v([t4, t3, y3, t1, t0, z3]),
+            _stack_v([y3, t1, t0, z3, t3, t4]),
+            const,
+        ),
+        6,
+    )
+    out_ref[...] = _pass_v(_stack_v([t2 - x3, t1 + y3, z3 + t0]))
+
+
+def _running_add_pallas(ax, ay, az, px, py, pz, interpret: bool = False):
+    """``_running_add_xla``'s values, bit for bit, from one Pallas
+    kernel: the six [1, NLIMBS] rows go in as one zero-padded
+    [_ROWS, _LANES] block and the three of the sum come out of one."""
+    rows = jnp.concatenate(
+        [ax, ay, az, px, py, pz, jnp.zeros((_ROWS - 6, NLIMBS), jnp.int32)]
+    )
+    out = pl.pallas_call(
+        _running_add_kernel_body,
+        out_shape=jax.ShapeDtypeStruct((_ROWS, _LANES), jnp.int32),
+        interpret=interpret,
+    )(jnp.asarray(_G1_CONST_ROWS), jnp.pad(rows, [(0, 0), (0, _LANES - NLIMBS)]))
+    return tuple(out[k : k + 1, :NLIMBS] for k in range(3))
+
+
+def _running_add_impl(ax, ay, az, px, py, pz):
+    """The running sum's one program (its name is what the profiler's
+    ``XLA Modules`` line shows): the Pallas kernel on a TPU, the XLA
+    formulation elsewhere (Pallas runs on the CPU only interpreted)."""
+    if jax.default_backend() == "tpu":
+        return _running_add_pallas(ax, ay, az, px, py, pz)
+    return _running_add_xla(ax, ay, az, px, py, pz)
 
 
 _running_add_kernel = jax.jit(_running_add_impl)

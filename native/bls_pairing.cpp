@@ -1816,6 +1816,32 @@ int hs_bls_verify_one(const uint8_t *msg, size_t msg_len, const uint8_t *pk96,
   return hs_bls_verify_one_ex(msg, msg_len, pk96, sig48, 1);
 }
 
+// sign msg with the secret scalar sk_le32 (32 bytes, little-endian):
+// out48 = compressed x*H(msg), byte for byte what curve.py's
+// hash_to_g1(msg).mul(x).to_bytes() gives.  Returns 1 ok / 0 for a
+// scalar that is zero or not below r (the caller signs in Python).
+int hs_bls_sign(const uint8_t *msg, size_t msg_len, const uint8_t *sk_le32,
+                uint8_t *out48) {
+  uint64_t k[4];
+  for (int i = 0; i < 4; i++) {
+    k[i] = 0;
+    for (int b = 0; b < 8; b++)
+      k[i] |= (uint64_t)sk_le32[8 * i + b] << (8 * b);
+  }
+  if ((k[0] | k[1] | k[2] | k[3]) == 0) return 0;
+  for (int i = 3; i >= 0; i--) {
+    if (k[i] < BLS_ORDER[i]) break;
+    if (k[i] > BLS_ORDER[i] || i == 0) return 0;  // k >= r
+  }
+  static const uint8_t DST[] = "HOTSTUFF_TPU_BLS_G1";
+  G1 hm;
+  hash_to_g1(hm, msg, msg_len, DST, sizeof(DST) - 1);
+  G1Jac sig;
+  g1_jac_mul(sig, hm, k, 4);
+  g1_to_bytes(out48, g1_from_jac(sig));
+  return 1;
+}
+
 // pairing equality on uncompressed-style operands is not exposed; the
 // aggregate paths reuse hs_bls_verify_one with aggregate pk/sig bytes.
 

@@ -2,9 +2,13 @@
 cell's shapes: ``TpuG1RunningSum`` over 64 and over 43 seeded vote
 signatures (a full committee's votes and a quorum's), and
 ``TpuG1Aggregator`` over 43 (padded to 128), each compared byte for byte
-with ``crypto/bls_g1_ref.py``.  Then a short profiler trace of eight
-running-sum adds, read back the way ``chipbench/readers/bls.py`` reads a
-cell's: the running-sum program's executions and their operations.
+with ``crypto/bls_g1_ref.py``; and the running add's two formulations
+on the device itself, the Pallas kernel against the XLA program, limb
+for limb along chains of seeded adds (the kernel interpreted on the
+CPU).  Then a short profiler trace of
+eight running-sum adds, read back the way ``chipbench/readers/bls.py``
+reads a cell's: the running-sum program's executions and their
+operations.
 
     python scripts/bls_device_check.py
 
@@ -40,6 +44,39 @@ def seeded_votes(n: int) -> list[bytes]:
     return out
 
 
+def kernels_agree(points) -> bool:
+    """``_running_add_pallas`` and ``_running_add_xla``, each jitted for
+    the default device, give the same limbs after every add of the
+    points, in four orders."""
+    import random
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from hotstuff_tpu.tpu import bls as T
+
+    interpret = jax.default_backend() != "tpu"  # the CPU rehearsal
+    xla = jax.jit(T._running_add_xla)
+    pallas = jax.jit(partial(T._running_add_pallas, interpret=interpret))
+    z = np.zeros((1, T.NLIMBS), np.int32)
+    z[0, 0] = 1
+    for seed in range(4):
+        order = random.Random(seed).sample(points, len(points))
+        a = b = (
+            jnp.zeros((1, T.NLIMBS), jnp.int32),
+            jnp.asarray(T.to_mont_limbs(1), jnp.int32).reshape(1, T.NLIMBS),
+            jnp.zeros((1, T.NLIMBS), jnp.int32),
+        )
+        for pt in order:
+            row = (T.ints_to_limbs_batch([pt.x]), T.ints_to_limbs_batch([pt.y]), z)
+            a, b = xla(*a, *row), pallas(*b, *row)
+            if not all(np.array_equal(u, v) for u, v in zip(a, b)):
+                return False
+    return True
+
+
 def main() -> int:
     import jax
 
@@ -63,6 +100,7 @@ def main() -> int:
             "equal": got == ref.sum_compressed(votes[:n]),
             "seconds": time.perf_counter() - t0,
         }
+    checks["pallas_vs_xla"] = {"equal": kernels_agree(points)}
     agg = TpuG1Aggregator()
     t0 = time.perf_counter()
     got = agg.aggregate(points[:43]).to_bytes()
@@ -84,6 +122,10 @@ def main() -> int:
     jax.profiler.stop_trace()
     events = bls_reader.trace_events(trace_dir)
     modules = [m for m in events["modules"] if bls_reader.RUNNING_ADD in m[0]]
+    ops_per_add = [
+        sum(start <= o[1] < start + length for o in events["ops"])
+        for _name, start, length in modules
+    ]
     reduced = bls_reader.reduce({
         "loop": [["proposer.make", 0, 1, {"round": 1}],
                  ["agg.accumulate", 0, 1, {}]],
@@ -96,6 +138,7 @@ def main() -> int:
             "equal": traced_equal,
             "running_add_executions": len(modules),
             "running_add_us": reduced["running_add_us"] if reduced else None,
+            "ops_per_add": ops_per_add,
         },
     }
     out_dir = os.path.join(ROOT, "chiprun_out", "bls_device_check")
@@ -104,7 +147,8 @@ def main() -> int:
         json.dump(result, f, indent=1)
     print(json.dumps({"device": device, "checks": checks,
                       "running_add_executions": len(modules),
-                      "running_add_us": result["trace"]["running_add_us"]}))
+                      "running_add_us": result["trace"]["running_add_us"],
+                      "ops_per_add": ops_per_add}))
     ok = traced_equal and all(c["equal"] for c in checks.values())
     return 0 if ok else 1
 
