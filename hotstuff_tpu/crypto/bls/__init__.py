@@ -70,8 +70,10 @@ class BlsPublicKey:
         return self.point.to_bytes()
 
     @classmethod
-    def from_bytes(cls, data: bytes) -> "BlsPublicKey | None":
-        pt = G2Point.from_bytes(data)
+    def from_bytes(
+        cls, data: bytes, subgroup_check: bool = True
+    ) -> "BlsPublicKey | None":
+        pt = G2Point.from_bytes(data, subgroup_check=subgroup_check)
         return None if pt is None else cls(pt)
 
     def verify(self, message: bytes, sig: "BlsSignature") -> bool:

@@ -48,15 +48,22 @@ _PK_CACHE_MAX = 4096
 #: time it is asked about.
 _POP_PASSED: set[tuple[bytes, bytes]] = set()
 _POP_PASSED_MAX = 4096
+#: the keys of those pairs that the native verifier passed, which
+#: subgroup-checks the key it is given
+_NATIVE_POP_KEYS: set[bytes] = set()
 
 
 def decoded_key(pk_bytes: bytes) -> BlsPublicKey | None:
     """The committee key ``pk_bytes`` decoded (subgroup-checked), from
-    the process's cache."""
+    the process's cache.  A key whose proof of possession passed the
+    native check is decoded without the pure-Python ladder: that check
+    refused it unless it lay in the subgroup (``possession_holds``)."""
     if pk_bytes not in _PK_CACHE:
         if len(_PK_CACHE) >= _PK_CACHE_MAX:
             _PK_CACHE.clear()
-        _PK_CACHE[pk_bytes] = BlsPublicKey.from_bytes(pk_bytes)
+        _PK_CACHE[pk_bytes] = BlsPublicKey.from_bytes(
+            pk_bytes, subgroup_check=pk_bytes not in _NATIVE_POP_KEYS
+        )
     return _PK_CACHE[pk_bytes]
 
 
@@ -89,7 +96,10 @@ def check_possession(pk: bytes, pop: bytes) -> bool:
         return False
     if len(_POP_PASSED) >= _POP_PASSED_MAX:
         _POP_PASSED.clear()
+        _NATIVE_POP_KEYS.clear()
     _POP_PASSED.add(key)
+    if native is not None:
+        _NATIVE_POP_KEYS.add(key[0])
     return True
 
 
@@ -97,10 +107,12 @@ class BlsVerifier:
     """VerifierBackend over BLS bytes; decoded keys come from the
     process's cache (``decoded_key``).
 
-    ``aggregator="tpu"`` runs the G1 signature sum on device
-    (hotstuff_tpu/tpu/bls.py — the psum-shaped reduction of
-    docs/BLS_TPU_DESIGN.md); the pairing equality stays on the host in
-    both modes, one constant-cost call per QC.
+    ``aggregator="tpu"`` puts a QC maker's running sum of its vote
+    signatures on the device (``sums_on_device``; hotstuff_tpu/tpu/bls.py
+    ``TpuG1RunningSum``); a quorum check sums its votes on the host in
+    both modes, and the pairing equality stays on the host, one
+    constant-cost call per QC.  BLS has no sharded device path: one
+    device holds the running sum.
 
     Async-claims integration (crypto/async_service.py):
 
@@ -125,11 +137,10 @@ class BlsVerifier:
 
     def __init__(self, aggregator: str = "cpu"):
         BLS_COUNTS.active = True
-        self._committee_size = 0
         # signer-set digest -> aggregated G2 key (compact-QC verify);
         # bounded in verify_aggregate_msg
         self._agg_pk_cache: dict[bytes, BlsPublicKey] = {}
-        self._tpu_agg = None
+        self._device_sum = aggregator == "tpu"
         # Native pairing (C++ port of this package, ~8x): used for
         # per-signature checks and point aggregation when the library
         # is present/healthy
@@ -142,19 +153,8 @@ class BlsVerifier:
             self._native = None
             self._native_verify = None
         self._storm = None  # TpuStormOffload (device ladders), warmed on demand
-        if aggregator == "tpu":
-            from ...tpu.bls import TpuG1Aggregator
-
-            self._tpu_agg = TpuG1Aggregator()
+        if self._device_sum:
             self.name = "bls-tpu"
-        elif aggregator == "tpu-sharded":
-            # batch sharded over every visible device: per-device tree
-            # reduction + one all_gather of the partial points
-            from ...parallel.mesh import default_mesh
-            from ...tpu.bls import TpuG1Aggregator
-
-            self._tpu_agg = TpuG1Aggregator(mesh=default_mesh())
-            self.name = "bls-tpu-sharded"
         elif aggregator != "cpu":
             raise ValueError(f"unknown BLS aggregator '{aggregator}'")
         # Worker-thread offload via AsyncVerifyService: only worthwhile
@@ -177,7 +177,7 @@ class BlsVerifier:
         distinct-digest storm (VERDICT r5 item 8).  Only meaningful on
         the device-aggregation variants; call at node boot, never
         mid-consensus."""
-        if self._tpu_agg is None or self._native is None:
+        if not self._device_sum or self._native is None:
             return
         from ...tpu.bls import TpuStormOffload
 
@@ -246,7 +246,6 @@ class BlsVerifier:
         )
 
     def precompute(self, pubkeys: list[bytes]) -> None:
-        self._committee_size = len(pubkeys)
         for pk in pubkeys:
             decoded_key(pk)
 
@@ -255,25 +254,22 @@ class BlsVerifier:
         """Whether a QC maker's running sum of vote signatures belongs
         on the device: with the device aggregator, the verifier a node
         runs under ``--verifier tpu`` (``consensus/aggregator.py``)."""
-        return self._tpu_agg is not None
+        return self._device_sum
 
     def warmup(self, batch: int | None = None) -> None:
-        """Compile or load, before the node binds its port, every G1
-        program a committee of this size can dispatch: the running-sum
-        add of one vote, and the aggregation tree at each pad bucket up
-        to the committee's size (``batch``, or the keys ``precompute``
-        was given, whichever is smaller).  Once a process; the CPU
-        verifier has none.  Each program's result is checked against
-        the host's sum, and the ``Device verifier [...] warm in`` line
-        says where each shape's seconds went."""
-        if self._tpu_agg is None or self.name in self._warm:
+        """Compile or load, before the node binds its port, the one G1
+        program a committee dispatches: the running-sum add of one vote
+        (``tpu/bls.py`` ``warm_g1_programs``), whatever the committee's
+        size or ``batch``.  Once a process; the CPU verifier has none.
+        The ``Device verifier [...] warm in`` line names the program and
+        says where its seconds went."""
+        if not self._device_sum or self.name in self._warm:
             return
         from ...tpu import device_info
         from ...tpu.bls import warm_g1_programs
 
-        sizes = [n for n in (batch, self._committee_size) if n]
         t0 = time.perf_counter()
-        report = warm_g1_programs(self._tpu_agg, min(sizes, default=1))
+        report = warm_g1_programs()
         self._warm.add(self.name)
         # NOTE: this log entry is part of the benchmark log-scrape
         # contract (chipbench/readers/verifier.py, as for ed25519)
@@ -285,7 +281,7 @@ class BlsVerifier:
                 {
                     **device_info(),
                     "kernel": "g1-xla",
-                    "pad_shapes": [int(k) for k in report if k.isdigit()],
+                    "pad_shapes": [],
                     "warm": report,
                 }
             ),
@@ -316,13 +312,15 @@ class BlsVerifier:
         msg = digest if isinstance(digest, bytes) else digest.to_bytes()
         if not votes:
             return False
-        if self._native is not None and self._tpu_agg is None:
+        if self._native is not None:
             # mixed path, fastest measured: signatures aggregate in C
             # (decompress + Jacobian sum, no per-sig subgroup ladders —
             # the aggregate is checked by the native verifier); public
             # keys sum over the CACHED decoded points (a native pk
             # aggregate would re-run the expensive G2 sqrt per key that
-            # the cache already paid once per epoch)
+            # the cache already paid once per epoch).  The device
+            # aggregator takes this path too: 171 signatures summed in C
+            # cost less than their decode in Python
             pubs, sig_bytes = [], []
             for pk, sig in votes:
                 pub = decoded_key(pk if isinstance(pk, bytes) else pk.to_bytes())
@@ -351,25 +349,9 @@ class BlsVerifier:
                 return False
             pks.append(pub)
             sig_points.append(s)
-        if self._tpu_agg is not None:
-            agg = self._tpu_agg.aggregate(sig_points)
-        else:
-            agg = G1Point.sum(sig_points)
+        agg = G1Point.sum(sig_points)
         agg_pk = aggregate_public_keys(pks)
-        if self._native_verify is not None:
-            # the native verifier subgroup-checks the aggregate SIGNATURE
-            # itself; the aggregate PK is a sum of individually
-            # subgroup-checked cached keys, so its ladder is skipped
-            with _spans.span("host.pairing"):
-                return self._native_verify(
-                    msg,
-                    agg_pk.to_bytes(),
-                    BlsSignature(agg).to_bytes(),
-                    check_pk_subgroup=False,
-                )
-        # ONE subgroup check on the aggregate (the device kernel's
-        # in-kernel r-ladder is still future work, so the host checks
-        # its result too — ~2 ms once per QC)
+        # ONE subgroup check on the aggregate, ~2 ms once per QC
         if not agg.in_subgroup():
             return False
         with _spans.span("host.pairing"):

@@ -65,21 +65,50 @@ def bls_keygen(seed: bytes | None = None, index: int = 0) -> tuple[PublicKey, by
             b"bls-keygen" + seed + struct.pack("<Q", index)
         ).digest()
     scalar = (int.from_bytes(material, "big") % (BLS_R - 1)) + 1
+    pk = PublicKey(_bls_public_key(scalar))
+    return pk, scalar.to_bytes(32, "big")
+
+
+#: digest of a BLS secret scalar -> its 96-byte key, so that the proof
+#: of possession made right after a key (``bls_pop``) does not pay its
+#: G2 multiply again; keyed by a digest, so no secret is kept here
+_BLS_PK_OF: dict[bytes, bytes] = {}
+
+
+def _bls_public_key(scalar: int) -> bytes:
+    """The 96-byte G2 key of a BLS secret scalar: one G2 multiply in
+    pure Python (~20 ms), once a process."""
     from .bls import BlsSecretKey
 
-    sk = BlsSecretKey(scalar)
-    pk = PublicKey(sk.public_key().to_bytes())
-    return pk, scalar.to_bytes(32, "big")
+    tag = hashlib.sha256(scalar.to_bytes(32, "big")).digest()
+    if tag not in _BLS_PK_OF:
+        if len(_BLS_PK_OF) >= 4096:
+            _BLS_PK_OF.clear()
+        _BLS_PK_OF[tag] = BlsSecretKey(scalar).public_key().to_bytes()
+    return _BLS_PK_OF[tag]
 
 
 def bls_pop(secret_bytes: bytes) -> bytes:
     """48-byte proof of possession for a BLS secret — REQUIRED committee
     material (``consensus.config.Authority.pop``): sum-of-keys QC
-    verification is rogue-key forgeable without it."""
-    from .bls import BlsSecretKey, prove_possession
+    verification is rogue-key forgeable without it.  The key's signature
+    of ``_POP_DST`` and the key, made in one native call where the
+    library loads (``bls/native.py`` ``sign``), byte for byte what
+    ``prove_possession`` makes in pure Python."""
+    from .bls import _POP_DST, BlsSecretKey, prove_possession
 
-    sk = BlsSecretKey(int.from_bytes(secret_bytes, "big"))
-    return prove_possession(sk).to_bytes()
+    scalar = int.from_bytes(secret_bytes, "big")
+    try:
+        from .bls import native
+    except ImportError:
+        native = None
+    if native is not None:
+        proof = native.sign(
+            _POP_DST + _bls_public_key(scalar), scalar.to_bytes(32, "little")
+        )
+        if proof is not None:
+            return proof
+    return prove_possession(BlsSecretKey(scalar)).to_bytes()
 
 
 def check_scheme(name: str) -> str:
@@ -137,15 +166,17 @@ def make_cpu_verifier(scheme: str) -> VerifierBackend:
 def make_device_verifier(scheme: str, kind: str) -> VerifierBackend:
     """Device-backed verifier: the Ed25519 batch kernel (with the
     lazy-import hybrid handled by the caller, node/node.py) or the BLS
-    verifier with its G1 aggregation on device."""
+    verifier with its QC makers' running sums on one device (``kind``
+    "tpu"; BLS has no sharded path, so "tpu-sharded" is refused)."""
     check_scheme(scheme)
     if scheme == "bls":
         from .bls.service import BlsVerifier
 
-        # 'tpu': single-device G1 tree reduction; 'tpu-sharded': batch
-        # sharded over the mesh with an all_gather partial-point combine
-        # (docs/BLS_TPU_DESIGN.md step 4).  BlsVerifier rejects anything
-        # else.
+        if kind != "tpu":
+            raise ValueError(
+                f"BLS has no '{kind}' device verifier: a QC's running sum "
+                "lives on one device (--verifier tpu)"
+            )
         v = BlsVerifier(aggregator=kind)
         if not hasattr(v, "dispatch_deadline_s"):
             # pure-Python pairing fallback (native lib absent): one

@@ -26,6 +26,20 @@ import asyncio
 from ..utils.clock import default_clock
 
 
+class ConnCounts:
+    """Node-to-node connections the senders of this process opened,
+    every sender of every node (the ``Host stats:`` line's
+    ``conn_opens=``, ``telemetry/hoststats.py``): each sender reaches
+    each peer once where the pools are unbounded, and again after every
+    eviction where they are bounded."""
+
+    def __init__(self):
+        self.opens = 0
+
+
+CONN_COUNTS = ConnCounts()
+
+
 class BoundedPoolMixin:
     _connections: dict
     _max_conns: int | None

@@ -19,6 +19,14 @@ from .framing import FramingError, read_frame, send_frame, set_nodelay
 
 log = logging.getLogger(__name__)
 
+#: connections the kernel holds for ``accept`` before it drops a peer's
+#: SYN (1 s before that peer retries): tokio's ``TcpListener::bind``
+#: value, which the reference listens with.  asyncio's default of 100
+#: overflows when a committee's 255 other members open their vote
+#: connections to a new leader at once.  The kernel caps it at
+#: ``net.core.somaxconn``.
+ACCEPT_BACKLOG = 1024
+
 #: wire tags mirrored from consensus/wire.py — importing it here would
 #: cycle (consensus imports this module for the Writer protocol);
 #: tests/test_wire_fuzz.py asserts these against the live constants
@@ -119,7 +127,8 @@ class Receiver:
     async def spawn(self) -> None:
         try:
             self._server = await asyncio.start_server(
-                self._handle_connection, self.host, self.port
+                self._handle_connection, self.host, self.port,
+                backlog=ACCEPT_BACKLOG,
             )
         except OSError as e:
             from .errors import classify

@@ -44,7 +44,7 @@ from ..telemetry import spans as _spans
 from ..utils.clock import default_clock, default_connector, default_rng
 from .errors import UnexpectedAckError, classify
 from .framing import FramingError, read_frame, send_frame, set_nodelay
-from .pool import BoundedPoolMixin, abort_writer
+from .pool import CONN_COUNTS, BoundedPoolMixin, abort_writer
 from .wan import LinkScheduler
 
 log = logging.getLogger(__name__)
@@ -127,6 +127,7 @@ class _Connection:
                     await default_clock().sleep(delay)
                 delay = min(delay * 2, RETRY_CAP_S)
                 continue
+            CONN_COUNTS.opens += 1
             set_nodelay(writer)
             self._writer = writer
             log.debug("Outgoing connection established with %s", self.address)

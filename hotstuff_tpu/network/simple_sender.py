@@ -17,7 +17,7 @@ from ..telemetry import spans as _spans
 from ..utils.clock import default_clock, default_connector, default_rng
 from .errors import classify
 from .framing import read_frame, send_frame, set_nodelay
-from .pool import BoundedPoolMixin, abort_writer
+from .pool import CONN_COUNTS, BoundedPoolMixin, abort_writer
 from .wan import LinkScheduler
 
 log = logging.getLogger(__name__)
@@ -101,6 +101,7 @@ class _Connection:
                 self.connect_failures += 1
                 log.warning("%s", classify(e, "connect", self.address))
                 continue  # drop this message, wait for the next
+            CONN_COUNTS.opens += 1
             set_nodelay(writer)
             self._writer = writer
             log.debug("Outgoing connection established with %s", self.address)

@@ -15,6 +15,7 @@ import asyncio
 import logging
 import selectors
 import sys
+import time
 
 from ..consensus import Committee, Parameters
 from .config import (
@@ -469,10 +470,11 @@ async def _run_many(args) -> None:
     # stays inside the hard cap / fs.nr_open.
     _raise_fd_limit(2 * len(key_files) * len(key_files) + 20_000)
     # Where the fd limit cannot cover the committee (a capability-
-    # restricted container pins the hard cap), bound the per-sender
-    # connection pools instead: idle-LRU eviction keeps the process
-    # near (n * senders * cap) connections at 2 fds each, at the cost
-    # of reconnects as leadership rotates.  Parity (unbounded) is kept
+    # restricted host pins the hard cap: 20,000 on the v5e hosts, where
+    # setrlimit may not raise it), bound the per-sender connection pools
+    # instead: idle-LRU eviction keeps the process near
+    # (n * senders * cap) connections at 2 fds each, at the cost of
+    # reconnects as leadership rotates.  Parity (unbounded) is kept
     # whenever the fd budget already fits the quadratic worst case.
     import resource
 
@@ -489,8 +491,13 @@ async def _run_many(args) -> None:
             n,
             os.environ["HOTSTUFF_MAX_PEER_CONNS"],
         )
+    began = time.perf_counter()
+    _check_committee_keys(args.committee)
+    keys_s = time.perf_counter() - began
+    warm_before, nodes_s = Node.warm_s, 0.0
     nodes = []
     for i, key_file in enumerate(key_files):
+        began = time.perf_counter()
         nodes.append(
             await Node.new(
                 committee_file=args.committee,
@@ -502,29 +509,39 @@ async def _run_many(args) -> None:
                 bind_host="127.0.0.1",
             )
         )
+        nodes_s += time.perf_counter() - began
+    warm_s = Node.warm_s - warm_before
+    # NOTE: scraped (chipbench/readers/boot.py)
+    logging.getLogger(__name__).info(
+        "Boot stats: nodes=%d keys_s=%.3f nodes_s=%.3f warm_s=%.3f",
+        n, keys_s, nodes_s - warm_s, warm_s,
+    )
+    # Each node's round timer started in its own ``Node.new``, and one
+    # process boots the nodes one after another: the first node's timer
+    # would count the rest of the committee's boot against its first
+    # round (seconds of the 5 s timeout at 256 nodes), as separate hosts
+    # booting together would not.  The committee serves from here.
+    for node in nodes:
+        node.consensus.core.timer.reset()
     _freeze_boot_objects()
+    await _with_host_stats(asyncio.gather(*(n.serve() for n in nodes)))
 
-    async def _fd_probe() -> None:
-        # capacity diagnostics for big co-located committees: one line
-        # every 5 s with the process's live fd count (the 256-node fd
-        # post-mortem needed exactly this and had to guess)
-        plog = logging.getLogger(__name__)
-        while True:
-            try:
-                n_fds = len(os.listdir("/proc/self/fd"))
-            except OSError:
-                return
-            plog.info("fd-probe: %d open fds", n_fds)
-            await asyncio.sleep(5)
 
-    probe = None
-    if len(nodes) >= 64:
-        probe = asyncio.ensure_future(_fd_probe())
-    try:
-        await _with_host_stats(asyncio.gather(*(n.serve() for n in nodes)))
-    finally:
-        if probe is not None:
-            probe.cancel()
+def _check_committee_keys(committee_file: str) -> None:
+    """Check every member's proof of possession and decode every BLS
+    key once for the process, ahead of the nodes: each ``Node.new``
+    then finds them in the process's caches (``crypto/bls/service.py``
+    ``check_possession``, ``decoded_key``), so the ``Boot stats:`` line
+    can tell the keys' seconds from the nodes'.  An ed25519 committee
+    has nothing to check here."""
+    committee = read_committee(committee_file)
+    committee.verify_pops()
+    for member in committee.committees():
+        if member.scheme == "bls":
+            from ..crypto.bls.service import decoded_key
+
+            for pk in member.authorities:
+                decoded_key(pk.to_bytes())
 
 
 async def _deploy_testbed(

@@ -16,6 +16,7 @@ from __future__ import annotations
 import asyncio
 import os
 import logging
+import time
 
 from ..consensus import Consensus, Parameters
 from ..crypto.scheme import (
@@ -236,8 +237,9 @@ def make_verifier(kind: str, scheme: str = "ed25519") -> VerifierBackend:
         return make_cpu_verifier(scheme)
     if kind in ("tpu", "tpu-sharded", "mesh"):
         if scheme == "bls":
-            # BLS device path: G1 vote-signature aggregation on device
-            # (hotstuff_tpu/tpu/bls.py), host pairing equality per QC.
+            # BLS device path: each QC's running sum of vote signatures
+            # on one device (hotstuff_tpu/tpu/bls.py), host pairing
+            # equality per QC; the sharded kinds are refused
             return make_device_verifier(
                 scheme, "tpu-sharded" if kind == "mesh" else kind
             )
@@ -247,6 +249,9 @@ def make_verifier(kind: str, scheme: str = "ed25519") -> VerifierBackend:
 
 class Node:
     CHANNEL_CAPACITY = 1_000
+    #: seconds the process's nodes spent in their verifiers' warm-up
+    #: (``node/main.py``'s ``Boot stats:`` line)
+    warm_s = 0.0
 
     def __init__(self):
         self.commit: asyncio.Queue | None = None
@@ -420,7 +425,9 @@ class Node:
                     if colocated <= 1
                     else min(1024, colocated * (quorum + 2))
                 )
+                began = time.perf_counter()
                 verifier.warmup(batch=wave)
+                Node.warm_s += time.perf_counter() - began
             else:
                 # every possible batch (<= committee size) routes to the
                 # CPU hybrid path: the kernel is never dispatched

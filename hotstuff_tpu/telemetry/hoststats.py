@@ -12,6 +12,7 @@ first over its window)::
       store_appends=5210 store_records=38877
       ancestor_hits=24310 ancestor_misses=0 sync_requests=0
       wan_frames=0 wan_delay_ms=0.0 wan_base_ms=0.0
+      conn_opens=8064 fds=16412
 
 - ``cpu_user_s`` / ``cpu_sys_s``: the process's CPU seconds, all threads
   (``os.times``).  Over a window's wall time they say whether a long
@@ -47,6 +48,14 @@ first over its window)::
   ``HOTSTUFF_WAN_SPEC``.  Delay over frames is the mean injected
   one-way delay; delay over base is held to 1 within 2% (the spec's
   ``injected_delay`` guarantee).
+- ``conn_opens``: node-to-node connections the senders opened, every
+  sender of every node (``network/pool.py`` ``CONN_COUNTS``): it climbs
+  through a committee's first rotation and stands still after it where
+  the pools are unbounded, and climbs every round where they are bounded
+  (``HOTSTUFF_MAX_PEER_CONNS``).
+- ``fds``: the process's open file descriptors when the line was made,
+  counted off the event loop on a worker thread (a listing of
+  ``/proc/self/fd`` takes milliseconds a thousand descriptors).
 
 A pause in which this process and another both stand still shows as one
 ``lag_max_ms`` the size of the pause with neither ``gc2_s`` nor CPU
@@ -65,6 +74,7 @@ import os
 import threading
 import time
 
+from ..network.pool import CONN_COUNTS
 from ..network.wan import WAN_COUNTS
 from ..store.engine import WAL_COUNTS
 from .blsstats import BLS_COUNTS
@@ -114,8 +124,9 @@ class HostStats:
             "loop_lag_max_ms": round(self.lag_max_s * 1e3, 3),
         }
 
-    def line(self) -> str:
-        """The counters as ``key=value`` pairs; resets the line's max."""
+    def line(self, fds: int = 0) -> str:
+        """The counters as ``key=value`` pairs, ``fds`` as counted by
+        the caller; resets the line's max."""
         # consensus imports telemetry, so its counter is fetched here
         from ..consensus.synchronizer import ANCESTOR_COUNTS
 
@@ -140,7 +151,8 @@ class HostStats:
             f"sync_requests={ANCESTOR_COUNTS.sync_requests} "
             f"wan_frames={WAN_COUNTS.frames} "
             f"wan_delay_ms={WAN_COUNTS.delay_s * 1e3:.1f} "
-            f"wan_base_ms={WAN_COUNTS.base_s * 1e3:.1f}"
+            f"wan_base_ms={WAN_COUNTS.base_s * 1e3:.1f} "
+            f"conn_opens={CONN_COUNTS.opens} fds={fds}"
         )
 
     async def run(self, logger=None) -> None:
@@ -159,14 +171,24 @@ class HostStats:
                 self.observe_lag(max(now - t0 - LAG_INTERVAL, 0.0))
                 if now >= next_log:
                     next_log = now + LOG_INTERVAL
+                    fds = await loop.run_in_executor(None, count_fds)
                     # NOTE: this log entry is scraped (benchmark/scaling.py,
                     # chipbench/readers/hoststats.py)
-                    logger.info("Host stats: %s", self.line())
+                    logger.info("Host stats: %s", self.line(fds))
                     if BLS_COUNTS.active:
                         # NOTE: scraped (chipbench/readers/bls.py)
                         logger.info("BLS stats: %s", BLS_COUNTS.line())
         finally:
             gc.callbacks.remove(self._on_gc)
+
+
+def count_fds() -> int:
+    """The process's open file descriptors; 0 where ``/proc`` does not
+    list them."""
+    try:
+        return len(os.listdir("/proc/self/fd"))
+    except OSError:
+        return 0
 
 
 _PROCESS: HostStats | None = None
@@ -190,4 +212,6 @@ def start() -> asyncio.Task:
     )
 
 
-__all__ = ["HostStats", "LOG_INTERVAL", "LAG_INTERVAL", "process", "start"]
+__all__ = [
+    "HostStats", "LOG_INTERVAL", "LAG_INTERVAL", "count_fds", "process", "start",
+]

@@ -1,9 +1,11 @@
 """BLS12-381 G1 aggregation on TPU — the threshold-variant device path.
 
-Implements the device side of docs/BLS_TPU_DESIGN.md: batched G1 point
-aggregation (the psum-shaped reduction that makes BLS QC verification
-scale with committee size), leaving the per-QC pairing equality on the
-host (crypto/bls/pairing.py), where it is one constant-cost call.
+Implements the device side of docs/BLS_TPU_DESIGN.md: the G1 sum of a
+QC's vote signatures, kept on the device as a running sum with one add
+a vote (``TpuG1RunningSum``), and the batched scalar ladders of the
+opt-in timeout-storm offload (``TpuStormOffload``).  The per-QC pairing
+equality stays on the host (crypto/bls/pairing.py), where it is one
+constant-cost call.
 
 Two design changes vs the original design note, found during
 implementation:
@@ -287,34 +289,14 @@ def _tree_reduce(p):
 
 def _aggregate_impl(xs, ys, zs):
     """Tree-reduce a [B, NLIMBS] batch of projective points to one point.
-    B must be a power of two (callers pad with the identity)."""
+    B must be a power of two (callers pad with the identity).  Only the
+    opt-in storm offload's weighted-signature sum (``TpuStormOffload``)
+    dispatches it: a QC's votes are summed by ``TpuG1RunningSum``, or
+    natively at a quorum check."""
     return tuple(c[0] for c in _tree_reduce((xs, ys, zs)))
 
 
-def _aggregate_plain_impl(xs, ys, zs):
-    """Same contract as ``_aggregate_impl`` but over PLAIN limb rows:
-    the Montgomery conversion (one mont_mul by R^2 per coordinate) rides
-    inside the same dispatch, so the host stages raw byte-split limbs
-    and never does per-point bignum arithmetic (ISSUE 5).  Identity pads
-    are plain (0 : 1 : 0).  mont_mul output is < 3.2q loose — well
-    inside the < ~60q input bound of the point-add tree."""
-    r2 = jnp.broadcast_to(jnp.asarray(R2_LIMBS), xs.shape)
-    return tuple(
-        c[0]
-        for c in _tree_reduce(mont_mul_many([xs, ys, zs], [r2] * 3))
-    )
-
-
 _aggregate_kernel = partial(jax.jit, static_argnames=())(_aggregate_impl)
-_aggregate_plain_kernel = partial(jax.jit, static_argnames=())(
-    _aggregate_plain_impl
-)
-# Donated variant (ISSUE 6, mirroring tpu/ed25519.py): the limb rows
-# are per-wave staging temporaries, so donating them lets XLA recycle
-# their device allocations across aggregation waves.
-_aggregate_plain_kernel_donated = jax.jit(
-    _aggregate_plain_impl, donate_argnums=(0, 1, 2)
-)
 
 _DONATE: bool | None = None
 
@@ -334,185 +316,32 @@ def _donate_buffers() -> bool:
     return _DONATE
 
 
-def make_sharded_g1_aggregate(mesh):
-    """Cross-device G1 aggregation (docs/BLS_TPU_DESIGN.md step 4):
-    the batch axis is sharded over the mesh's ``dp`` axis; each device
-    tree-reduces its slice to ONE partial point, the D partials cross
-    the interconnect with ``all_gather`` (D x 90 int32 words — trivially
-    small), and a log2(D)-deep tree replicated on every device combines
-    them.  Point addition is not componentwise, so a plain ``psum``
-    cannot apply — this is the psum-SHAPED reduction the design doc
-    describes.  Batch must be a multiple of mesh size with a
-    power-of-two per-device slice; the driver pads with identities."""
-    from jax.sharding import PartitionSpec as P
-
-    from jax import shard_map
-
-    from ..parallel.mesh import DP_AXIS as axis
-
-    def local(xs, ys, zs):
-        part = _tree_reduce((xs, ys, zs))  # [1, NLIMBS] per device
-        gathered = tuple(
-            jax.lax.all_gather(c[0], axis, axis=0, tiled=False)
-            for c in part
-        )  # [D, NLIMBS] replicated
-        out = _tree_reduce(gathered)
-        return out  # [1, NLIMBS] replicated
-
-    fn = shard_map(
-        local,
-        mesh=mesh,
-        in_specs=(P(axis), P(axis), P(axis)),
-        out_specs=(P(), P(), P()),
-        # the all_gather DOES replicate the partials, but the static
-        # varying-mesh-axes inference cannot see through the point-add
-        # tree that follows — disable the check rather than fight it
-        check_vma=False,
-    )
-    return jax.jit(fn)
-
-
-# ---- host driver ------------------------------------------------------------
-
-
-class TpuG1Aggregator:
-    """Aggregate G1 points (vote signatures) on device.
-
-    The device does the O(n) part (the point sum); the caller feeds the
-    resulting aggregate into the host pairing check — one constant-cost
-    pairing per QC regardless of committee size (docs/BLS_TPU_DESIGN.md).
-
-    ``mesh`` (optional, a 1-D ``jax.sharding.Mesh`` over axis "dp")
-    shards the batch across devices: per-device tree reduction, one
-    all_gather of D partial points, replicated final tree — the
-    multi-chip path, exercised on the 8-device CPU mesh in tests.
-
-    Inputs must be subgroup points (the CPU deserialization layer
-    checks, per-signature or once on the aggregate; completeness of the
-    addition formula depends on it)."""
-
-    PAD_SIZES = (8, 32, 128, 512)
-
-    def __init__(self, mesh=None):
-        self.mesh = mesh
-        if mesh is not None:
-            # fail at construction (node boot), not inside the first
-            # QC verify: slices must be equal powers of two per device,
-            # and the shard axis name is part of the kernel contract
-            d = int(mesh.devices.size)
-            if d & (d - 1):
-                raise ValueError(
-                    f"sharded G1 aggregation needs a power-of-two mesh, "
-                    f"got {d} devices"
-                )
-            from ..parallel.mesh import DP_AXIS
-
-            if tuple(mesh.axis_names) != (DP_AXIS,):
-                raise ValueError(
-                    f"sharded G1 aggregation needs a 1-D ('{DP_AXIS}',) "
-                    f"mesh, got axes {tuple(mesh.axis_names)}"
-                )
-        self._sharded = (
-            None if mesh is None else make_sharded_g1_aggregate(mesh)
-        )
-
-    def _padded_size(self, n: int) -> int:
-        padded = next(
-            (s for s in self.PAD_SIZES if s >= n),
-            1 << (n - 1).bit_length(),
-        )
-        if self.mesh is not None:
-            # equal power-of-two slices per device (mesh size validated
-            # as a power of two in __init__, so this terminates)
-            d = int(self.mesh.devices.size)
-            while padded % d or (padded // d) & (padded // d - 1):
-                padded *= 2
-        return padded
-
-    def aggregate(self, points: list[G1Point]) -> G1Point:
-        real = [pt for pt in points if not pt.inf]
-        if not real:
-            return G1Point.identity()
-        with _spans.span("prepare"):
-            padded = self._padded_size(len(real))
-            m = len(real)
-            xs = np.zeros((padded, NLIMBS), np.int32)
-            ys = np.zeros((padded, NLIMBS), np.int32)
-            zs = np.zeros((padded, NLIMBS), np.int32)
-            if self._sharded is None:
-                # vectorized staging (ISSUE 5): ship PLAIN byte-split
-                # limbs; the kernel Montgomery-converts on device, so
-                # prepare does no per-point bignum arithmetic.  Real
-                # rows are (x : y : 1) plain, identity pads (0 : 1 : 0)
-                # plain — both mont-convert correctly in-kernel.
-                xs[:m] = ints_to_limbs_batch([pt.x for pt in real])
-                ys[:m] = ints_to_limbs_batch([pt.y for pt in real])
-                zs[:m, 0] = 1
-                ys[m:, 0] = 1
-                kernel = (
-                    _aggregate_plain_kernel_donated
-                    if _donate_buffers()
-                    else _aggregate_plain_kernel
-                )
-            else:
-                # sharded path: the shard_map kernel's contract is
-                # Montgomery-form rows — keep the host conversion
-                one = to_mont_limbs(1)
-                for i, pt in enumerate(real):
-                    xs[i] = to_mont_limbs(pt.x)
-                    ys[i] = to_mont_limbs(pt.y)
-                    zs[i] = one
-                for i in range(m, padded):
-                    ys[i] = one  # identity rows: (0 : 1 : 0)
-                kernel = self._sharded
-        rec = _spans.recorder()
-        if rec is None:
-            x, y, z = kernel(jnp.asarray(xs), jnp.asarray(ys), jnp.asarray(zs))
-            # same fence as the profiled path (ISSUE 5): the dispatch
-            # pipeline parks this worker thread here with the GIL
-            # released while the next wave stages — the profiler
-            # measures exactly what production runs
-            x, y, z = jax.block_until_ready((x, y, z))
-        else:
-            # profiling: split the dispatch into its waterfall stages;
-            # structurally identical to the production path above
-            with rec.span("dispatch"):
-                x, y, z = kernel(
-                    jnp.asarray(xs), jnp.asarray(ys), jnp.asarray(zs)
-                )
-            with rec.span("device.execute"):
-                x, y, z = jax.block_until_ready((x, y, z))
-        with _spans.span("readback"):
-            return self._projective_to_affine(
-                np.asarray(x).reshape(NLIMBS),
-                np.asarray(y).reshape(NLIMBS),
-                np.asarray(z).reshape(NLIMBS),
-            )
-
-    @staticmethod
-    def _projective_to_affine(x, y, z) -> G1Point:
-        zi = from_mont_int(z)
-        if zi == 0:
-            return G1Point.identity()
-        xi = from_mont_int(x)
-        yi = from_mont_int(y)
-        z_inv = pow(zi, Q - 2, Q)
-        return G1Point(xi * z_inv % Q, yi * z_inv % Q)
+def projective_to_affine(x, y, z) -> G1Point:
+    """The affine point of one projective (X : Y : Z) read back from
+    the device, each coordinate a row of loose Montgomery limbs: one
+    modular inversion on the host."""
+    zi = from_mont_int(z)
+    if zi == 0:
+        return G1Point.identity()
+    xi = from_mont_int(x)
+    yi = from_mont_int(y)
+    z_inv = pow(zi, Q - 2, Q)
+    return G1Point(xi * z_inv % Q, yi * z_inv % Q)
 
 
 def _running_add_xla(ax, ay, az, px, py, pz):
     """One incremental accumulate (ISSUE 9): the new point arrives as
     PLAIN [1, NLIMBS] limb rows (byte-split on host, no bignum work),
     Montgomery-converts in-kernel (one R^2 multiply per coordinate,
-    same trick as ``_aggregate_plain_impl``), then ``point_add``s into
+    REDC(x * R^2) = x * R), then ``point_add``s into
     the Montgomery-form accumulator.  The accumulator is ``_freshen``ed
-    as it comes in: unlike the log-depth aggregation tree, this chain is
-    as deep as the committee (up to 512 sequential adds), and
+    as it comes in: this chain is as deep as the quorum (171 sequential
+    adds at 256 nodes), and
     unfreshened point_add outputs compound ~x2.5 per round until the
     CIOS columns overflow int32 (see ``_freshen``'s magnitude audit).
     Freshening the input, not the output, lets the one multiply by 1 and
     the three by R^2 run as one ``mont_mul_many``; what is stored is one
-    point_add away from fresh values, as the tree's first level is."""
+    point_add away from fresh values."""
     r2 = jnp.broadcast_to(jnp.asarray(R2_LIMBS), px.shape)
     one = jnp.broadcast_to(jnp.asarray(_ONE_MONT), ax.shape).astype(jnp.int32)
     ax, ay, az, px, py, pz = mont_mul_many(
@@ -689,15 +518,14 @@ _running_add_kernel_donated = jax.jit(
 class TpuG1RunningSum:
     """Device-resident incremental G1 accumulator (ISSUE 9).
 
-    ``TpuG1Aggregator`` batches the whole vote set at quorum;
-    this keeps a running Σ sig_i ON DEVICE as votes arrive — one
+    Keeps a running Σ sig_i ON DEVICE as votes arrive — one
     fixed-shape [1, NLIMBS] ``point_add`` dispatch per vote — so QC
     formation at quorum is a readback of an already-computed point:
     O(1) marginal work per vote, O(1) work at quorum.  The async
     dispatch never blocks the caller; only ``snapshot()`` fences.
 
-    Same trust contract as the batch aggregator: callers feed subgroup
-    points (completeness of the addition law depends on it)."""
+    Callers feed subgroup points (completeness of the addition law
+    depends on it)."""
 
     def __init__(self):
         self._acc = None
@@ -737,61 +565,45 @@ class TpuG1RunningSum:
         """Fence the pending adds and read the aggregate back (affine)."""
         with _spans.span("agg.snapshot"):
             x, y, z = jax.block_until_ready(self._acc)
-            return TpuG1Aggregator._projective_to_affine(
+            return projective_to_affine(
                 np.asarray(x).reshape(NLIMBS),
                 np.asarray(y).reshape(NLIMBS),
                 np.asarray(z).reshape(NLIMBS),
             )
 
 
-def warm_g1_programs(aggregator: TpuG1Aggregator, committee: int) -> dict:
-    """Compile or load every G1 program a committee of ``committee``
-    nodes can dispatch, before the consensus hot path: the running-sum
-    add of one vote (the donated variant where ``_donate_buffers()``
-    picks it) and the aggregation tree at every pad shape a batch of 1
-    to ``committee`` points lands on (8, 32 and 128 for 64 nodes).  Each
-    program's first call runs in a thread of its own, so that the
-    compiles, which hold no interpreter lock, overlap: a cold warm-up
-    takes about its largest tree's compile, not the sum of all.  Each
-    sums the first multiples of the generator, ``G, 2G, ...``, and its
-    result is checked against the host's, so a wrong program stops the
-    boot.  The points are distinct, as a QC's signatures are: copies of
-    one point make every level of the tree a doubling, and 128 of them
-    overflow its unfreshened limbs (``_freshen``).  Returns, by program
-    (``running_add``, then each pad shape), where its first call's
-    seconds went and its compile-cache hits (``FirstCallTimer``)."""
-    from concurrent.futures import ThreadPoolExecutor
+def warm_g1_programs() -> dict:
+    """Compile or load, before the consensus hot path, the one G1
+    program a committee dispatches: the running-sum add of one vote
+    (the donated variant where ``_donate_buffers()`` picks it), whatever
+    the committee's size.  A quorum check sums its votes natively
+    (``crypto/bls/service.py`` ``verify_shared_msg``), so no committee
+    dispatches the aggregation tree and none compiles it.  The add sums
+    ``G`` and ``2G`` and is checked against the host's ``3G``, so a
+    wrong program stops the boot.  Returns, by program
+    (``running_add``), where its first call's seconds went and its
+    compile-cache hits (``FirstCallTimer``).
 
+    The add compiles in under a second on a v5e, below jax's threshold
+    for writing the persistent compile cache, so no boot would find it
+    there and the report would count neither a hit nor a miss; the
+    threshold is lifted for this one compile."""
     from . import FirstCallTimer
 
+    option = "jax_persistent_cache_min_compile_time_secs"
+    threshold = getattr(jax.config, option)
+    jax.config.update(option, 0.0)
     g = G1Point.generator()
-    shapes = sorted({aggregator._padded_size(k) for k in range(1, committee + 1)})
-    multiples = [g]
-    while len(multiples) < max(shapes[-1], 2):
-        multiples.append(multiples[-1] + g)
-
-    def running_add() -> G1Point:
-        acc = TpuG1RunningSum()
-        acc.add(multiples[0])
-        acc.add(multiples[1])
-        return acc.snapshot()
-
-    programs = {"running_add": (running_add, 3)}
-    for shape in shapes:
-        programs[str(shape)] = (
-            partial(aggregator.aggregate, multiples[:shape]),
-            shape * (shape + 1) // 2,
-        )
-
-    def first_call(name: str) -> dict:
-        run, times = programs[name]
+    try:
         with FirstCallTimer() as timer:
-            if run() != g.mul(times):
-                raise RuntimeError(f"G1 warmup: the {name} program is wrong")
-            return timer.take()
-
-    with ThreadPoolExecutor(len(programs)) as pool:
-        return dict(zip(programs, pool.map(first_call, programs)))
+            acc = TpuG1RunningSum()
+            acc.add(g)
+            acc.add(g + g)
+            if acc.snapshot() != g.mul(3):
+                raise RuntimeError("G1 warmup: the running_add program is wrong")
+            return {"running_add": timer.take()}
+    finally:
+        jax.config.update(option, threshold)
 
 
 # ---- batched variable-base scalar multiplication ----------------------------
@@ -818,7 +630,10 @@ def _freshen(a):
     ~10q, and feeding them straight back in compounds (~x2.5 per
     round) until the CIOS columns overflow int32 — measured as wrong
     results after ~40-50 chained doublings.  The aggregation tree
-    (log-depth, fresh 1.5q leaves) never chains deep enough to need it."""
+    (``_aggregate_impl``) does not freshen its levels, and on XLA:CPU
+    some of its sums of 40 distinct random points come out wrong; its
+    one caller, the storm offload, takes a wrong sum as a failed batch
+    and falls back to the host's per-item checks."""
     one = jnp.broadcast_to(jnp.asarray(_ONE_MONT), a.shape).astype(jnp.int32)
     return mont_mul(a, one)
 
@@ -910,7 +725,7 @@ class TpuG1ScalarMul:
             assert 0 <= k < (1 << self.nbits)
         x, y, z = (np.asarray(a) for a in self.mul_arrays(scalars, points))
         return [
-            TpuG1Aggregator._projective_to_affine(x[i], y[i], z[i])
+            projective_to_affine(x[i], y[i], z[i])
             for i in range(len(points))
         ]
 
@@ -984,7 +799,7 @@ class TpuStormOffload:
             from_mont_int(z[2 * n + i]) == 0 for i in range(n)
         )
         whm = [
-            TpuG1Aggregator._projective_to_affine(x[i], y[i], z[i])
+            projective_to_affine(x[i], y[i], z[i])
             for i in range(n)
         ]
         # aggregate the wsig segment on device; the pad MUST come from
@@ -999,7 +814,7 @@ class TpuStormOffload:
         ax, ay, az = _aggregate_kernel(
             jnp.asarray(xs), jnp.asarray(ys), jnp.asarray(zs)
         )
-        agg = TpuG1Aggregator._projective_to_affine(
+        agg = projective_to_affine(
             np.asarray(ax).reshape(NLIMBS),
             np.asarray(ay).reshape(NLIMBS),
             np.asarray(az).reshape(NLIMBS),

@@ -7,7 +7,11 @@ follows the verifier its node was given."""
 from __future__ import annotations
 
 import asyncio
+import json
 import logging
+import os
+import subprocess
+import sys
 
 import pytest
 
@@ -26,6 +30,7 @@ from hotstuff_tpu.crypto.bls.service import (
 
 from chipbench.logs import CommitteeLog
 
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SECRETS = [BlsSecretKey(0x5EED + 7 * i) for i in range(4)]
 KEYS = [sk.public_key().to_bytes() for sk in SECRETS]
 POPS = [prove_possession(sk).to_bytes() for sk in SECRETS]
@@ -36,6 +41,7 @@ def cold(monkeypatch):
     """An empty proof memo and key cache for the test, the process's own
     put back after it."""
     monkeypatch.setattr(service, "_POP_PASSED", set())
+    monkeypatch.setattr(service, "_NATIVE_POP_KEYS", set())
     monkeypatch.setattr(service, "_PK_CACHE", {})
 
 
@@ -112,7 +118,7 @@ def test_eight_verifiers_decode_each_key_once(cold, monkeypatch):
     real = service.BlsPublicKey.from_bytes
     monkeypatch.setattr(
         service.BlsPublicKey, "from_bytes",
-        lambda data: decodes.append(data) or real(data),
+        lambda data, **kw: decodes.append(data) or real(data, **kw),
     )  # fmt: skip
     verifiers = [BlsVerifier() for _ in range(8)]
     for v in verifiers:
@@ -121,13 +127,32 @@ def test_eight_verifiers_decode_each_key_once(cold, monkeypatch):
     assert len(service._PK_CACHE) == len(KEYS)
 
 
+def test_a_key_whose_proof_passed_natively_skips_the_ladder(cold, monkeypatch):
+    """The native proof check subgroup-checks the key, so its decode for
+    the cache skips the pure-Python ladder; a key decoded before any
+    proof of it passed (or checked in pure Python) keeps the ladder."""
+    pytest.importorskip("hotstuff_tpu.crypto.bls.native")
+    checked = []
+    real = service.BlsPublicKey.from_bytes
+    monkeypatch.setattr(
+        service.BlsPublicKey, "from_bytes",
+        lambda data, **kw: checked.append(kw["subgroup_check"]) or real(data, **kw),
+    )  # fmt: skip
+    assert check_possession(KEYS[0], POPS[0])
+    assert service.decoded_key(KEYS[0]).to_bytes() == KEYS[0]
+    assert service.decoded_key(KEYS[1]).to_bytes() == KEYS[1]
+    assert checked == [False, True]
+    assert not check_possession(KEYS[2], POPS[0])  # another key's proof
+    assert KEYS[2] not in service._NATIVE_POP_KEYS
+
+
 def test_warmup_runs_once_a_process_and_prints_the_warm_line(
     cold, monkeypatch, caplog
 ):
-    """The device aggregator's programs (XLA:CPU here) at a 64-node
-    committee's shapes: the running-sum add and the trees of 8, 32 and
-    128 points, each checked against the host's sum, once however many
-    verifiers warm; the line is the one the benchmark's log reader
+    """The device aggregator's one program (XLA:CPU here) for a 64-node
+    committee: the running-sum add, checked against the host's sum, once
+    however many verifiers warm, and no aggregation tree (a quorum check
+    sums natively); the line is the one the benchmark's log reader
     parses."""
     monkeypatch.setattr(BlsVerifier, "_warm", set())
     caplog.set_level(logging.INFO, logger=service.__name__)
@@ -145,8 +170,8 @@ def test_warmup_runs_once_a_process_and_prints_the_warm_line(
     seconds, described = log.warm
     assert seconds >= 0
     assert described["kernel"] == "g1-xla"
-    assert described["pad_shapes"] == [8, 32, 128]
-    assert set(described["warm"]) == {"running_add", "8", "32", "128"}
+    assert described["pad_shapes"] == []
+    assert set(described["warm"]) == {"running_add"}
     for report in described["warm"].values():
         assert {"first_call_s", "cache_hits", "cache_misses"} <= set(report)
     # the CPU verifier has nothing to warm and prints nothing
@@ -155,6 +180,38 @@ def test_warmup_runs_once_a_process_and_prints_the_warm_line(
     cpu.precompute(KEYS)
     cpu.warmup(batch=64)
     assert not [r for r in caplog.records if " warm in " in r.getMessage()]
+
+
+def test_a_second_boot_finds_the_running_add_in_the_compile_cache(tmp_path):
+    """The add compiles faster than jax's threshold for writing the
+    persistent cache; the warm-up writes it all the same, so the next
+    process's warm report counts hits and no miss (the benchmark's
+    ``verifier.cache_hits`` reads them), and the threshold is jax's
+    again afterwards."""
+    code = (
+        "import json, jax\n"
+        "from hotstuff_tpu.tpu.bls import warm_g1_programs\n"
+        "report = warm_g1_programs()['running_add']\n"
+        "print(json.dumps([report['cache_hits'], report['cache_misses'],"
+        " jax.config.jax_persistent_cache_min_compile_time_secs]))\n"
+    )
+    env = {**os.environ, "JAX_COMPILATION_CACHE_DIR": str(tmp_path)}
+    reads = []
+    for _ in range(2):
+        done = subprocess.run(
+            [sys.executable, "-c", code], cwd=ROOT, capture_output=True,
+            text=True, env=env, timeout=120,
+        )  # fmt: skip
+        assert done.returncode == 0, done.stderr[-2000:]
+        reads.append(json.loads(done.stdout.strip().splitlines()[-1]))
+    (hits, misses, threshold), (hits2, misses2, threshold2) = reads
+    assert hits == 0 and misses > 0
+    assert hits2 == misses and misses2 == 0
+    import jax
+
+    assert threshold == threshold2 == (
+        jax.config.jax_persistent_cache_min_compile_time_secs
+    )
 
 
 def test_the_running_sum_follows_the_verifier(monkeypatch):

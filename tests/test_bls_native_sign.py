@@ -172,3 +172,24 @@ def test_native_off_signs_the_same_bytes_in_python(native):
     assert no_native
     assert bytes.fromhex(sig) == native.sign(message, le32(scalar))
     assert (line["signs"], line["native_signs"]) == (1, 0)
+
+
+def test_a_proof_of_possession_is_made_natively_with_the_same_bytes(
+    native, monkeypatch
+):
+    """``bls_pop`` signs ``_POP_DST`` and the key in one native call, the
+    bytes ``prove_possession`` makes in pure Python, and makes them so
+    without the library too; the proof verifies either way."""
+    import hotstuff_tpu.crypto.bls as package
+    from hotstuff_tpu.crypto.bls import prove_possession, verify_possession
+    from hotstuff_tpu.crypto.scheme import bls_keygen, bls_pop
+
+    pk, secret = bls_keygen(b"p" * 32, 7)
+    sk = BlsSecretKey(int.from_bytes(secret, "big"))
+    assert pk.to_bytes() == sk.public_key().to_bytes()
+    expected = prove_possession(sk).to_bytes()
+    assert bls_pop(secret) == expected
+    monkeypatch.delattr(package, "native", raising=False)
+    monkeypatch.setitem(sys.modules, "hotstuff_tpu.crypto.bls.native", None)
+    assert bls_pop(secret) == expected
+    assert verify_possession(sk.public_key(), BlsSignature.from_bytes(expected))

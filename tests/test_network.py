@@ -67,6 +67,33 @@ def test_receiver_dispatches_and_acks(payload):
     asyncio.run(body())
 
 
+def test_receiver_accepts_a_committee_of_connects_at_once():
+    """255 peers connecting to one listener at the same instant (at 256
+    nodes, every member's vote connection to a new leader) are all
+    accepted at once: the accept backlog is tokio's 1024, where asyncio's
+    100 drops the SYNs past it and those peers retry a second later."""
+
+    async def body():
+        port = BASE_PORT + 40
+        rx = Receiver("127.0.0.1", port, EchoHandler())
+        await rx.spawn()
+        loop = asyncio.get_running_loop()
+        began = loop.time()
+        conns = await asyncio.gather(
+            *(asyncio.open_connection("127.0.0.1", port) for _ in range(255))
+        )
+        while rx.connections < len(conns) and loop.time() - began < 5:
+            await asyncio.sleep(0.01)
+        took = loop.time() - began
+        assert rx.connections == 255
+        assert took < 0.9, took  # no peer waited out a dropped SYN
+        for _, writer in conns:
+            writer.close()
+        await rx.shutdown()
+
+    asyncio.run(body())
+
+
 def test_simple_sender():
     async def body():
         port = BASE_PORT + 1

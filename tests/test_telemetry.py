@@ -258,7 +258,7 @@ def test_host_stats_line_is_cumulative_but_for_the_lag_max():
         "lag_mean_ms", "lag_max_ms", "gc2", "gc2_s",
         "store_appends", "store_records",
         "ancestor_hits", "ancestor_misses", "sync_requests",
-        "wan_frames", "wan_delay_ms", "wan_base_ms",
+        "wan_frames", "wan_delay_ms", "wan_base_ms", "conn_opens", "fds",
     }
     assert float(first["lag_max_ms"]) == 10.0
     assert float(first["lag_mean_ms"]) == 6.0

@@ -1,4 +1,4 @@
-"""TPU BLS12-381 G1 aggregation vs the pure-Python oracle
+"""TPU BLS12-381 G1 arithmetic and running sum vs the pure-Python oracle
 (crypto/bls/curve.py), incl. the adversarial edge cases the branchless
 point addition must handle (equal points, opposite points, identity)."""
 
@@ -68,7 +68,7 @@ def _dev_point(pt: G1Point):
 
 def _read_point(p) -> G1Point:
     x, y, z = (np.asarray(c)[0] for c in p)
-    return T.TpuG1Aggregator._projective_to_affine(x, y, z)
+    return T.projective_to_affine(x, y, z)
 
 
 @pytest.mark.parametrize(
@@ -94,6 +94,21 @@ def test_point_add_unified(case):
     assert got == want, case
 
 
+def test_projective_to_affine_reads_any_scaling_back():
+    """The host's read-back of a device point: (l*x : l*y : l) is (x, y)
+    for any nonzero l, loose limbs (a multiple of q added) included, and
+    a zero Z is the identity whatever X and Y hold."""
+    p = rand_point()
+    for lam in (1, 2, rng.randrange(2, Q)):
+        rows = [T.to_mont_limbs(v * lam % Q) for v in (p.x, p.y, 1)]
+        assert T.projective_to_affine(*rows) == p
+    loose = [T._int_to_limbs(v * T.R_MONT % Q + Q) for v in (p.x, p.y, 1)]
+    assert T.projective_to_affine(*loose) == p
+    assert T.projective_to_affine(
+        T.to_mont_limbs(7), T.to_mont_limbs(9), np.zeros(T.NLIMBS, np.int32)
+    ) == G1Point.identity()
+
+
 def test_point_add_doubles():
     for _ in range(3):
         p = rand_point()
@@ -101,25 +116,30 @@ def test_point_add_doubles():
         assert got == p + p
 
 
+def _running_sum(points) -> G1Point:
+    acc = T.TpuG1RunningSum()
+    for pt in points:
+        acc.add(pt)
+    return acc.snapshot()
+
+
 def test_aggregate_matches_cpu_backend():
-    """Device tree-reduce == CPU aggregate_signatures on real vote sets,
-    including duplicate signatures (adversarial re-submission)."""
-    agg = T.TpuG1Aggregator()
+    """The device's running sum == CPU aggregate_signatures on real vote
+    sets, including duplicate signatures (adversarial re-submission)."""
     digest = b"\x07" * 32
     sks = [BlsSecretKey(100 + i) for i in range(7)]
     sigs = [sk.sign(digest) for sk in sks]
     sigs.append(sigs[0])  # duplicate
     want = aggregate_signatures(sigs).point
-    got = agg.aggregate([s.point for s in sigs])
-    assert got == want
+    assert _running_sum([s.point for s in sigs]) == want
 
 
 def test_aggregate_identity_and_empty():
-    agg = T.TpuG1Aggregator()
-    assert agg.aggregate([]) == G1Point.identity()
-    assert agg.aggregate([G1Point.identity()]) == G1Point.identity()
+    assert _running_sum([]) == G1Point.identity()
+    assert _running_sum([G1Point.identity()]) == G1Point.identity()
     p = rand_point()
-    assert agg.aggregate([p, G1Point.identity()]) == p
+    assert _running_sum([p, G1Point.identity()]) == p
+    assert _running_sum([p, -p]) == G1Point.identity()
 
 
 def test_bls_verifier_tpu_aggregation_end_to_end():
@@ -143,58 +163,31 @@ def test_bls_verifier_tpu_aggregation_end_to_end():
 
 
 def test_aggregate_deep_tree_stress():
-    """40 points -> 64-pad, 6 tree levels of loose-on-loose additions:
-    regression for the CIOS overflow-column fold (carry residue parked
-    above limb 29 was silently dropped, shifting the value by k*R —
-    only surfaced at tree depth >= 3 with particular carry patterns)."""
-    agg = T.TpuG1Aggregator()
+    """40 distinct points through the running sum: a 40-deep chain of
+    loose-on-loose additions, past the ~40-add magnitude drift that the
+    accumulator's freshen exists for, where the CIOS overflow-column
+    fold once dropped a carry (shifting the value by k*R)."""
     pts = [rand_point() for _ in range(40)]
     want = pts[0]
     for p in pts[1:]:
         want = want + p
-    assert agg.aggregate(pts) == want
+    assert _running_sum(pts) == want
 
 
-def test_sharded_aggregate_matches_cpu_backend():
-    """Cross-device G1 aggregation (design doc step 4): batch sharded
-    over the 8-device CPU mesh, per-device tree reduce, all_gather of
-    the partial points, replicated final tree — equals the CPU
-    aggregate on random vote sets, pads included."""
-    from hotstuff_tpu.crypto.bls import aggregate_signatures, BlsSignature, keygen
-    from hotstuff_tpu.parallel.mesh import default_mesh
-    from hotstuff_tpu.tpu.bls import TpuG1Aggregator
-
-    mesh = default_mesh()
-    assert mesh.devices.size == 8  # conftest forces the 8-device CPU mesh
-    agg = TpuG1Aggregator(mesh=mesh)
-
-    msg = b"sharded aggregate digest"
-    pairs = [keygen(bytes([60 + i])) for i in range(11)]  # odd count -> pads
-    sigs = [sk.sign(msg) for _, sk in pairs]
-    want = aggregate_signatures(sigs).point
-
-    got = agg.aggregate([s.point for s in sigs])
-    assert got == want
-    # degenerate shapes
-    assert agg.aggregate([]).inf
-    one = sigs[0].point
-    assert agg.aggregate([one]) == one
-
-
-def test_sharded_bls_verifier_end_to_end():
-    """BlsVerifier(aggregator='tpu-sharded') — the product plug point —
-    verifies a valid shared-message vote set and rejects a forgery."""
-    from hotstuff_tpu.crypto.bls import keygen
+@pytest.mark.parametrize("kind", ["tpu-sharded", "mesh"])
+def test_sharded_bls_verifier_end_to_end(kind):
+    """BLS has no sharded device path (a QC's running sum lives on one
+    device): asking a node for one is refused, by the verifier and by
+    the node's factory, rather than served by the single-device sum
+    under another name."""
     from hotstuff_tpu.crypto.bls.service import BlsVerifier
+    from hotstuff_tpu.node.node import make_verifier
 
-    v = BlsVerifier(aggregator="tpu-sharded")
-    assert v.name == "bls-tpu-sharded"
-    msg = b"sharded verifier digest"
-    pairs = [keygen(bytes([80 + i])) for i in range(5)]
-    votes = [(pk.to_bytes(), sk.sign(msg).to_bytes()) for pk, sk in pairs]
-    assert v.verify_shared_msg(msg, votes)
-    forged = votes[:4] + [(votes[4][0], votes[0][1])]
-    assert not v.verify_shared_msg(msg, forged)
+    with pytest.raises(ValueError, match="unknown BLS aggregator"):
+        BlsVerifier(aggregator="tpu-sharded")
+    with pytest.raises(ValueError, match="no 'tpu-sharded' device verifier"):
+        make_verifier(kind, "bls")
+    assert make_verifier("tpu", "bls").name == "bls-tpu"
 
 
 def test_scalar_mult_ladder_matches_oracle():

@@ -45,7 +45,7 @@ def same(a, b) -> bool:
 
 
 def affine(acc) -> G1Point:
-    return T.TpuG1Aggregator._projective_to_affine(
+    return T.projective_to_affine(
         *(np.asarray(c).reshape(T.NLIMBS) for c in acc)
     )
 
