@@ -66,8 +66,9 @@ class _SigAccumulator:
     formation.
 
     Where the sum runs follows the verifier the node was given: on
-    DEVICE (``tpu.bls.TpuG1RunningSum``, one fixed-shape ``point_add``
-    dispatch per vote) under the device aggregator (``--verifier tpu``,
+    DEVICE (``tpu.bls.TpuG1RunningSum``: votes staged on the host and
+    folded in by one dispatch per 64, the rest at quorum) under the
+    device aggregator (``--verifier tpu``,
     ``BlsVerifier.sums_on_device``), else an incremental host Jacobian
     add.  HOTSTUFF_AGG_DEVICE_SUM=1/0 forces either.  Per-signature
     decompress skips the r-torsion ladder — the emitted aggregate is

@@ -258,8 +258,8 @@ class BlsVerifier:
 
     def warmup(self, batch: int | None = None) -> None:
         """Compile or load, before the node binds its port, the one G1
-        program a committee dispatches: the running-sum add of one vote
-        (``tpu/bls.py`` ``warm_g1_programs``), whatever the committee's
+        program a committee dispatches: the running sum's fold of staged
+        votes (``tpu/bls.py`` ``warm_g1_programs``), whatever the committee's
         size or ``batch``.  Once a process; the CPU verifier has none.
         The ``Device verifier [...] warm in`` line names the program and
         says where its seconds went."""

@@ -8,7 +8,7 @@ or before its window's end less the last at or before its start::
 
     BLS stats: signs=4096 device_adds=2752 host_adds=0 snapshots=64
       qcs=64 compact_qcs=64 agg_verifies=64 agg_failures=0 pairings=130
-      native_signs=4096
+      native_signs=4096 device_calls=64
 
 - ``signs``: signatures made (``BlsSigningService.sign_sync``, all
   nodes): votes, blocks and timeouts.
@@ -19,6 +19,10 @@ or before its window's end less the last at or before its start::
   its running sum on the device (``tpu/bls.py`` ``TpuG1RunningSum``)
   and on the host (a Jacobian add); the verifier the node was given
   decides which (``consensus/aggregator.py`` ``_SigAccumulator``).
+- ``device_calls``: folds of staged votes dispatched to the device
+  (``TpuG1RunningSum``: one when its buffer of ``FOLD_ROWS`` fills and
+  one at a snapshot for what remains), the warm-up's included;
+  ``device_adds`` over it is the votes a call carried.
 - ``snapshots``: running sums read back from the device at quorum.
 - ``qcs`` / ``compact_qcs``: QCs the leaders made, and of those the ones
   in the compact form (one aggregate signature and a signer bitmap).
@@ -45,6 +49,7 @@ FIELDS = (
     "agg_failures",
     "pairings",
     "native_signs",
+    "device_calls",
 )
 
 

@@ -63,7 +63,7 @@ SPAN_AGG_STAGES: tuple = (
     "agg.gather",
     "agg.keysum",
     "agg.pairing",
-    "agg.accumulate",  # one vote's device add (tpu/bls.py)
+    "agg.accumulate",  # one vote staged for the device sum (tpu/bls.py)
     "agg.snapshot",  # the running sum's fence and readback at quorum
     "bls.sign",  # BlsSigningService.sign_sync: hash to G1, scalar multiply
     "bls.decode",  # a vote signature decompressed for the running sum

@@ -1,9 +1,10 @@
 """BLS12-381 G1 aggregation on TPU — the threshold-variant device path.
 
 Implements the device side of docs/BLS_TPU_DESIGN.md: the G1 sum of a
-QC's vote signatures, kept on the device as a running sum with one add
-a vote (``TpuG1RunningSum``), and the batched scalar ladders of the
-opt-in timeout-storm offload (``TpuStormOffload``).  The per-QC pairing
+QC's vote signatures, kept on the device as a running sum that folds
+in its staged votes 64 at a time (``TpuG1RunningSum``), and the
+batched scalar ladders of the opt-in timeout-storm offload
+(``TpuStormOffload``).  The per-QC pairing
 equality stays on the host (crypto/bls/pairing.py), where it is one
 constant-cost call.
 
@@ -68,6 +69,7 @@ from jax.experimental.pallas import tpu as pltpu
 from ..crypto.bls.curve import G1Point
 from ..crypto.bls.fields import P as Q
 from ..telemetry import spans as _spans
+from ..telemetry.blsstats import BLS_COUNTS
 
 NLIMBS = 30
 LIMB_BITS = 13
@@ -119,24 +121,17 @@ def to_mont_limbs(x: int) -> np.ndarray:
 # form — the hook that lets staging ship raw byte-split limbs (ISSUE 5)
 R2_LIMBS = _int_to_limbs(RADIX * RADIX % Q)
 
-_BLS_LIMB_WEIGHTS = (1 << np.arange(LIMB_BITS, dtype=np.int32)).astype(
-    np.int32
-)
+_LIMB_SHIFTS = range(0, NLIMBS * LIMB_BITS, LIMB_BITS)
 
 
 def ints_to_limbs_batch(vals: list[int]) -> np.ndarray:
     """[n] integers mod q -> [n, NLIMBS] PLAIN (non-Montgomery) limb
-    rows, vectorized: one bytes join + bit-matrix split replaces n
-    Python bignum multiplies (the old per-point ``to_mont_limbs`` loop
-    held the GIL for the whole staging pass)."""
-    n = len(vals)
-    rows = np.frombuffer(
-        b"".join(v.to_bytes(48, "big") for v in vals), np.uint8
-    ).reshape(n, 48)
-    bits = np.unpackbits(rows[:, ::-1], axis=1, bitorder="little")
-    bits = np.pad(bits, [(0, 0), (0, NLIMBS * LIMB_BITS - 384)])
-    groups = bits.reshape(n, NLIMBS, LIMB_BITS).astype(np.int32)
-    return groups @ _BLS_LIMB_WEIGHTS
+    rows by shifts and masks.  A vote's two coordinates are split on
+    the event loop as it is staged, and at that size plain Python is
+    faster than splitting through numpy's bit arrays."""
+    return np.array(
+        [[(v >> s) & MASK for s in _LIMB_SHIFTS] for v in vals], np.int32
+    ).reshape(len(vals), NLIMBS)
 
 
 def from_mont_int(limbs) -> int:
@@ -454,14 +449,13 @@ def _rows_v(m, n: int):
     return [m[k : k + 1, :] for k in range(n)]
 
 
-def _running_add_kernel_body(const_ref, in_ref, out_ref):
-    """``_running_add_xla`` on rows: ``in_ref`` holds the accumulator's
-    and the new point's six coordinates, ``out_ref`` gets the sum's
-    three; each stacked step below is the line of ``point_add`` it
-    names."""
-    const = const_ref[...]
+def _running_add_rows(const, rows):
+    """``_running_add_xla`` on rows: ``rows`` holds the accumulator's
+    and the new point's six coordinates, one a row; the sum's three come
+    back in rows 0..2 and the rows below them are zero.  Each stacked
+    step below is the line of ``point_add`` it names."""
     mont = _stack_v([const[4:5, :]] * 3 + [const[5:6, :]] * 3)
-    x1, y1, z1, x2, y2, z2 = _rows_v(_mont_mul_v(in_ref[...], mont, const), 6)
+    x1, y1, z1, x2, y2, z2 = _rows_v(_mont_mul_v(rows, mont, const), 6)
     sp = _rows_v(_pass_v(_stack_v([x1 + y1, y1 + z1, x1 + z1])), 3)
     sq = _rows_v(_pass_v(_stack_v([x2 + y2, y2 + z2, x2 + z2])), 3)
     t0, t1, t2, t3, t4, x3 = _rows_v(
@@ -480,7 +474,11 @@ def _running_add_kernel_body(const_ref, in_ref, out_ref):
         ),
         6,
     )
-    out_ref[...] = _pass_v(_stack_v([t2 - x3, t1 + y3, z3 + t0]))
+    return _pass_v(_stack_v([t2 - x3, t1 + y3, z3 + t0]))
+
+
+def _running_add_kernel_body(const_ref, in_ref, out_ref):
+    out_ref[...] = _running_add_rows(const_ref[...], in_ref[...])
 
 
 def _running_add_pallas(ax, ay, az, px, py, pz, interpret: bool = False):
@@ -498,18 +496,85 @@ def _running_add_pallas(ax, ay, az, px, py, pz, interpret: bool = False):
     return tuple(out[k : k + 1, :NLIMBS] for k in range(3))
 
 
-def _running_add_impl(ax, ay, az, px, py, pz):
+# ---- the fold: a buffer of staged votes in one program ----------------------
+# Handing one vote to the device (its host arrays and a jitted call)
+# costs the event loop ~100 times the add's ~12 us on a v5e, so the
+# running sum stages each vote's affine limbs in a host buffer of
+# FOLD_ROWS rows and hands the buffer over once: one ``jax.device_put``
+# and one call that makes the staged rows' adds in staging order, each
+# exactly the single add above.
+
+#: staged votes a fold takes at most: a 64-node quorum (43) in one call,
+#: a 256-node one (171) in three
+FOLD_ROWS = 64
+
+
+def _plain_points(rows):
+    """[k, 2, NLIMBS] staged affine (x, y) plain limbs -> [k, 3, NLIMBS]
+    projective (x : y : 1), still plain: the fold converts in-kernel."""
+    z = jnp.zeros((rows.shape[0], 1, NLIMBS), jnp.int32).at[:, 0, 0].set(1)
+    return jnp.concatenate([rows, z], axis=1)
+
+
+def _running_fold_xla(ax, ay, az, rows, n):
+    """``n`` successive ``_running_add_xla`` of the first ``n`` staged
+    rows into the accumulator; the rows from ``n`` on are not read."""
+    pts = _plain_points(rows)
+
+    def step(i, acc):
+        pt = pts[i]
+        return _running_add_xla(*acc, pt[0:1], pt[1:2], pt[2:3])
+
+    return jax.lax.fori_loop(0, n, step, (ax, ay, az))
+
+
+def _running_fold_kernel_body(n_ref, const_ref, acc_ref, pts_ref, out_ref):
+    """``_running_add_rows`` once a staged point, the first ``n_ref[0]``
+    of them: the accumulator rides rows 0..2 of the carried block, the
+    point's three plain coordinates rows 3..5 of its own block."""
+    const = const_ref[...]
+    row = jax.lax.broadcasted_iota(jnp.int32, (_ROWS, _LANES), 0)
+
+    def step(i, acc):
+        return _running_add_rows(const, jnp.where(row < 3, acc, pts_ref[i]))
+
+    out_ref[...] = jax.lax.fori_loop(0, n_ref[0], step, acc_ref[...])
+
+
+def _running_fold_pallas(ax, ay, az, rows, n, interpret: bool = False):
+    """``_running_fold_xla``'s values, bit for bit, from one Pallas
+    kernel that loops over the staged points in VMEM, the row count in
+    SMEM."""
+    acc = jnp.pad(
+        jnp.concatenate([ax, ay, az]), [(0, _ROWS - 3), (0, _LANES - NLIMBS)]
+    )
+    pts = jnp.pad(
+        _plain_points(rows), [(0, 0), (3, _ROWS - 6), (0, _LANES - NLIMBS)]
+    )
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
+        _running_fold_kernel_body,
+        out_shape=jax.ShapeDtypeStruct((_ROWS, _LANES), jnp.int32),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), vmem, vmem, vmem],
+        out_specs=vmem,
+        interpret=interpret,
+    )(jnp.reshape(n, (1,)), jnp.asarray(_G1_CONST_ROWS), acc, pts)
+    return tuple(out[k : k + 1, :NLIMBS] for k in range(3))
+
+
+def _running_add_impl(ax, ay, az, rows, n):
     """The running sum's one program (its name is what the profiler's
-    ``XLA Modules`` line shows): the Pallas kernel on a TPU, the XLA
-    formulation elsewhere (Pallas runs on the CPU only interpreted)."""
+    ``XLA Modules`` line shows): the fold of ``n`` staged rows, the
+    Pallas kernel on a TPU, the XLA formulation elsewhere (Pallas runs
+    on the CPU only interpreted)."""
     if jax.default_backend() == "tpu":
-        return _running_add_pallas(ax, ay, az, px, py, pz)
-    return _running_add_xla(ax, ay, az, px, py, pz)
+        return _running_fold_pallas(ax, ay, az, rows, n)
+    return _running_fold_xla(ax, ay, az, rows, n)
 
 
 _running_add_kernel = jax.jit(_running_add_impl)
 # donated variant: the previous accumulator is dead the moment the new
-# one exists — let XLA recycle its buffers across votes
+# one exists — let XLA recycle its buffers across folds
 _running_add_kernel_donated = jax.jit(
     _running_add_impl, donate_argnums=(0, 1, 2)
 )
@@ -518,11 +583,12 @@ _running_add_kernel_donated = jax.jit(
 class TpuG1RunningSum:
     """Device-resident incremental G1 accumulator (ISSUE 9).
 
-    Keeps a running Σ sig_i ON DEVICE as votes arrive — one
-    fixed-shape [1, NLIMBS] ``point_add`` dispatch per vote — so QC
-    formation at quorum is a readback of an already-computed point:
-    O(1) marginal work per vote, O(1) work at quorum.  The async
-    dispatch never blocks the caller; only ``snapshot()`` fences.
+    Keeps a running Σ sig_i ON DEVICE as votes arrive, so QC formation
+    at quorum is a fold of the last few votes and a readback.  ``add``
+    stages a vote's limbs in a host buffer of ``FOLD_ROWS`` rows; the
+    buffer is folded into the sum by one dispatch when it fills, and at
+    ``snapshot()`` for what remains.  The async dispatch never blocks
+    the caller; only ``snapshot()`` fences.
 
     Callers feed subgroup points (completeness of the addition law
     depends on it)."""
@@ -530,6 +596,8 @@ class TpuG1RunningSum:
     def __init__(self):
         self._acc = None
         self._count = 0
+        self._staged = np.zeros((FOLD_ROWS, 2, NLIMBS), np.int32)
+        self._pending = 0
         self.reset()
 
     def reset(self) -> None:
@@ -540,30 +608,44 @@ class TpuG1RunningSum:
             jnp.zeros((1, NLIMBS), jnp.int32),
         )
         self._count = 0
+        self._pending = 0
 
     def __len__(self) -> int:
         return self._count
 
     def add(self, pt: G1Point) -> None:
-        """Accumulate one point; returns immediately (async dispatch)."""
+        """Accumulate one point: staged, and the buffer folded once
+        full (async dispatch)."""
         if pt.inf:
             return
         with _spans.span("agg.accumulate"):
-            xs = jnp.asarray(ints_to_limbs_batch([pt.x]))
-            ys = jnp.asarray(ints_to_limbs_batch([pt.y]))
-            zs = np.zeros((1, NLIMBS), np.int32)
-            zs[0, 0] = 1
-            kernel = (
-                _running_add_kernel_donated
-                if _donate_buffers()
-                else _running_add_kernel
-            )
-            self._acc = kernel(*self._acc, xs, ys, jnp.asarray(zs))
+            self._staged[self._pending] = ints_to_limbs_batch([pt.x, pt.y])
+            self._pending += 1
             self._count += 1
+            if self._pending == FOLD_ROWS:
+                self._fold()
+
+    def _fold(self) -> None:
+        """The staged rows into the sum: one transfer, one call."""
+        # the transfer may read its host array after the call returns
+        # (even under ``may_alias=False``), and the buffer is staged
+        # again at once: hand it a copy of its own
+        rows, n = jax.device_put((self._staged.copy(), np.int32(self._pending)))
+        kernel = (
+            _running_add_kernel_donated
+            if _donate_buffers()
+            else _running_add_kernel
+        )
+        self._acc = kernel(*self._acc, rows, n)
+        self._pending = 0
+        BLS_COUNTS.add("device_calls")
 
     def snapshot(self) -> G1Point:
-        """Fence the pending adds and read the aggregate back (affine)."""
+        """Fold what is staged, fence, and read the aggregate back
+        (affine)."""
         with _spans.span("agg.snapshot"):
+            if self._pending:
+                self._fold()
             x, y, z = jax.block_until_ready(self._acc)
             return projective_to_affine(
                 np.asarray(x).reshape(NLIMBS),
@@ -574,32 +656,37 @@ class TpuG1RunningSum:
 
 def warm_g1_programs() -> dict:
     """Compile or load, before the consensus hot path, the one G1
-    program a committee dispatches: the running-sum add of one vote
-    (the donated variant where ``_donate_buffers()`` picks it), whatever
-    the committee's size.  A quorum check sums its votes natively
-    (``crypto/bls/service.py`` ``verify_shared_msg``), so no committee
-    dispatches the aggregation tree and none compiles it.  The add sums
-    ``G`` and ``2G`` and is checked against the host's ``3G``, so a
-    wrong program stops the boot.  Returns, by program
-    (``running_add``), where its first call's seconds went and its
-    compile-cache hits (``FirstCallTimer``).
+    program a committee dispatches: the running sum's fold (the donated
+    variant where ``_donate_buffers()`` picks it), whatever the
+    committee's size; a fold's row count is an argument, not a shape.
+    A quorum check sums its votes natively (``crypto/bls/service.py``
+    ``verify_shared_msg``), so no committee dispatches the aggregation
+    tree and none compiles it.  The fold sums ``G`` to ``(FOLD_ROWS +
+    2)G``, a full buffer and a remainder of two, and is checked against
+    the host's sum, so a wrong program stops the boot.  Returns, by
+    program (``running_add``), where its first call's seconds went and
+    its compile-cache hits (``FirstCallTimer``).
 
-    The add compiles in under a second on a v5e, below jax's threshold
-    for writing the persistent compile cache, so no boot would find it
-    there and the report would count neither a hit nor a miss; the
+    The fold compiles in about a second on a v5e, near jax's threshold
+    for writing the persistent compile cache, so a boot might not find
+    it there and the report would count neither a hit nor a miss; the
     threshold is lifted for this one compile."""
     from . import FirstCallTimer
 
+    g = G1Point.generator()
+    points = [g]
+    while len(points) < FOLD_ROWS + 2:
+        points.append(points[-1] + g)
+    total = g.mul(len(points) * (len(points) + 1) // 2)
     option = "jax_persistent_cache_min_compile_time_secs"
     threshold = getattr(jax.config, option)
     jax.config.update(option, 0.0)
-    g = G1Point.generator()
     try:
         with FirstCallTimer() as timer:
             acc = TpuG1RunningSum()
-            acc.add(g)
-            acc.add(g + g)
-            if acc.snapshot() != g.mul(3):
+            for pt in points:
+                acc.add(pt)
+            if acc.snapshot() != total:
                 raise RuntimeError("G1 warmup: the running_add program is wrong")
             return {"running_add": timer.take()}
     finally:

@@ -2,13 +2,14 @@
 shapes: ``TpuG1RunningSum`` over 171, 64 and 43 seeded vote signatures
 (the quorum of a 256-node committee, a full 64-node committee's votes
 and its quorum), each compared byte for byte with
-``crypto/bls_g1_ref.py``; and the running add's two formulations
-on the device itself, the Pallas kernel against the XLA program, limb
-for limb along chains of seeded adds (the kernel interpreted on the
-CPU).  Then a short profiler trace of
-eight running-sum adds, read back the way ``chipbench/readers/bls.py``
-reads a cell's: the running-sum program's executions and their
-operations.
+``crypto/bls_g1_ref.py``; and the running sum's fold in its two
+formulations on the device itself, the Pallas kernel against the XLA
+program and against one single-add kernel call a vote, limb for limb
+along 171 seeded votes folded as the running sum folds them (the kernel
+interpreted on the CPU).  Then a short profiler trace of one 171-vote
+running sum (three folds), read back the way
+``chipbench/readers/bls.py`` reads a cell's: the running-sum program's
+executions and their operations.
 
     python scripts/bls_device_check.py
 
@@ -44,10 +45,12 @@ def seeded_votes(n: int) -> list[bytes]:
     return out
 
 
-def kernels_agree(points) -> bool:
-    """``_running_add_pallas`` and ``_running_add_xla``, each jitted for
-    the default device, give the same limbs after every add of the
-    points, in four orders."""
+def folds_agree(points) -> bool:
+    """``_running_fold_pallas``, ``_running_fold_xla`` and one
+    ``_running_add_pallas`` call a point, each jitted for the default
+    device, give the same limbs after every fold of the points, staged
+    ``FOLD_ROWS`` at a time as the running sum stages them, in four
+    orders."""
     import random
     from functools import partial
 
@@ -58,21 +61,29 @@ def kernels_agree(points) -> bool:
     from hotstuff_tpu.tpu import bls as T
 
     interpret = jax.default_backend() != "tpu"  # the CPU rehearsal
-    xla = jax.jit(T._running_add_xla)
-    pallas = jax.jit(partial(T._running_add_pallas, interpret=interpret))
+    xla = jax.jit(T._running_fold_xla)
+    pallas = jax.jit(partial(T._running_fold_pallas, interpret=interpret))
+    single = jax.jit(partial(T._running_add_pallas, interpret=interpret))
     z = np.zeros((1, T.NLIMBS), np.int32)
     z[0, 0] = 1
+    same = lambda u, v: all(np.array_equal(a, b) for a, b in zip(u, v))  # noqa: E731
     for seed in range(4):
         order = random.Random(seed).sample(points, len(points))
-        a = b = (
+        a = b = c = (
             jnp.zeros((1, T.NLIMBS), jnp.int32),
             jnp.asarray(T.to_mont_limbs(1), jnp.int32).reshape(1, T.NLIMBS),
             jnp.zeros((1, T.NLIMBS), jnp.int32),
         )
-        for pt in order:
-            row = (T.ints_to_limbs_batch([pt.x]), T.ints_to_limbs_batch([pt.y]), z)
-            a, b = xla(*a, *row), pallas(*b, *row)
-            if not all(np.array_equal(u, v) for u, v in zip(a, b)):
+        for at in range(0, len(order), T.FOLD_ROWS):
+            chunk = order[at : at + T.FOLD_ROWS]
+            rows = np.zeros((T.FOLD_ROWS, 2, T.NLIMBS), np.int32)
+            for i, pt in enumerate(chunk):
+                rows[i] = T.ints_to_limbs_batch([pt.x, pt.y])
+            n = np.int32(len(chunk))
+            a, b = xla(*a, rows, n), pallas(*b, rows, n)
+            for i in range(len(chunk)):
+                c = single(*c, rows[i, 0:1], rows[i, 1:2], z)
+            if not (same(a, b) and same(b, c)):
                 return False
     return True
 
@@ -100,7 +111,7 @@ def main() -> int:
             "equal": got == ref.sum_compressed(votes[:n]),
             "seconds": time.perf_counter() - t0,
         }
-    checks["pallas_vs_xla"] = {"equal": kernels_agree(points[:64])}
+    checks["pallas_vs_xla"] = {"equal": folds_agree(points)}
 
     trace_dir = tempfile.mkdtemp(prefix="bls_device_check_")
     acc = TpuG1RunningSum()
@@ -108,13 +119,13 @@ def main() -> int:
     acc.snapshot()  # compiled before the trace starts
     jax.profiler.start_trace(trace_dir)
     acc = TpuG1RunningSum()
-    for pt in points[:8]:
+    for pt in points:
         acc.add(pt)
-    traced_equal = acc.snapshot().to_bytes() == ref.sum_compressed(votes[:8])
+    traced_equal = acc.snapshot().to_bytes() == ref.sum_compressed(votes)
     jax.profiler.stop_trace()
     events = bls_reader.trace_events(trace_dir)
     modules = [m for m in events["modules"] if bls_reader.RUNNING_ADD in m[0]]
-    ops_per_add = [
+    ops_per_fold = [
         sum(start <= o[1] < start + length for o in events["ops"])
         for _name, start, length in modules
     ]
@@ -130,7 +141,7 @@ def main() -> int:
             "equal": traced_equal,
             "running_add_executions": len(modules),
             "running_add_us": reduced["running_add_us"] if reduced else None,
-            "ops_per_add": ops_per_add,
+            "ops_per_fold": ops_per_fold,
         },
     }
     out_dir = os.path.join(ROOT, "chiprun_out", "bls_device_check")
@@ -140,7 +151,7 @@ def main() -> int:
     print(json.dumps({"device": device, "checks": checks,
                       "running_add_executions": len(modules),
                       "running_add_us": result["trace"]["running_add_us"],
-                      "ops_per_add": ops_per_add}))
+                      "ops_per_fold": ops_per_fold}))
     ok = traced_equal and all(c["equal"] for c in checks.values())
     return 0 if ok else 1
 

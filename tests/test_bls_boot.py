@@ -150,11 +150,14 @@ def test_warmup_runs_once_a_process_and_prints_the_warm_line(
     cold, monkeypatch, caplog
 ):
     """The device aggregator's one program (XLA:CPU here) for a 64-node
-    committee: the running-sum add, checked against the host's sum, once
-    however many verifiers warm, and no aggregation tree (a quorum check
-    sums natively); the line is the one the benchmark's log reader
-    parses."""
+    committee: the running sum's fold, a full buffer and a remainder
+    checked against the host's sum, once however many verifiers warm,
+    and no aggregation tree (a quorum check sums natively); the line is
+    the one the benchmark's log reader parses."""
+    from hotstuff_tpu.telemetry.blsstats import BLS_COUNTS
+
     monkeypatch.setattr(BlsVerifier, "_warm", set())
+    calls = BLS_COUNTS.counts["device_calls"]
     caplog.set_level(logging.INFO, logger=service.__name__)
     committee_keys = [
         BlsSecretKey(0xC0DE + i).public_key().to_bytes() for i in range(64)
@@ -165,6 +168,7 @@ def test_warmup_runs_once_a_process_and_prints_the_warm_line(
         v.warmup(batch=1024)
     lines = [r.getMessage() for r in caplog.records if " warm in " in r.getMessage()]
     assert len(lines) == 1
+    assert BLS_COUNTS.counts["device_calls"] - calls == 2
     log = CommitteeLog()
     log.feed(f"2026-01-01T00:00:00.000Z [INFO] {service.__name__} {lines[0]}")
     seconds, described = log.warm
@@ -212,6 +216,27 @@ def test_a_second_boot_finds_the_running_add_in_the_compile_cache(tmp_path):
     assert threshold == threshold2 == (
         jax.config.jax_persistent_cache_min_compile_time_secs
     )
+
+
+@pytest.mark.parametrize("fault", ["the-remainder", "a-full-buffer"])
+def test_a_fold_that_drops_votes_stops_the_boot(fault, monkeypatch):
+    """The warm-up's check spans a full buffer's fold and a remainder's:
+    a program that drops the last row of every fold, or that leaves the
+    sum as it was for a full buffer, is refused."""
+    import jax
+
+    from hotstuff_tpu.tpu import bls as T
+
+    def wrong(ax, ay, az, rows, n):
+        if fault == "the-remainder":
+            return T._running_fold_xla(ax, ay, az, rows, n - 1)
+        n = jax.numpy.where(n == T.FOLD_ROWS, 0, n)
+        return T._running_fold_xla(ax, ay, az, rows, n)
+
+    monkeypatch.setattr(T, "_DONATE", False)
+    monkeypatch.setattr(T, "_running_add_kernel", jax.jit(wrong))
+    with pytest.raises(RuntimeError, match="running_add program is wrong"):
+        T.warm_g1_programs()
 
 
 def test_the_running_sum_follows_the_verifier(monkeypatch):

@@ -7,6 +7,7 @@ import random
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from hotstuff_tpu.crypto.bls import (
@@ -172,6 +173,131 @@ def test_aggregate_deep_tree_stress():
     for p in pts[1:]:
         want = want + p
     assert _running_sum(pts) == want
+
+
+# ---- the fold: staged votes, one program a buffer ------------------------------
+
+#: one row, two, one short of a full buffer, a full one, one past it, and
+#: a 256-node quorum (two full buffers and 43)
+FOLD_SIZES = [1, 2, 63, 64, 65, 171]
+#: the program that made one add a vote before the fold (XLA:CPU here)
+SINGLE = jax.jit(T._running_add_xla)
+
+
+def identity_rows():
+    return (
+        jnp.zeros((1, T.NLIMBS), jnp.int32),
+        jnp.asarray(T.to_mont_limbs(1), jnp.int32).reshape(1, T.NLIMBS),
+        jnp.zeros((1, T.NLIMBS), jnp.int32),
+    )
+
+
+def plain_rows(pt: G1Point):
+    """A vote's point as the single-add program took it: plain (x, y, 1)
+    limb rows."""
+    z = np.zeros((1, T.NLIMBS), np.int32)
+    z[0, 0] = 1
+    return (T.ints_to_limbs_batch([pt.x]), T.ints_to_limbs_batch([pt.y]), z)
+
+
+def seeded_points(n: int, seed: int) -> list[G1Point]:
+    """``n`` distinct seeded multiples of G."""
+    rs = random.Random(seed)
+    return [G1Point.generator().mul(rs.randrange(1, 2**64)) for _ in range(n)]
+
+
+def check_fold_is_single_adds(n, donate, monkeypatch, fold=None, single=SINGLE):
+    """``TpuG1RunningSum`` over ``n`` seeded points with an identity
+    staged among them (skipped), its fold program ``fold`` where given
+    (else the module's own) and donation as asked: after the snapshot
+    the accumulator's limbs are those of ``n`` calls of ``single``, one
+    a point, the affine sum is the host's, and a donated accumulator's
+    first buffers are gone."""
+    points = seeded_points(n, seed=0xF01D + n)
+    points.insert(n // 2, G1Point.identity())
+    monkeypatch.setattr(T, "_DONATE", donate)
+    if fold is not None:
+        name = "_running_add_kernel_donated" if donate else "_running_add_kernel"
+        monkeypatch.setattr(T, name, fold)
+    acc = T.TpuG1RunningSum()
+    first = acc._acc
+    total = G1Point.identity()
+    want = identity_rows()
+    for pt in points:
+        acc.add(pt)
+        total = total + pt
+        if not pt.inf:
+            want = single(*want, *plain_rows(pt))
+    assert acc.snapshot() == total
+    assert len(acc) == n
+    assert all(np.array_equal(u, v) for u, v in zip(acc._acc, want))
+    assert all(a.is_deleted() for a in first) == donate
+
+
+@pytest.mark.parametrize("donate", [False, True], ids=["kept", "donated"])
+@pytest.mark.parametrize("n", FOLD_SIZES)
+def test_the_fold_is_successive_single_adds(n, donate, monkeypatch):
+    """The XLA fold (what the running sum runs off the chip), a buffer of
+    ``FOLD_ROWS`` at a time and the rest at the snapshot, bit for bit
+    the single-add program's chain; the rows a fold leaves from an
+    earlier one behind its count are not read."""
+    check_fold_is_single_adds(n, donate, monkeypatch)
+
+
+def test_the_buffer_is_staged_again_the_moment_a_fold_is_dispatched():
+    """The running sum restages its buffer while the fold it just
+    dispatched may not have read it yet: what the fold sums is what was
+    staged when it was dispatched, however soon the buffer is written
+    over (a transfer that read the buffer late summed the new rows in
+    some of these tries)."""
+    g = G1Point.generator()
+    points = [g.mul(k) for k in range(1, T.FOLD_ROWS + 1)]
+    for _ in range(30):
+        acc = T.TpuG1RunningSum()
+        for pt in points:
+            acc.add(pt)
+        acc._staged[:] = 7
+        assert acc.snapshot() == g.mul(T.FOLD_ROWS * (T.FOLD_ROWS + 1) // 2)
+
+
+@pytest.mark.parametrize("n, calls", [(1, 1), (43, 1), (64, 1), (65, 2), (171, 3)])
+def test_a_fold_is_one_device_call(n, calls):
+    """``device_calls`` of the ``BLS stats:`` line: one a full buffer,
+    inside the add that fills it, and one at the snapshot for the rest;
+    none for a snapshot with nothing staged."""
+    from hotstuff_tpu.telemetry.blsstats import BLS_COUNTS, FIELDS
+
+    assert "device_calls" in FIELDS
+    before = BLS_COUNTS.counts["device_calls"]
+    acc = T.TpuG1RunningSum()
+    for pt in seeded_points(n, seed=n):
+        acc.add(pt)
+    assert BLS_COUNTS.counts["device_calls"] - before == n // T.FOLD_ROWS
+    acc.snapshot()
+    acc.snapshot()
+    assert BLS_COUNTS.counts["device_calls"] - before == calls
+    assert f"device_calls={BLS_COUNTS.counts['device_calls']}" in BLS_COUNTS.line()
+
+
+@pytest.mark.parametrize("n", [43, 171])
+def test_the_accumulators_aggregate_is_the_references(n, monkeypatch):
+    """A QC maker's ``_SigAccumulator`` on the device over a 64-node
+    quorum's and a 256-node quorum's seeded vote signatures: the
+    compressed aggregate is ``crypto/bls_g1_ref.py``'s sum."""
+    import hashlib
+
+    from hotstuff_tpu.consensus.aggregator import _SigAccumulator
+    from hotstuff_tpu.crypto import Signature
+    from hotstuff_tpu.crypto import bls_g1_ref as ref
+    from hotstuff_tpu.crypto.bls.service import BlsVerifier
+
+    monkeypatch.delenv("HOTSTUFF_AGG_DEVICE_SUM", raising=False)
+    digest = hashlib.sha256(b"fold quorum").digest()
+    sigs = [BlsSecretKey(0xF01D + i).sign(digest).to_bytes() for i in range(n)]
+    acc = _SigAccumulator(BlsVerifier(aggregator="tpu"))
+    assert acc._device is not None
+    assert all(acc.add(Signature(sig)) for sig in sigs)
+    assert acc.aggregate() == ref.sum_compressed(sigs)
 
 
 @pytest.mark.parametrize("kind", ["tpu-sharded", "mesh"])
