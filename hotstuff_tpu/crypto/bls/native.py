@@ -10,8 +10,9 @@ Python.  The per-certificate aggregate checks were already one pairing
 equality; this path matters for PER-MESSAGE authentication (timeout
 floods — the view-change-storm bench showed ~45 ms/timeout on the
 Python backend).  Signing (``sign``: hash to G1, the scalar multiply and
-compression in one call) takes ~0.75 ms against ~6 ms in Python on an
-x86 core, and every vote and block a BLS node makes is one.
+compression in one call) takes ~0.35–0.40 ms against ~6 ms in Python on
+the TPU v5e host's cores (``scripts/bls_sign_bench.py``), and every vote
+and block a BLS node makes is one.
 
 Set ``HOTSTUFF_BLS_NATIVE=0`` to force the Python pairing.  The library
 runs a bilinearity selftest at load; any failure falls back to Python.

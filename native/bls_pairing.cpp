@@ -1534,6 +1534,135 @@ inline void hash_to_g1(G1 &out, const uint8_t *msg, size_t msg_len,
   out = g1_from_jac(cleared);
 }
 
+// ------------------------------------------------------------- signing
+// sk*[h1]B for the hash base B, the point hash_to_g1(msg).mul(sk) gives,
+// in about half the work of two ladders:
+// - h1 = (1 - x)*c with c = (1 - x)/3, and [1 - x] maps every point of
+//   the curve into G1 (RFC 9380 8.8.1's h_eff for G1), so
+//   sk*[h1]B = [sk*c mod r]([1 - x]B): a sparse 64-bit ladder clears
+//   the cofactor and the rest of h1 is folded into the scalar;
+// - on G1, phi(X:Y:Z) = (beta*X : Y : Z) is -[x^2] (g1_in_subgroup), so
+//   with k = k2*x^2 + k1 (k1, k2 < x^2 < 2^128, as r < x^4),
+//   [k]P = [k1]P + [k2](-phi(P)): one interleaved width-5 wNAF pass of
+//   ~128 doublings and ~43 additions, where the ladder took 255 and ~128;
+// - Jacobian from the cofactor ladder to the end: one inversion.
+// Like the ladder it replaced, this branches on the key's bits: neither
+// is constant time.
+
+static const uint64_t BLS_H_EFF = BLS_X_ABS + 1;  // 1 - x, as x = -|x|
+static const uint64_t BLS_H1_C = BLS_H_EFF / 3;    // h1 = (1 - x)*c
+static_assert(BLS_H_EFF % 3 == 0, "3 divides 1 - x");
+
+// a + b mod r for a, b < r (r < 2^255, so the sum fits in four limbs)
+inline void sc_add_mod_r(uint64_t out[4], const uint64_t a[4],
+                         const uint64_t b[4]) {
+  unsigned __int128 carry = 0;
+  for (int i = 0; i < 4; i++) {
+    unsigned __int128 s = (unsigned __int128)a[i] + b[i] + (uint64_t)carry;
+    out[i] = (uint64_t)s;
+    carry = s >> 64;
+  }
+  for (int i = 3; i >= 0; i--) {
+    if (out[i] > BLS_ORDER[i]) break;
+    if (out[i] < BLS_ORDER[i]) return;
+  }
+  unsigned __int128 borrow = 0;
+  for (int i = 0; i < 4; i++) {
+    unsigned __int128 d =
+        (unsigned __int128)out[i] - BLS_ORDER[i] - (uint64_t)borrow;
+    out[i] = (uint64_t)d;
+    borrow = (d >> 64) ? 1 : 0;
+  }
+}
+
+// a*m mod r for a < r, by double-and-add over the bits of m
+inline void sc_mul_small_mod_r(uint64_t out[4], const uint64_t a[4],
+                               uint64_t m) {
+  uint64_t acc[4] = {0, 0, 0, 0};
+  for (int bit = 63; bit >= 0; bit--) {
+    sc_add_mod_r(acc, acc, acc);
+    if ((m >> bit) & 1) sc_add_mod_r(acc, acc, a);
+  }
+  std::memcpy(out, acc, sizeof acc);
+}
+
+// k = k2*x^2 + k1 with k1 < x^2, for k < r: two divisions by |x|
+inline void glv_split(unsigned __int128 &k1, unsigned __int128 &k2,
+                      const uint64_t k[4]) {
+  uint64_t q1[4], q2[4];
+  unsigned __int128 r1 = 0, r2 = 0;
+  for (int i = 3; i >= 0; i--) {
+    unsigned __int128 cur = (r1 << 64) | k[i];
+    q1[i] = (uint64_t)(cur / BLS_X_ABS);
+    r1 = cur % BLS_X_ABS;
+  }
+  for (int i = 3; i >= 0; i--) {
+    unsigned __int128 cur = (r2 << 64) | q1[i];
+    q2[i] = (uint64_t)(cur / BLS_X_ABS);
+    r2 = cur % BLS_X_ABS;
+  }
+  k2 = ((unsigned __int128)q2[1] << 64) | q2[0];  // q2[2..3] = 0 as k < r
+  k1 = r2 * BLS_X_ABS + r1;
+}
+
+// width-5 NAF of k < x^2, least significant digit first: digits 0 or odd
+// in [-15, 15]; returns the digit count (at most 129)
+inline int wnaf5(int8_t d[130], unsigned __int128 k) {
+  int n = 0;
+  while (k) {
+    int di = 0;
+    if (k & 1) {
+      di = (int)(k & 31);
+      if (di >= 16) di -= 32;
+      if (di > 0)
+        k -= (unsigned)di;
+      else
+        k += (unsigned)-di;  // k + 15 < 2^128: no wrap
+    }
+    d[n++] = (int8_t)di;
+    k >>= 1;
+  }
+  return n;
+}
+
+// acc += d*T for a wNAF digit d and the table T of odd multiples
+inline void g1_jac_add_digit(G1Jac &acc, const G1Jac t[8], int d) {
+  if (d > 0) {
+    g1_jac_add(acc, acc, t[d >> 1]);
+  } else if (d < 0) {
+    G1Jac neg = t[(-d) >> 1];
+    fp_neg(neg.y, neg.y);
+    g1_jac_add(acc, acc, neg);
+  }
+}
+
+// [k]P for P in G1 and k < r, by the GLV split
+inline void g1_glv_mul(G1Jac &r, const G1Jac &p, const uint64_t k[4]) {
+  unsigned __int128 k1, k2;
+  glv_split(k1, k2, k);
+  // P, 3P, ..., 15P, and -phi of each: (beta*X, -Y, Z)
+  G1Jac t1[8], t2[8], p2;
+  t1[0] = p;
+  g1_jac_dbl(p2, p);
+  for (int i = 1; i < 8; i++) g1_jac_add(t1[i], t1[i - 1], p2);
+  Fp beta;
+  fp_set(beta, BLS_BETA_TEST_M);
+  for (int i = 0; i < 8; i++) {
+    fp_mul(t2[i].x, t1[i].x, beta);
+    fp_neg(t2[i].y, t1[i].y);
+    t2[i].z = t1[i].z;
+  }
+  int8_t d1[130], d2[130];
+  int n1 = wnaf5(d1, k1), n2 = wnaf5(d2, k2);
+  G1Jac acc = {fp_one(), fp_one(), fp_zero()};
+  for (int i = (n1 > n2 ? n1 : n2) - 1; i >= 0; i--) {
+    g1_jac_dbl(acc, acc);
+    if (i < n1) g1_jac_add_digit(acc, t1, d1[i]);
+    if (i < n2) g1_jac_add_digit(acc, t2, d2[i]);
+  }
+  r = acc;
+}
+
 // Decompressed-pk cache: committee keys repeat across every verify
 // call, and G2 decompression costs an Fq2 sqrt (~0.3 ms) plus an
 // optional subgroup ladder.  Keyed by the raw 96 compressed bytes;
@@ -1818,7 +1947,8 @@ int hs_bls_verify_one(const uint8_t *msg, size_t msg_len, const uint8_t *pk96,
 
 // sign msg with the secret scalar sk_le32 (32 bytes, little-endian):
 // out48 = compressed x*H(msg), byte for byte what curve.py's
-// hash_to_g1(msg).mul(x).to_bytes() gives.  Returns 1 ok / 0 for a
+// hash_to_g1(msg).mul(x).to_bytes() gives, computed as the signing
+// section above sets out.  Returns 1 ok / 0 for a
 // scalar that is zero or not below r (the caller signs in Python).
 int hs_bls_sign(const uint8_t *msg, size_t msg_len, const uint8_t *sk_le32,
                 uint8_t *out48) {
@@ -1834,10 +1964,14 @@ int hs_bls_sign(const uint8_t *msg, size_t msg_len, const uint8_t *sk_le32,
     if (k[i] > BLS_ORDER[i] || i == 0) return 0;  // k >= r
   }
   static const uint8_t DST[] = "HOTSTUFF_TPU_BLS_G1";
-  G1 hm;
-  hash_to_g1(hm, msg, msg_len, DST, sizeof(DST) - 1);
+  G1 base;
+  hash_to_g1_base(base, msg, msg_len, DST, sizeof(DST) - 1);
+  G1Jac hm;  // [1 - x]B, in G1
+  g1_jac_mul(hm, base, &BLS_H_EFF, 1);
+  uint64_t kc[4];  // sk*c mod r: the rest of h1, folded into the key
+  sc_mul_small_mod_r(kc, k, BLS_H1_C);
   G1Jac sig;
-  g1_jac_mul(sig, hm, k, 4);
+  g1_glv_mul(sig, hm, kc);
   g1_to_bytes(out48, g1_from_jac(sig));
   return 1;
 }

@@ -17,7 +17,8 @@ import sys
 import pytest
 
 from hotstuff_tpu.crypto.bls import BlsSecretKey, BlsSignature
-from hotstuff_tpu.crypto.bls.fields import P, R
+from hotstuff_tpu.crypto.bls.curve import H1, G1Point, hash_to_g1
+from hotstuff_tpu.crypto.bls.fields import P, R, X
 from hotstuff_tpu.crypto.bls.service import BlsSigningService
 from hotstuff_tpu.telemetry.blsstats import BLS_COUNTS, FIELDS
 
@@ -93,6 +94,85 @@ def test_native_sign_at_the_scalars_edges(native, scalar):
         assert native.sign(b"edge", le32(scalar)) == (
             bytes([one[0] ^ 0x20]) + one[1:]
         )
+
+
+# The native sign clears H(m)'s cofactor with h_eff = 1 - x and folds the
+# rest of h1 = (1 - x)*c into the key: sk*[h1]B = [sk*c mod r]([1 - x]B).
+# It then splits k' = sk*c mod r as k2*x^2 + k1 (GLV, x^2 the eigenvalue
+# of -phi on G1).  Written out here, not read from the library.
+H_EFF = 1 - X
+C = H_EFF // 3
+X2 = X * X
+# k' = k2*x^2 + k1 on the split's edges: k2 = 0 (1, 2, and x^2 - 1, the
+# largest k1), k1 = 0 (x^2, and r - 1 = (x^2 - 1)*x^2, the largest k2),
+# both 1, the largest 128-bit k', and r - x^2 = (x^2 - 2)*x^2 + 1
+FOLDED_EDGES = {
+    "one": 1, "two": 2, "x2-1": X2 - 1, "x2": X2, "x2+1": X2 + 1,
+    "2^128-1": 2**128 - 1, "r-x2": R - X2, "r-1": R - 1,
+}  # fmt: skip
+
+
+def hash_base(message: bytes) -> G1Point:
+    """hash_to_g1's point before its cofactor is cleared (the map as
+    ``tries`` writes it out)."""
+    counter = tries(message) - 1
+    h = hashlib.sha256(DST + counter.to_bytes(4, "big") + message).digest()
+    x = int.from_bytes(h + hashlib.sha256(b"x2" + h).digest()[:16], "big") % P
+    y = pow((x**3 + 4) % P, (P + 1) // 4, P)
+    return G1Point(x, min(y, P - y))
+
+
+def test_h1_is_h_eff_times_c():
+    assert H_EFF == 0xD201000000010001 and C == 0x460055555555AAAB
+    assert H_EFF * C == H1 and H_EFF % 3 == 0
+    assert X2 < 2**128 and R < X2 * X2  # so k1 and k2 are below 2^128
+
+
+@pytest.mark.parametrize("kprime", FOLDED_EDGES.values(), ids=FOLDED_EDGES.keys())
+def test_native_sign_where_the_folded_key_is_on_the_splits_edges(native, kprime):
+    scalar = kprime * pow(C, -1, R) % R
+    assert scalar * C % R == kprime
+    sk = BlsSecretKey(scalar)
+    pk = sk.public_key().to_bytes()
+    for message in (b"", b"edge", bytes(32), pair(0)[1]):
+        sig = native.sign(message, le32(scalar))
+        assert sig == sk.sign(message).to_bytes()
+        assert native.verify_one(message, pk, sig)
+
+
+@pytest.mark.parametrize("kprime", FOLDED_EDGES.values(), ids=FOLDED_EDGES.keys())
+def test_the_glv_split_in_curve_terms(kprime):
+    """[k']P = [k1]P + [k2](-phi(P)) on G1, phi(x, y) = (beta*x, y) with
+    beta the cube root of unity whose eigenvalue is -x^2."""
+    g = G1Point.generator()
+    beta = next(
+        b for b in (pow(w, (P - 1) // 3, P) for w in range(2, 10))
+        if b != 1 and G1Point(b * g.x, g.y) == -g._mul_raw(X2)
+    )  # fmt: skip
+    k2, k1 = divmod(kprime, X2)
+    assert k1 < 2**128 and k2 < 2**128
+    p = g._mul_raw(0xB15_5164)
+    minus_phi = G1Point(beta * p.x, -p.y)
+    assert p._mul_raw(k1) + minus_phi._mul_raw(k2) == p._mul_raw(kprime)
+
+
+@pytest.mark.parametrize("i", range(8))
+def test_h_eff_clears_the_cofactor_and_the_rest_folds_into_the_key(native, i):
+    """For raw hash bases B, none of them in G1: [1 - x]B is in G1,
+    and [sk*c mod r]([1 - x]B) is [sk]([h1]B), the signature's point."""
+    scalar, message = pair(2 * i)  # a message whose hash was retried
+    base = hash_base(message)
+    assert native.hash_base_many([message]) == (
+        base.x.to_bytes(48, "big") + base.y.to_bytes(48, "big")
+    )
+    assert base.mul_by_cofactor() == hash_to_g1(message)
+    cleared = base._mul_raw(H_EFF)
+    assert cleared.in_subgroup() and not cleared.inf
+    assert cleared.mul(scalar * C % R) == base.mul_by_cofactor().mul(scalar)
+
+
+def test_the_hash_bases_lie_outside_g1():
+    assert not any(hash_base(pair(2 * i)[1]).in_subgroup() for i in range(8))
 
 
 @pytest.mark.parametrize(
